@@ -1,10 +1,13 @@
 #!/usr/bin/env python
 """Benchmark: committed cmds/sec of the device-resident MultiPaxos
 steady-state pipeline at 1M in-flight slots (BASELINE.json north star),
-MESH-AWARE: on a healthy multi-chip accelerator mesh the headline runs
-the sharded drain pipeline over every device (the paxmesh substrate;
-paired A/B + per-shard latency live in bench_results/multichip_lt.json
-via bench/multichip_lt.py).
+MESH-AWARE: with several chips the headline runs the sharded drain
+pipeline over every device (the paxmesh substrate; paired A/B +
+per-shard latency come from bench/multichip_lt.py).
+
+This times ``bench/pipeline.py``'s loop, which invents its votes on the
+device and which no role executes: a kernel-layer number, not what a
+client waits for.
 
 Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...}
@@ -13,13 +16,8 @@ vs_baseline is against the reference's best published number: peak
 batched compartmentalized MultiPaxos throughput, ~934k cmds/s
 (benchmarks/eurosys/fig1_batched_multipaxos_results.csv; BASELINE.md).
 
-DEGRADATION IS LOUD (the r05 wedged-link regression class): a CPU
-fallback or a mesh that attaches but cannot psum REFUSES to stamp a
-headline -- the output carries ``"degraded": true`` + the probe's
-diagnosis and NO value/vs_baseline, and the exit code is nonzero.
-Set FPX_BENCH_ALLOW_DEGRADED=1 to run the pipeline anyway for local
-methodology work; the result still says degraded and never reports a
-vs_baseline.
+It runs on a TPU or not at all: without one, ``device.claim_tpu``
+raises and nothing is printed.
 """
 
 import json
@@ -27,42 +25,12 @@ import os
 import sys
 import time
 
-sys.path.insert(0, ".")
-
-from frankenpaxos_tpu.bench.device_probe import (  # noqa: E402
-    _ACCELERATOR_PLATFORMS,
-    mesh_probe,
-)
-
-_probe = mesh_probe()
-_accelerator = _probe.platform in _ACCELERATOR_PLATFORMS
-_partial_mesh = (_accelerator and _probe.device_count >= 2
-                 and not _probe.collective_ok)
-_degraded = not _accelerator or _partial_mesh
-
-if _degraded and not os.environ.get("FPX_BENCH_ALLOW_DEGRADED"):
-    # REFUSE the headline: no value, no vs_baseline -- a wedged link or
-    # CPU fallback must never be recorded as a device result.
-    print(json.dumps({
-        "metric": "committed_cmds_per_sec_at_1M_inflight_slots",
-        "degraded": True,
-        "probe_note": _probe.note,
-        "probe": _probe._asdict(),
-        "note": ("refusing to stamp a headline from a "
-                 + ("partial mesh (collective psum failed)"
-                    if _partial_mesh else "CPU/non-accelerator fallback")
-                 + "; set FPX_BENCH_ALLOW_DEGRADED=1 to run anyway "
-                   "(still labeled degraded, never a vs_baseline)"),
-    }))
-    sys.exit(1)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-if not _accelerator:
-    jax.config.update("jax_platforms", "cpu")
-
-
+from frankenpaxos_tpu import device  # noqa: E402
 from frankenpaxos_tpu.bench.pipeline import (  # noqa: E402
     drain_latency_distribution,
     make_sharded_runner,
@@ -82,14 +50,12 @@ NUM_ACCEPTORS = 3         # f = 1, SimpleMajority
 # latency clears the 50us target in EVERY run (<=27us). The previously
 # chosen 64K point is faster on lucky runs but jittered 0.8-1.5B
 # cmds/s across quiet repeats with worst-run latency breaching the
-# target -- the r01-r03 headline swing (815M/549M/1.64B) came from
-# exactly that. ITERS is sized so ITERS*BLOCK = 2^30 total commits:
-# large enough to swamp the ~0.1s dispatch+fetch RTT, small enough
-# that the int32 committed counter cannot wrap (2^31).
+# target. (That sweep predates the current code and machine; not
+# measured again since.) ITERS is sized so ITERS*BLOCK = 2^30 total
+# commits: large enough to swamp one dispatch+fetch, small enough that
+# the int32 committed counter cannot wrap (2^31).
 BLOCK = 1 << 15
-# Degraded (CPU-forced) runs ~2 orders slower; 2^26 total commits
-# keeps such a run to seconds while the real-device run keeps 2^30.
-ITERS = 32768 if _accelerator else 2048
+ITERS = 32768
 
 
 def _measure(spec, num_acceptors: int) -> tuple[float, float]:
@@ -113,7 +79,7 @@ def _measure(spec, num_acceptors: int) -> tuple[float, float]:
     # Time through a VALUE fetch: a device->host copy cannot complete
     # before the computation, making the measurement robust where a bare
     # block_until_ready on a donated scalar has been seen returning
-    # early. The one fetch RTT amortizes over ITERS drains.
+    # early. The one fetch amortizes over ITERS drains.
     committed = int(state.committed)
     elapsed = time.perf_counter() - t0
     assert committed == warm_committed, "nondeterministic pipeline"
@@ -161,16 +127,19 @@ def _measure_mesh(spec) -> tuple[float, float, dict]:
     return committed / elapsed, elapsed / at * 1e6, {
         "mesh_shape": {"group": 1, "slot": len(devices)},
         "mesh_devices": len(devices),
-        "mesh_ab_artifact": "bench_results/multichip_lt.json",
     }
 
 
 def main() -> None:
+    if device.explicit_cpu():
+        sys.exit("bench.py measures the chip; JAX_PLATFORMS=cpu pins "
+                 "it off")
+    found = device.claim_tpu()
     majority_spec = SimpleMajority(range(NUM_ACCEPTORS)).write_spec()
     mesh_fields: dict = {}
-    if _accelerator and _probe.device_count >= 2:
+    if found["count"] >= 2:
         # Mesh-aware by default: the headline is the sharded pipeline
-        # over every device (probe already proved the collective).
+        # over every device.
         cmds_per_sec, batch_latency_us, mesh_fields = _measure_mesh(
             majority_spec)
         single_cmds_per_sec, _ = _measure(majority_spec, NUM_ACCEPTORS)
@@ -213,21 +182,9 @@ def main() -> None:
         "block_slots": BLOCK,
         "window_slots": WINDOW,
         "iters": ITERS,
-        "probe_note": _probe.note,
-        "device": str(jax.devices()[0]),
+        "device": found,
+        "vs_baseline": round(cmds_per_sec / BASELINE_CMDS_PER_SEC, 3),
     }
-    if _degraded:
-        # FPX_BENCH_ALLOW_DEGRADED escape hatch: the run happened, but
-        # it is NOT a device headline -- no vs_baseline, loud label.
-        out["degraded"] = True
-        out["note"] = ("FPX_BENCH_ALLOW_DEGRADED run on a degraded/"
-                       "CPU substrate -- not a device result")
-        out.pop("value")
-        out["degraded_cmds_per_sec"] = round(cmds_per_sec, 1)
-    else:
-        out["degraded"] = False
-        out["vs_baseline"] = round(cmds_per_sec / BASELINE_CMDS_PER_SEC,
-                                   3)
     print(json.dumps(out))
 
 

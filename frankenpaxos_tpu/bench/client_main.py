@@ -42,7 +42,7 @@ from frankenpaxos_tpu.serve.backoff import RETRY_EXHAUSTED
 
 
 def _closed_loops(transport, num_loops: int, duration_s: float,
-                  warmup_s: float, issue_op) -> list:
+                  warmup_s: float, issue_op, stop: bool = True) -> list:
     """Shared closed-loop machinery: run ``num_loops`` callback-chained
     loops on the transport's event loop for ``duration_s`` (after a
     ``warmup_s`` settling window), recording one row per completed op.
@@ -51,7 +51,8 @@ def _closed_loops(transport, num_loops: int, duration_s: float,
     for ``finished(kind)`` on completion. Reissues are rescheduled via
     call_soon rather than recursed: a protocol that answers
     synchronously (an already-chosen single-decree value) would
-    otherwise blow the stack.
+    otherwise blow the stack. ``stop=False`` leaves the transport
+    running for a caller that has more to ask of the cluster.
     """
     rows: list = []
     done = threading.Event()
@@ -78,7 +79,8 @@ def _closed_loops(transport, num_loops: int, duration_s: float,
     for i in range(num_loops):
         transport.loop.call_soon_threadsafe(issue, i)
     done.wait(timeout=warmup_s + duration_s + 30)
-    transport.stop()
+    if stop:
+        transport.stop()
     return rows
 
 
@@ -251,6 +253,85 @@ def run_skewed(protocol_name: str, config_raw: dict, *,
                          issue_op)
 
 
+def run_readback(config_raw: dict, *, num_clients: int, duration_s: float,
+                 seed: int = 0, warmup_s: float = 0.25,
+                 overrides: dict | None = None) -> dict:
+    """Closed write loops against a multipaxos KeyValueStore whose
+    outcome can be CHECKED: loop ``p`` owns key ``k<seed>.<p>`` and
+    writes 0, 1, 2, ... to it, so every key's last acknowledged value is
+    known, and once the loops stop every written key is read back with
+    a linearizable read. Returns the counts and every discrepancy:
+    ``unacked`` (a write that got no reply, or a give-up) and
+    ``mismatched`` (a read that did not return the last acked value)."""
+    from frankenpaxos_tpu.runtime.serializer import PickleSerializer
+    from frankenpaxos_tpu.statemachine import GetRequest, SetRequest
+
+    serializer = PickleSerializer()
+    protocol = get_protocol("multipaxos")
+    config = protocol.load_config(config_raw)
+    logger = FakeLogger(LogLevel.FATAL)
+    transport = TcpTransport(("127.0.0.1", free_port()), logger)
+    transport.start()
+    ctx = DeployCtx(config=config, transport=transport, logger=logger,
+                    overrides=overrides or {}, seed=seed)
+    client = protocol.make_client(ctx, transport.listen_address)
+    keys = [f"k{seed}.{p}" for p in range(num_clients)]
+    issued = [0] * num_clients
+    acked = [-1] * num_clients   # last acknowledged value per loop
+    num_acked = [0]
+
+    def issue_op(p: int, finished) -> None:
+        value = issued[p]
+        issued[p] += 1
+
+        def on_reply(reply) -> None:
+            if reply is RETRY_EXHAUSTED:
+                finished("giveup")
+                return
+            acked[p] = value
+            num_acked[0] += 1
+            finished(WRITE)
+
+        client.write(p, serializer.to_bytes(
+            SetRequest(((keys[p], str(value)),))), on_reply)
+
+    rows = _closed_loops(transport, num_clients, duration_s, warmup_s,
+                         issue_op, stop=False)
+    unacked = [keys[p] for p in range(num_clients)
+               if acked[p] != issued[p] - 1]
+
+    # A loop whose write is still outstanding is not idle, and reading
+    # on it would trip the client's one-op-per-pseudonym check; with
+    # any write unacknowledged the run has failed already.
+    to_read = ([] if unacked
+               else [p for p in range(num_clients) if acked[p] >= 0])
+    read = {}
+    all_read = threading.Event()
+
+    def read_all() -> None:
+        for p in to_read:
+            def on_reply(raw, p=p) -> None:
+                read[p] = dict(
+                    serializer.from_bytes(raw).key_values).get(keys[p])
+                if len(read) == len(to_read):
+                    all_read.set()
+
+            client.read(p, serializer.to_bytes(GetRequest((keys[p],))),
+                        on_reply)
+
+    if to_read:
+        transport.loop.call_soon_threadsafe(read_all)
+        all_read.wait(timeout=60)
+    transport.stop()
+    mismatched = [keys[p] for p in to_read
+                  if read.get(p) != str(acked[p])]
+    return {"keys": num_clients,
+            "writes_issued": sum(issued), "writes_acked": num_acked[0],
+            "keys_read_back": len(to_read) - len(mismatched),
+            "unacked": unacked, "mismatched": mismatched,
+            "rows": rows}
+
+
 def run_drive(protocol_name: str, config_raw: dict, *,
               num_clients: int, duration_s: float, seed: int = 0,
               warmup_s: float = 0.25,
@@ -314,13 +395,27 @@ def main(argv=None) -> None:
                              '{"name": "open_loop", "rate": ...}')
     parser.add_argument("--num_sessions", type=int, default=1024,
                         help="open-loop pseudonym pool size")
+    parser.add_argument("--readback", default=None, metavar="JSON",
+                        help="checked KV write loops (run_readback): "
+                             "one key per loop, every key read back "
+                             "linearizably at the end; the verdict goes "
+                             "to this JSON file, the ops to --out")
     parser.add_argument("--out", required=True)
     args = parser.parse_args(argv)
 
     with open(args.config) as f:
         config_raw = json.load(f)
 
-    if args.open_loop:
+    if args.readback:
+        verdict = run_readback(
+            config_raw, num_clients=args.num_clients,
+            duration_s=args.duration, seed=args.seed,
+            overrides=(json.loads(args.client_options)
+                       if args.client_options else None))
+        rows = verdict.pop("rows")
+        with open(args.readback, "w") as f:
+            json.dump(verdict, f)
+    elif args.open_loop:
         from frankenpaxos_tpu.bench.workload import OpenLoopWorkload
 
         workload = (workload_from_dict(json.loads(args.workload))

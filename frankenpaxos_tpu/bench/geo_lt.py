@@ -369,7 +369,7 @@ class _FlatDriver:
 
 def flat_arm(commands: int, reps: int, seed: int = 0,
              chunk: int = 25) -> dict:
-    """The overload_lt A/B discipline (docs/BENCH_HISTORY.md): keep
+    """The overload_lt A/B discipline: keep
     all three arms' sims ALIVE, alternate them in small chunks with
     GC disabled (every noise window is shared), ratio summed per-arm
     times, gate on the median over fresh-sim reps -- whole-rep

@@ -35,8 +35,8 @@ import os
 import statistics
 import time
 
-#: Overhead A/B shape (the overload_lt calibration,
-#: docs/BENCH_HISTORY.md): ~24 ticks per interleave chunk, 24 timed
+#: Overhead A/B shape (the overload_lt calibration):
+#: ~24 ticks per interleave chunk, 24 timed
 #: chunks per block, 4 warm-up chunks discarded, median over blocks.
 OVERHEAD_CHUNK_TICKS = 24
 OVERHEAD_CHUNKS = 24

@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import random
 import signal
 import socket
 import subprocess
@@ -41,12 +42,15 @@ class Proc:
         return self._proc.pid
 
     def kill(self) -> None:
+        """Stop the process and REAP it: a chip's next owner must not
+        start while the previous one still holds the device."""
         if self._proc.poll() is None:
             self._proc.send_signal(signal.SIGTERM)
             try:
                 self._proc.wait(timeout=5)
             except subprocess.TimeoutExpired:
                 self._proc.kill()
+                self._proc.wait()
         self._out.close()
 
     def running(self) -> bool:
@@ -81,10 +85,41 @@ class LocalHost:
         return {p for p in paths if needle in self.read_output(p)}
 
 
+def _ephemeral_floor() -> int:
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            return int(f.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        return 32768  # the Linux default
+
+
+#: Ports this process has handed out. A placement draws a dozen ports
+#: long before any role binds one, so the allocator itself must never
+#: repeat -- role and /metrics ports of one launch stay distinct.
+_handed_out: set = set()
+_port_rng = random.Random(os.getpid() ^ time.time_ns())
+
+
 def free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+    """A localhost port that is free now and that nothing takes by
+    accident before its role binds it. Ports come from BELOW the
+    kernel's ephemeral range: a port the kernel picked (``bind(0)``) is
+    released back to the pool every outgoing connection draws its source
+    port from, and a busy test host steals it before the role starts --
+    the ``[Errno 98] address already in use`` start-up flake."""
+    lo, hi = 10240, _ephemeral_floor()
+    for _ in range(10_000):
+        port = _port_rng.randrange(lo, hi)
+        if port in _handed_out:
+            continue
+        with socket.socket() as s:
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                continue
+        _handed_out.add(port)
+        return port
+    raise RuntimeError(f"no free port in [{lo}, {hi})")
 
 
 class BenchmarkDirectory:
@@ -104,6 +139,9 @@ class BenchmarkDirectory:
         # role can be relaunched verbatim (readiness retry, chaos
         # driver).
         self.role_commands: dict[str, tuple] = {}
+        # The label of the one process launch_roles left unpinned to
+        # own the chip (None: every role is pinned to the CPU).
+        self.chip_owner: Optional[str] = None
         # label -> obs.telemetry.TelemetryReporter, registered by
         # harnesses that drive a device pipeline beside the roles;
         # chaos SIGKILL post-mortems snapshot each reporter's last

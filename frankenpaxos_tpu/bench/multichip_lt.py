@@ -17,8 +17,7 @@ that make the number trustworthy:
     arms' committed / sm_state registers must agree exactly -- the
     oracle gate's bit-identity, enforced at headline scale for free.
 
-Methodology (the overload_lt shape, calibrated on this 2-CPU
-container, docs/BENCH_HISTORY.md): both arms PERSISTENT, driven
+Methodology (the overload_lt shape): both arms PERSISTENT, driven
 alternately in equal chunks with the order flipped every chunk and GC
 disabled during the timed region, warmup chunks discarded, per-arm
 times summed, and the reported speedup the MEDIAN over independent
@@ -26,13 +25,10 @@ blocks. Chunks resume the drain counter (``run_steps_from`` / the
 sharded runner take a traced start), so every chunk reuses one
 compiled executable and the ring keeps rolling.
 
-Degradation is LOUD: with no accelerator mesh the A/B runs on a
-FORCED 8-device host-platform mesh and the artifact says so
-(``"host_mesh": true`` -- CI's multichip-smoke lane, and honest
-methodology work on a dev box); an accelerator that attaches but
-cannot psum (a wedged inter-chip link, the r05 class) writes
-``"degraded": true`` with the probe note and exits nonzero instead of
-benching a partial mesh.
+It runs on the TPU's chips (``device.claim_tpu``: no TPU, no run).
+The one exception is an explicit ``JAX_PLATFORMS=cpu`` -- CI's
+multichip-smoke lane -- under which the A/B runs on a FORCED 8-device
+host-platform mesh and the artifact says so (``"host_mesh": true``).
 
 Usage::
 
@@ -49,10 +45,7 @@ import os
 import sys
 import time
 
-from frankenpaxos_tpu.bench.device_probe import (
-    _ACCELERATOR_PLATFORMS,
-    mesh_probe,
-)
+from frankenpaxos_tpu import device
 
 #: Headline arms: the bench.py 1M-slot window and the scale-out 8M one,
 #: both at the frontier-swept 32K-slot drain (bench_results/
@@ -83,7 +76,6 @@ def _force_host_mesh() -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _spec_arrays():
@@ -394,33 +386,13 @@ def main(argv=None) -> int:
                              "chunks, same gates")
     args = parser.parse_args(argv)
 
-    probe = mesh_probe()
-    accelerator = probe.platform in _ACCELERATOR_PLATFORMS
-    if accelerator and probe.device_count >= 2 \
-            and not probe.collective_ok:
-        # A mesh that attaches but cannot psum is a PARTIAL MESH:
-        # refuse to bench it (the r05 wedged-link class, loud).
-        artifact = {
-            "kind": "multichip_lt",
-            "degraded": True,
-            "probe_note": probe.note,
-            "probe": probe._asdict(),
-        }
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(artifact, f, indent=2)
-            f.write("\n")
-        print(json.dumps(artifact))
-        return 1
-    host_mesh = not accelerator
+    host_mesh = device.explicit_cpu()
     if host_mesh:
         _force_host_mesh()
+    found = device.claim_tpu()
 
     import jax
     import numpy as np
-
-    if host_mesh:
-        jax.config.update("jax_platforms", "cpu")
     from jax.sharding import Mesh
 
     devices = jax.devices()
@@ -469,7 +441,7 @@ def main(argv=None) -> int:
         "mode": "smoke" if args.smoke else "full",
         "degraded": False,
         "host_mesh": host_mesh,
-        "probe": probe._asdict(),
+        "device": found,
         "mesh_shape": {"group": 1, "slot": len(devices)},
         "num_acceptors": NUM_ACCEPTORS,
         "arms": arm_rows,
@@ -485,7 +457,7 @@ def main(argv=None) -> int:
             "chunks discarded, speedup = summed 1-chip time / summed "
             "mesh time, median over independent blocks"),
         "host_mesh_note": (
-            "no accelerator mesh: A/B ran on a FORCED 8-device "
+            "JAX_PLATFORMS=cpu: A/B ran on a FORCED 8-device "
             "host-platform (CPU XLA) mesh -- methodology and "
             "bit-identity are real, the speedup is NOT a hardware "
             "claim (8 virtual devices share this host's cores)"

@@ -40,8 +40,9 @@ class MultiPaxosInput:
     num_clients: int = 2
     duration_s: float = 2.0
     quorum_backend: str = "dict"
-    # Pipelined device drains for the tpu backend (hide the device-link
-    # RTT behind the event loop; see ProxyLeaderOptions.tpu_pipelined).
+    # Pipelined device drains for the tpu backend (overlap the result
+    # fetch with the next drain's decode; see
+    # ProxyLeaderOptions.tpu_pipelined).
     tpu_pipelined: bool = False
     # The drain-granular run pipeline (ClientRequestArray -> Phase2aRun
     # -> Phase2bRange -> ChosenRun -> ClientReplyArray): clients
@@ -94,18 +95,17 @@ def placement(input: MultiPaxosInput) -> dict:
     }
 
 
-def run_benchmark(bench: BenchmarkDirectory,
-                  input: MultiPaxosInput) -> dict:
-    # Launch + leader warmup, with ONE retry on a fresh placement: a
-    # lost startup race (a free_port() stolen between allocation and
-    # bind, a role losing the scheduler lottery on a loaded 1-CPU
-    # host) is a deployment artifact, not a benchmark result, and a
-    # retry runs with entirely fresh ports. The per-role readiness
-    # itself is the launch_roles connect-back handshake.
+def launch_with_retry(bench: BenchmarkDirectory, input: MultiPaxosInput,
+                      **launch_kwargs) -> tuple:
+    """Launch + leader warmup, with ONE retry on a fresh placement: a
+    lost startup race (a port taken between allocation and bind, a role
+    losing the scheduler lottery on a loaded host) is a deployment
+    artifact, not a benchmark result, and a retry runs with entirely
+    fresh ports. The per-role readiness itself is the launch_roles
+    connect-back handshake. Returns ``(config_path, config)``."""
     for attempt in (1, 2):
         try:
-            config_path, config = _launch_and_warm(bench, input)
-            break
+            return _launch_and_warm(bench, input, **launch_kwargs)
         except RuntimeError as e:
             if attempt == 2:
                 raise
@@ -120,6 +120,11 @@ def run_benchmark(bench: BenchmarkDirectory,
             for log in glob.glob(os.path.join(bench.path, "*.log")):
                 os.replace(log, f"{log}.attempt{attempt}")
 
+
+def run_benchmark(bench: BenchmarkDirectory,
+                  input: MultiPaxosInput) -> dict:
+    config_path, config = launch_with_retry(bench, input)
+
     if input.client_procs > 0:
         return _run_with_client_procs(bench, input, config_path)
 
@@ -127,10 +132,14 @@ def run_benchmark(bench: BenchmarkDirectory,
 
 
 def _launch_and_warm(bench: BenchmarkDirectory,
-                     input: MultiPaxosInput) -> tuple:
+                     input: MultiPaxosInput,
+                     extra_overrides: "dict | None" = None,
+                     **launch_kwargs) -> tuple:
     """One deployment startup attempt: launch every role (handshake
     readiness) and commit a warmup write through leader 0. Raises
-    RuntimeError -- with the roles already cleaned up -- on failure."""
+    RuntimeError -- with the roles already cleaned up -- on failure.
+    ``extra_overrides`` are ``--options.*`` beyond what ``input``
+    spells; ``launch_kwargs`` go to ``launch_roles``."""
     from frankenpaxos_tpu.bench.deploy_suite import launch_roles
     from frankenpaxos_tpu.deploy import get_protocol
     from frankenpaxos_tpu.protocols.multipaxos import Client, ClientOptions
@@ -146,15 +155,18 @@ def _launch_and_warm(bench: BenchmarkDirectory,
     if input.num_batchers:
         overrides["batch_size"] = str(input.batch_size)
         overrides["flush_period_s"] = str(input.batch_flush_period_s)
+    overrides.update(extra_overrides or {})
     launch_roles(bench, "multipaxos", config_path, config,
                  state_machine=input.state_machine,
                  overrides=overrides,
                  prometheus=input.prometheus, supernode=input.supernode,
                  profiled=input.profiled, wal_dir=input.wal_dir,
-                 # tpu role startup pre-compiles kernels over the
-                 # device link, which takes minutes under contention.
+                 # The chip-owning role initialises the TPU and compiles
+                 # its tracker's kernels before it reports ready; with a
+                 # cold compile cache that is the bulk of set-up time.
                  ready_timeout_s=(120.0 if input.quorum_backend == "dict"
-                                  else 300.0))
+                                  else 300.0),
+                 **launch_kwargs)
 
     # Explicit leader-ready probe: a warmup write with a short resend
     # period retries until leader 0 has completed Phase 1 and can commit
@@ -249,9 +261,11 @@ def _run_with_client_threads(bench: BenchmarkDirectory,
         t.join()
     elapsed = time.time() - start
 
-    role_metrics = _scrape_role_metrics(bench, input)
-    role_cpu = bench.role_cpu_seconds()
-    bench.cleanup()
+    try:
+        role_metrics = _scrape_role_metrics(bench, input)
+        role_cpu = bench.role_cpu_seconds()
+    finally:
+        bench.cleanup()
     return _write_stats(bench, input, samples, elapsed, role_metrics,
                         input.workload, role_cpu)
 
@@ -329,20 +343,15 @@ def _run_with_client_procs(bench: BenchmarkDirectory,
 def _scrape_role_metrics(bench: BenchmarkDirectory,
                          input: MultiPaxosInput) -> dict:
     """Scrape every role's /metrics endpoint (framework metrics only);
-    must run before bench.cleanup() kills the roles."""
+    must run before bench.cleanup() kills the roles. An endpoint that
+    does not answer raises: a missing scrape is a failure, not a zero."""
     if not input.prometheus:
         return {}
     from frankenpaxos_tpu.bench.metrics import scrape
 
-    role_metrics = {}
-    for label, port in bench.prometheus_ports.items():
-        try:
-            role_metrics[label] = {
-                k: v for k, v in scrape(port).items()
-                if k.startswith("multipaxos_")}
-        except OSError:
-            role_metrics[label] = {}
-    return role_metrics
+    return {label: {k: v for k, v in scrape(port).items()
+                    if k.startswith("multipaxos_")}
+            for label, port in bench.prometheus_ports.items()}
 
 
 def _write_stats(bench: BenchmarkDirectory, input: MultiPaxosInput,

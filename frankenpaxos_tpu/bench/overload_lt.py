@@ -283,8 +283,8 @@ def measure_overhead_block(inflight: int) -> float:
     disabled, arm order flipped every chunk; returns the off/no-hooks
     throughput ratio from the summed per-arm times.
 
-    Why this shape (calibrated on this 2-CPU container, see
-    docs/BENCH_HISTORY.md): separate whole-rep arms flake against the
+    Why this shape (calibrated on a 2-CPU container): separate
+    whole-rep arms flake against the
     3% gate no matter the estimator -- per-rep noise is ~+-20% at
     0.5s reps and an A/A control (two IDENTICAL sims) still spread
     +-8% at 2s reps because gen2 GC pauses over the sims' growing

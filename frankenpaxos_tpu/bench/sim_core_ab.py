@@ -30,7 +30,7 @@ machinery, not protocol handler Python:
   reported so the headline can't be mistaken for a claim about
   shallow buffers).
 
-Methodology (overload_lt calibration, docs/BENCH_HISTORY.md): the
+Methodology (overload_lt calibration): the
 gate workload alternates the two arms in identical per-round chunks
 with GC disabled and warm-up rounds discarded, and the ratio is the
 median over independent blocks. Before timing, both arms replay a

@@ -158,6 +158,22 @@ class Role:
 
     addresses: Callable[[Any], list]
     make: Callable[[DeployCtx, Any, int], Any]
+    # The ``--options.*`` names that, set to "tpu", make this role build
+    # a device backend. One chip serves one process, so the CLI and the
+    # launcher decide from this, per process, who owns the chip and who
+    # is pinned to the CPU (device.py).
+    device_options: tuple = ()
+
+    def hosts_device(self, overrides: dict) -> bool:
+        return any(overrides.get(name) == "tpu"
+                   for name in self.device_options)
+
+
+def process_label(role_name: str, index_arg: str) -> str:
+    """What the process started with ``--role role_name --index
+    index_arg`` is called: in its log and trace file names, in the
+    launcher's books, and in the ready handshake between them."""
+    return f"{role_name}_{index_arg.replace(',', '_')}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,7 +306,9 @@ def _single_decree(name, mod_name, cfg_name, leader_name, acceptor_name,
             lambda c: list(c.leader_addresses),
             lambda ctx, a, i: leader_cls(
                 a, ctx.transport, ctx.logger, ctx.config,
-                **ctx.kw(leader_cls))),
+                **ctx.kw(leader_cls)),
+            device_options=(("quorum_backend",) if name == "fastpaxos"
+                            else ())),
         "acceptor": Role(
             lambda c: list(c.acceptor_addresses),
             lambda ctx, a, i: acceptor_cls(
@@ -417,13 +435,15 @@ def _multipaxos() -> Protocol:
                 lambda ctx, a, i: mp.Leader(
                     a, ctx.transport, ctx.logger, ctx.config,
                     ctx.opts(mp.LeaderOptions), seed=ctx.seed,
-                    collectors=ctx.collectors)),
+                    collectors=ctx.collectors),
+                device_options=("phase1_backend",)),
             "proxy_leader": Role(
                 lambda c: list(c.proxy_leader_addresses),
                 lambda ctx, a, i: mp.ProxyLeader(
                     a, ctx.transport, ctx.logger, ctx.config,
                     ctx.opts(mp.ProxyLeaderOptions), seed=ctx.seed,
-                    collectors=ctx.collectors)),
+                    collectors=ctx.collectors),
+                device_options=("quorum_backend", "epoch_backend")),
             "acceptor": Role(
                 flat_acceptors,
                 lambda ctx, a, i: mp.Acceptor(
@@ -623,7 +643,8 @@ def _fastmultipaxos() -> Protocol:
                 lambda ctx, a, i: m.FastMultiPaxosLeader(
                     a, ctx.transport, ctx.logger, ctx.config, ctx.sm(),
                     options=ctx.opts(m.FastMultiPaxosLeaderOptions),
-                    seed=ctx.seed)),
+                    seed=ctx.seed),
+                device_options=("quorum_backend",)),
             "acceptor": Role(
                 lambda c: list(c.acceptor_addresses),
                 lambda ctx, a, i: m.FastMultiPaxosAcceptor(
@@ -668,7 +689,8 @@ def _epaxos() -> Protocol:
             lambda c: list(c.replica_addresses),
             lambda ctx, a, i: m.EPaxosReplica(
                 a, ctx.transport, ctx.logger, ctx.config, ctx.sm(),
-                ctx.opts(m.EPaxosReplicaOptions), seed=ctx.seed))},
+                ctx.opts(m.EPaxosReplicaOptions), seed=ctx.seed),
+            device_options=("dep_backend",))},
         make_client=lambda ctx, a: m.EPaxosClient(
             a, ctx.transport, ctx.logger, ctx.config, seed=ctx.seed,
             **ctx.kw(m.EPaxosClient)),
@@ -717,27 +739,32 @@ def _simplebpaxos(gc: bool = False) -> Protocol:
 
         return SimpleBPaxosConfig(**kwargs)
 
+    gc_options = ("gc_backend",) if gc else ()
     roles = {
         "leader": Role(
             lambda c: list(c.leader_addresses),
             lambda ctx, a, i: leader_cls(
                 a, ctx.transport, ctx.logger, ctx.config, seed=ctx.seed,
-                **ctx.kw(leader_cls))),
+                **ctx.kw(leader_cls)),
+            device_options=("dep_backend",)),
         "proposer": Role(
             lambda c: list(c.proposer_addresses),
             lambda ctx, a, i: proposer_cls(
                 a, ctx.transport, ctx.logger, ctx.config, seed=ctx.seed,
-                **ctx.kw(proposer_cls))),
+                **ctx.kw(proposer_cls)),
+            device_options=gc_options),
         "dep_node": Role(
             lambda c: list(c.dep_service_node_addresses),
             lambda ctx, a, i: dep_cls(
                 a, ctx.transport, ctx.logger, ctx.config, ctx.sm(),
-                **ctx.kw(dep_cls))),
+                **ctx.kw(dep_cls)),
+            device_options=gc_options),
         "acceptor": Role(
             lambda c: list(c.acceptor_addresses),
             lambda ctx, a, i: acceptor_cls(
                 a, ctx.transport, ctx.logger, ctx.config,
-                **ctx.kw(acceptor_cls))),
+                **ctx.kw(acceptor_cls)),
+            device_options=gc_options),
         "replica": Role(
             lambda c: list(c.replica_addresses),
             lambda ctx, a, i: replica_cls(
@@ -837,7 +864,8 @@ def _matchmakermultipaxos() -> Protocol:
                 lambda ctx, a, i: m.MMPLeader(
                     a, ctx.transport, ctx.logger, ctx.config,
                     seed=ctx.seed,
-                    quorum_backend=ctx.opt("quorum_backend", "dict"))),
+                    quorum_backend=ctx.opt("quorum_backend", "dict")),
+                device_options=("quorum_backend",)),
             "matchmaker": Role(
                 lambda c: list(c.matchmaker_addresses),
                 lambda ctx, a, i: m.MMPMatchmaker(
@@ -1058,7 +1086,8 @@ def _wpaxos() -> Protocol:
                 lambda c: list(c.leader_addresses),
                 lambda ctx, a, i: m.WPaxosLeader(
                     a, ctx.transport, ctx.logger, ctx.config,
-                    ctx.opts(m.WPaxosLeaderOptions))),
+                    ctx.opts(m.WPaxosLeaderOptions)),
+                device_options=("quorum_backend",)),
             "acceptor": Role(
                 lambda c: [a for row in c.acceptor_addresses
                            for a in row],

@@ -1,46 +1,83 @@
 """ctypes loader for the native wire codec (with pure-Python fallback).
 
-Compiles ``codec.cpp`` with g++ on first use (cached as
-``libfpxcodec.so`` next to the source; rebuilds when the source is
-newer). Every entry point has a NumPy/struct fallback so the framework
-runs where no compiler exists.
+Compiles ``codec.cpp`` with g++ on first use, next to the source, under
+a name that carries the source's hash. Every entry point has a
+NumPy/struct fallback so the framework runs where no compiler exists;
+a process that must not run on the fallback calls :func:`require`.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import struct
 import subprocess
+import tempfile
 from typing import Optional
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "codec.cpp")
-_LIB = os.path.join(_DIR, "libfpxcodec.so")
 _LEN = struct.Struct(">I")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_load_error = ""
 
 
-def _build() -> None:
-    subprocess.run(
-        ["g++", "-O3", "-shared", "-fPIC", "-o", _LIB, _SRC],
-        check=True, capture_output=True)
+def _lib_path() -> str:
+    """Keyed on the source's CONTENT: a copy of the tree does not keep
+    mtimes, and a library built from another ``codec.cpp`` must never
+    load."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libfpxcodec-{digest}.so")
+
+
+def _build(lib_path: str) -> None:
+    """Compile to a temporary name and rename into place: a dozen roles
+    starting on a fresh checkout may all build at once, and none may
+    load a half-written file."""
+    fd, tmp = tempfile.mkstemp(dir=_DIR, prefix="libfpxcodec-",
+                               suffix=".tmp")
+    os.close(fd)
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
+            check=True, capture_output=True)
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libfpxcodec*.so")):
+        if stale != lib_path:
+            with contextlib.suppress(FileNotFoundError):
+                os.unlink(stale)  # a concurrent builder got there first
+
+
+def require() -> ctypes.CDLL:
+    """The codec library, or ``RuntimeError`` saying why there is none.
+    For processes that must not silently run on the Python mirror."""
+    lib = load()
+    if lib is None:
+        raise RuntimeError(f"native codec unavailable: {_load_error}")
+    return lib
 
 
 def load() -> Optional[ctypes.CDLL]:
     """The codec library, building it if needed; None if unavailable."""
-    global _lib, _load_failed
+    global _lib, _load_failed, _load_error
     if _lib is not None or _load_failed:
         return _lib
     try:
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            _build()
-        lib = ctypes.CDLL(_LIB)
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _build(lib_path)
+        lib = ctypes.CDLL(lib_path)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
         u64p = ctypes.POINTER(ctypes.c_uint64)
@@ -87,8 +124,10 @@ def load() -> Optional[ctypes.CDLL]:
         lib.fpx_reply_columns.argtypes = [
             u8p, ctypes.c_uint64, i64p, ctypes.c_uint32]
         _lib = lib
-    except (OSError, subprocess.CalledProcessError):
+    except (OSError, subprocess.CalledProcessError) as e:
         _load_failed = True
+        stderr = getattr(e, "stderr", b"") or b""
+        _load_error = f"{e!r} {stderr.decode(errors='replace')[-500:]}"
     return _lib
 
 

@@ -80,13 +80,10 @@ def link_keep_mask_jit(src_zones: np.ndarray, dst_zones: np.ndarray,
                        up: np.ndarray) -> np.ndarray:
     """jit-able twin of :func:`link_keep_mask` for schedule-scale
     waves: pads the wave to the next power of two (one XLA program per
-    size bucket) and gathers through the same sentinel-row up-matrix.
-    Falls back to numpy when jax is unavailable."""
+    size bucket) and gathers through the same sentinel-row up-matrix."""
+    import jax
+
     n = src_zones.shape[0]
-    try:
-        import jax
-    except Exception:  # pragma: no cover - jax is baked into the image
-        return link_keep_mask(src_zones, dst_zones, up)
     src_p = _pad_pow2(src_zones.astype(np.int32), UNPLACED_ZONE)
     dst_p = _pad_pow2(dst_zones.astype(np.int32), UNPLACED_ZONE)
     mask = _link_keep_jax(jax.numpy.asarray(src_p),

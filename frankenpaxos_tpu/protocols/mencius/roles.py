@@ -9,10 +9,7 @@ from __future__ import annotations
 import dataclasses
 import random
 
-try:
-    from sortedcontainers import SortedDict  # type: ignore[import-untyped]
-except ImportError:  # stripped environments: pure-Python fallback
-    from frankenpaxos_tpu.utils.sorted_compat import SortedDict
+from sortedcontainers import SortedDict  # type: ignore[import-untyped]
 
 from frankenpaxos_tpu.election.basic import (
     ElectionOptions,

@@ -9,10 +9,8 @@ leader that forwarded it), ``max_voted_slot`` serving quorum reads.
 from __future__ import annotations
 
 import dataclasses
-try:
-    from sortedcontainers import SortedDict  # type: ignore[import-untyped]
-except ImportError:  # stripped environments: pure-Python fallback
-    from frankenpaxos_tpu.utils.sorted_compat import SortedDict
+
+from sortedcontainers import SortedDict  # type: ignore[import-untyped]
 
 from frankenpaxos_tpu.protocols.multipaxos.config import MultiPaxosConfig
 from frankenpaxos_tpu.protocols.multipaxos.messages import (

@@ -58,9 +58,9 @@ class ProxyLeaderOptions:
     # 0 = auto-calibrate to the device platform (see TpuQuorumTracker).
     tpu_min_device_slots: int = 0
     # Pipelined device drains: dispatch this drain's votes async and
-    # emit the PREVIOUS drain's results, hiding the device-link RTT
-    # behind the event loop (one drain of extra choose latency). A
-    # flush timer collects the final dispatch during quiescence.
+    # emit the PREVIOUS drain's results, overlapping the result fetch
+    # with the next drain's decode (one drain of extra choose latency).
+    # A flush timer collects the final dispatch during quiescence.
     tpu_pipelined: bool = False
     tpu_flush_period_s: float = 0.005
     # Reconfiguration (reconfig/): backend for the epoch-segmented
@@ -87,16 +87,32 @@ class ProxyLeader(Actor):
             "multipaxos_proxy_leader_requests_latency_seconds", labels=("type",))
         self.metrics_requests = collectors.counter(
             "multipaxos_proxy_leader_requests_total", labels=("type",))
-        # Pipelined-mode overlap instrumentation (VERDICT r4 weak #2):
-        # how many dispatches are in flight when a new one is queued
-        # (depth 0 = no overlap, the link RTT is serialized per drain)
-        # and how long each device collect blocks the worker thread.
+        # Pipelined-mode overlap instrumentation: how many dispatches
+        # are in flight when a new one is queued (depth 0 = no overlap,
+        # every fetch is serialized behind its drain) and how long each
+        # device collect blocks the worker thread.
         self.metrics_tpu_dispatches = collectors.counter(
             "multipaxos_proxy_leader_tpu_dispatches_total")
         self.metrics_tpu_inflight = collectors.summary(
             "multipaxos_proxy_leader_tpu_inflight_at_dispatch")
         self.metrics_tpu_collect = collectors.summary(
             "multipaxos_proxy_leader_tpu_collect_seconds")
+        # Where the tpu tracker's work went, in both modes (the
+        # tracker's own counts, published after every drain): a "tpu"
+        # run whose drains all went to the host tally left the device
+        # idle, and only these say so.
+        tpu_drains = collectors.counter(
+            "multipaxos_proxy_leader_tpu_drains_total", labels=("path",))
+        tpu_votes = collectors.counter(
+            "multipaxos_proxy_leader_tpu_votes_total", labels=("path",))
+        # In the order _publish_tpu_counts reads the tracker's counts.
+        self.metrics_tpu_work = (
+            tpu_drains.labels("device"), tpu_drains.labels("host"),
+            tpu_votes.labels("device"), tpu_votes.labels("host"),
+            tpu_votes.labels("spilled"),
+            collectors.counter(
+                "multipaxos_proxy_leader_tpu_window_violations_total"))
+        self._tpu_published = (0,) * len(self.metrics_tpu_work)
         self.grid = config.quorum_grid() if config.flexible else None
         self._row_size = len(config.acceptor_addresses[0])
         # paxingest (ingest/): control batch frames of vote acks land
@@ -159,10 +175,9 @@ class ProxyLeader(Actor):
                 # Real transport: fetch device results on ONE daemon
                 # worker thread (preserving dispatch order) and post
                 # each completion back onto the event loop, so the loop
-                # never blocks on the device link. A daemon thread (vs a
+                # never blocks on a device fetch. A daemon thread (vs a
                 # ThreadPoolExecutor, whose threads are joined at
-                # interpreter exit) cannot wedge process shutdown on a
-                # dead device link.
+                # interpreter exit) cannot hold up process shutdown.
                 import queue
                 import threading
 
@@ -514,6 +529,8 @@ class ProxyLeader(Actor):
             self._emit_chosen(self.tracker.drain())
             if self._epoch_tracker is not None:
                 self._emit_chosen(self._epoch_tracker.drain())
+        if self.options.quorum_backend == "tpu":
+            self._publish_tpu_counts()
         if self._collector is not None:
             while True:
                 dispatch = self.tracker.take_dispatch()
@@ -523,7 +540,7 @@ class ProxyLeader(Actor):
                 # Depth includes the dispatch the collector thread is
                 # currently blocked on (it left the queue but is in
                 # flight): a healthy one-deep pipeline must read 1,
-                # not 0 -- 0 means the link RTT is serialized.
+                # not 0 -- 0 means every fetch is serialized.
                 self.metrics_tpu_inflight.observe(
                     self._collector.qsize()
                     + getattr(self, "_collecting", 0))
@@ -534,6 +551,20 @@ class ProxyLeader(Actor):
             self._flush_timer.stop()
             if self.tracker.has_pending():
                 self._flush_timer.start()
+
+    def _publish_tpu_counts(self) -> None:
+        """The tracker's device/host work counts into /metrics, as
+        increments: colocated proxy leaders share one series."""
+        t = self.tracker
+        counts = (t.device_drains, t.host_drains, t.device_votes,
+                  t.host_votes, t.spilled_votes,
+                  t.checker.window_violations)
+        if counts == self._tpu_published:
+            return
+        for series, now, then in zip(self.metrics_tpu_work, counts,
+                                     self._tpu_published):
+            series.inc(now - then)
+        self._tpu_published = counts
 
     def _collect_and_post(self, dispatch) -> None:
         """Runs on the collector thread: block on the device fetch, then

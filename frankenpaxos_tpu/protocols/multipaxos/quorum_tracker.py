@@ -101,10 +101,10 @@ class TpuQuorumTracker(QuorumTracker):
     ``DictQuorumTracker``, the oracle itself) -- SURVEY.md section 7's
     "overflow -> host-side spill path". Drains NARROWER than the
     threshold skip the device entirely and go straight to the host
-    tally: a ~150us fixed device round-trip cannot beat ~0.6us/vote
-    Python below ~100 slots, exactly the small-batch host fallback
-    every accelerator framework keeps. The result: at trickle widths
-    the tracker matches the dict oracle, and past the threshold the
+    tally: a fixed device round-trip cannot beat per-vote Python on a
+    handful of slots, exactly the small-batch host fallback every
+    accelerator framework keeps. The result: at trickle widths the
+    tracker matches the dict oracle, and past the threshold the
     per-drain cost stays flat while the oracle's grows per vote.
 
     **Pipelined.** Every dense run goes through the stateful on-device
@@ -112,11 +112,18 @@ class TpuQuorumTracker(QuorumTracker):
     (returning []) and enqueues an in-flight record; the caller
     collects completed dispatches via :meth:`take_dispatch` +
     :meth:`collect` -- from a worker thread (ProxyLeader posts results
-    back onto the event loop) or a flush timer. This hides the
-    device-link latency behind the event loop -- essential when the
-    accelerator sits across a high-RTT link -- at the cost of one
-    dispatch of added choose latency; the board must see every vote
-    because results are not available within the drain."""
+    back onto the event loop) or a flush timer. This overlaps the
+    device->host fetch of one drain's result with the decode of the
+    next drain's messages, at the cost of one dispatch of added choose
+    latency; the board must see every vote because results are not
+    available within the drain.
+
+    Either way a drain may be decided without the device doing any
+    work, so the tracker COUNTS where its work went: ``device_drains``
+    and the ``device_votes`` they carried went through a kernel,
+    ``host_drains`` and their ``host_votes`` straight to the host
+    tally, and ``spilled_votes`` are the votes of device drains that
+    the stateless check left below quorum and handed to the tally."""
 
     def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
                  pipelined: bool = False, mesh=None,
@@ -125,6 +132,11 @@ class TpuQuorumTracker(QuorumTracker):
 
         self.config = config
         self.pipelined = pipelined
+        self.device_drains = 0
+        self.device_votes = 0
+        self.host_drains = 0
+        self.host_votes = 0
+        self.spilled_votes = 0
         # In-flight dispatches: (slots, rounds, device per-vote masks).
         # append/popleft are GIL-atomic, so a collector thread may pop
         # while the event loop appends.
@@ -178,8 +190,7 @@ class TpuQuorumTracker(QuorumTracker):
         self._host_gc_cap = max(1 << 16, 2 * window)
         # Kernel width buckets. Drains are chunked to these so ONLY the
         # prewarmed widths ever compile -- an unexpected width compiling
-        # mid-run stalls the event loop for seconds over a remote device
-        # link. Dense buckets go wide (a contiguous 4k-slot run is one
+        # mid-run stalls the event loop for seconds. Dense buckets go wide (a contiguous 4k-slot run is one
         # slice+matmul call); the sparse scatter tail stays narrow.
         self.max_chunk = 256
         self.dense_buckets = tuple(
@@ -193,16 +204,16 @@ class TpuQuorumTracker(QuorumTracker):
         # filled; emptier clusters cost fewer device calls via scatter.
         self.min_fill = 0.25
         if min_device_slots <= 0:
-            # Auto-calibrate the host/device routing threshold to the
-            # backend. On a real local TPU a stateless check is tens of
-            # microseconds -- engage it early. On the host-XLA CPU
-            # control, the call itself is ~150us but its AMBIENT cost
-            # on a small host is the real price (kernel execution and
-            # thread-pool churn timeshare with the single-threaded
-            # actor pipeline; measured ~2-4ms of surrounding-pipeline
-            # slowdown per call on a 1-CPU box), so the device must
-            # only engage when a drain carries enough votes to beat
-            # that: ~1k slots.
+            # The host/device routing threshold follows the platform.
+            # On CPU XLA (the tests' control) the call itself is ~150us
+            # but its AMBIENT cost on a small host is the real price
+            # (kernel execution and thread-pool churn timeshare with
+            # the single-threaded actor pipeline; measured ~2-4ms of
+            # surrounding-pipeline slowdown per call on a 1-CPU box),
+            # so the device must only engage when a drain carries
+            # enough votes to beat that: ~1k slots. The TPU value is an
+            # assumption that no chip run has measured yet; moving it
+            # needs a trace of the served path.
             import jax
 
             platform = jax.devices()[0].platform
@@ -314,7 +325,7 @@ class TpuQuorumTracker(QuorumTracker):
             self._spill_ranges(ranges)
             self._spill_arrays(av)
             self._note_frontier(frontier)
-            return self._host_results()
+            return self._host_drain_results(nvotes)
 
         slots = np.asarray(sl, dtype=np.int64)
         cols = np.asarray(cl, dtype=np.int32)
@@ -370,7 +381,7 @@ class TpuQuorumTracker(QuorumTracker):
             self._spill_arrays(av)
             self._spill_votes(slots, cols, rounds)
             self._note_frontier(frontier)
-            return self._host_results()
+            return self._host_drain_results(nvotes)
 
         width = hi - lo + 1
         if width < self.min_device_slots:
@@ -380,7 +391,7 @@ class TpuQuorumTracker(QuorumTracker):
             self._spill_arrays(av)
             self._spill_votes(slots, cols, rounds)
             self._note_frontier(hi)
-            return self._host_results()
+            return self._host_drain_results(nvotes)
 
         # Wide single-round drain: one stateless check per max_dense
         # segment of the span (usually exactly one). Only segments
@@ -432,6 +443,7 @@ class TpuQuorumTracker(QuorumTracker):
                 block[col, s_arr[inseg] - seg_start] = 1
             dispatched.append((seg_start, seg_width, block,
                                self.checker.check_block_async(block)))
+        spilled = 0
         for seg_start, seg_width, block, mask in dispatched:
             hit = np.asarray(mask)[:seg_width]
             touched = block[:, :seg_width].any(axis=0)
@@ -448,10 +460,14 @@ class TpuQuorumTracker(QuorumTracker):
                 # construction), which may complete earlier slots.
                 rcols, rpos = np.nonzero(block[:, :seg_width]
                                          * resid[None, :])
+                spilled += rcols.size
                 for col, pos in zip(rcols.tolist(), rpos.tolist()):
                     g, i = divmod(col, self._row_size)
                     self._host.record(seg_start + pos, rnd0, g, i)
         self._note_frontier(hi)
+        self.device_drains += 1
+        self.device_votes += nvotes
+        self.spilled_votes += spilled
         out.extend(self._host_results())
         return out
 
@@ -487,6 +503,12 @@ class TpuQuorumTracker(QuorumTracker):
             self._host.states = {
                 k: v for k, v in self._host.states.items()
                 if k[0] >= cutoff}
+
+    def _host_drain_results(self, nvotes: int) -> list[tuple[int, int]]:
+        """A drain decided by the host tally alone: count it."""
+        self.host_drains += 1
+        self.host_votes += nvotes
+        return self._host_results()
 
     def _host_results(self) -> list[tuple[int, int]]:
         """Drain the host tally, marking its completions in the dedup
@@ -568,6 +590,8 @@ class TpuQuorumTracker(QuorumTracker):
             slots = np.concatenate(parts_s)
             cols = np.concatenate(parts_c)
             rounds = np.concatenate(parts_r)
+        self.device_drains += 1
+        self.device_votes += slots.shape[0]
 
         # The drain's dominant round (fast path: single-round drain).
         if rounds[0] == rounds[-1] and (rounds == rounds[0]).all():
@@ -668,7 +692,7 @@ class TpuQuorumTracker(QuorumTracker):
         any new kernel width: each piece is decomposed into prewarmed
         bucket widths, and sub-bucket remainders take the (prewarmed)
         scatter path. A mid-run XLA compile would stall the event loop
-        for seconds over a remote device link."""
+        for seconds."""
         self._record_board_bucketed(parts, start, block[:, :room], rnd)
         rest = block[:, room:]
         if rest.any():

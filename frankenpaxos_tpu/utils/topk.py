@@ -12,11 +12,7 @@ import dataclasses
 from typing import Callable, Generic, TypeVar
 
 import numpy as np
-
-try:
-    from sortedcontainers import SortedSet  # type: ignore[import-untyped]
-except ImportError:  # stripped environments: pure-Python fallback
-    from frankenpaxos_tpu.utils.sorted_compat import SortedSet
+from sortedcontainers import SortedSet  # type: ignore[import-untyped]
 
 V = TypeVar("V")
 

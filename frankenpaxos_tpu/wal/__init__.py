@@ -8,7 +8,7 @@ amortizes across the drain exactly like the run pipeline's device
 dispatches ("Paxos in the Cloud" finds durable logging dominates Paxos
 latency unless writes are batched -- PAPERS.md).
 
-The reference keeps no persistence layer at all (VERDICT.md section 5);
+The reference keeps no persistence layer at all;
 this package is the production-scale answer: acceptors recover
 promises/votes/run records and replicas recover an SM snapshot + the
 executed watermark after ``kill -9``, then rejoin the cluster.
