@@ -61,9 +61,12 @@ _UNCHARTED_OK: frozenset = frozenset()
 _CHILD_SUFFIXES = {
     "histogram": ("_bucket", "_sum", "_count"),
     "summary": ("_sum", "_count"),
+    # Read when scraped (Collectors.sampled_summary): no buckets.
+    "sampled_summary": ("_sum", "_count"),
 }
 
-_COLLECTOR_METHODS = ("counter", "gauge", "histogram", "summary")
+_COLLECTOR_METHODS = ("counter", "gauge", "histogram", "summary",
+                      "sampled_summary")
 
 #: A series token: fpx_ followed by snake_case, not ending in ``_``
 #: (so a bare ``fpx_runtime_`` prefix in prose never matches).

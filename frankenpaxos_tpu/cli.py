@@ -218,7 +218,12 @@ def main(argv=None) -> None:
     if collectors is not None:
         from frankenpaxos_tpu.obs import RuntimeMetrics
 
-        transport.runtime_metrics = RuntimeMetrics(collectors, label)
+        # The chip owner's stages are also annotations on the device
+        # trace's clock while one runs (obs/trace.py); no other process
+        # imports JAX's profiler for it.
+        transport.runtime_metrics = RuntimeMetrics(
+            collectors, label, device_clock=owns_chip)
+        transport.runtime_metrics.watch_gc()
     if args.trace:
         import atexit
         import os

@@ -7,8 +7,9 @@ TPU209 enforces that):
     propagated at the transport FRAME layer (the wire tag space 1..127
     is fully allocated, so the context rides the frame header, not the
     message codecs) plus the Tracer that emits receive/timer/drain
-    spans with drain-stage sub-spans (decode, handler, quorum-kernel,
-    wal-fsync, send-release).
+    spans with stage sub-spans (decode, handler, drain, fan-out,
+    wal-fsync, send-release, ...), and the per-thread stage
+    accounting behind ``fpx_runtime_drain_stage_seconds``.
   * ``flight`` -- a fixed-size per-role flight recorder ring buffer
     over an mmap'd file: the OS keeps the dirty pages when the process
     is SIGKILL'd, so a crashed role still leaves a record of its last

@@ -15,9 +15,19 @@ and one per ``on_drain``. The drain span adopts the context of the
 LAST sampled message delivered in its batch (group commit serves a
 batch; the adopted command's critical path runs through its batch's
 drain). Inside handlers and drains, ``Actor.trace_stage`` opens
-drain-stage sub-spans -- decode, handler, quorum-kernel, wal-fsync,
-send-release -- the stages the latency-breakdown table attributes
-per-command time to.
+drain-stage sub-spans -- decode, handler, drain, fan-out, wal-fsync,
+send-release, ... (docs/OBSERVABILITY.md has the table) -- the stages
+the latency-breakdown table attributes per-command time to.
+
+STAGE ACCOUNTING: every stage scope, traced or not, is one ``_Stage``:
+two clock reads and a few adds into an accumulator that belongs to
+the thread doing the work. A scope opened inside another on the same
+thread subtracts from it, so the accumulators hold SELF time and a
+thread's stages add up. ``fpx_runtime_drain_stage_seconds_{sum,count}
+{role,stage}`` is produced from the accumulators when /metrics is
+scraped. In the process that owns the chip, while a device trace
+runs, each scope is also a profiler annotation ``fpx.<stage>``, which
+puts the program's stages on the device trace's clock.
 
 DETERMINISM: ids come from a per-role counter (salted with a CRC of
 the role name so roles never collide), and the clock is injectable --
@@ -33,7 +43,9 @@ made ONCE, at the root) but never read the clock or allocate records.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
+import threading
 import time
 from typing import Callable, Optional
 import zlib
@@ -111,20 +123,166 @@ class VirtualClock:
         return self.now
 
 
+class _Stage:
+    """One stage's accumulator on one thread, and the scope that feeds
+    it: reused by every batch of work of that stage on that thread, so
+    a closed scope costs two clock reads and a few adds -- no lock, no
+    label lookup, no object. ``sum`` holds SELF time: what a scope
+    opened inside this one (same thread) took is subtracted, and this
+    scope's whole duration is handed to the one it sits in.
+
+    In the process that owns the chip, while a device trace runs
+    (``thread.annotation``, see _StageThread.refresh), the scope is
+    also a ``jax.profiler.TraceAnnotation`` named ``fpx.<stage>`` on
+    the thread that does the work: the one place a stage becomes an
+    annotation. With no trace running none is built; building one
+    costs more than the whole scope."""
+
+    __slots__ = ("thread", "name", "sum", "count", "t0", "outer",
+                 "depth", "saved", "also", "sticky", "span")
+
+    def __init__(self, thread: "_StageThread", name: str):
+        self.thread = thread
+        self.name = name
+        self.sum = 0.0
+        self.count = 0
+        self.depth = 0
+        self.saved: list = []  # the outer activations, if re-entered
+        # A second series over the same clock pair (a Summary or
+        # Histogram child): it is given the scope's WHOLE duration
+        # when the scope closes. ``sticky`` keeps it for every scope
+        # (wal-fsync's histogram); otherwise it is for the open scope
+        # only (RuntimeMetrics.share_clock).
+        self.also = None
+        self.sticky = False
+        self.span = None
+
+    def __enter__(self) -> "_Stage":
+        thread = self.thread
+        if self.depth:
+            self.saved.append((self.t0, self.outer, self.span))
+            self.span = None
+        self.depth += 1
+        if thread.annotation is not None:
+            self.span = self.open_span()
+        # Nothing between these three lines allocates, so no garbage
+        # collection (a scope of its own, stage ``gc``) can land
+        # between the hand-over of ``child`` and the clock reading.
+        self.outer = thread.child
+        thread.child = 0.0
+        self.t0 = thread.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        thread = self.thread
+        dur = thread.clock() - self.t0
+        self.sum += dur - thread.child
+        self.count += 1
+        thread.child = self.outer + dur
+        if self.span is not None:
+            self.span.__exit__(None, None, None)
+            self.span = None
+        if self.also is not None:
+            self.also.observe(dur)
+            if not self.sticky:
+                self.also = None
+        self.depth -= 1
+        if self.depth:
+            self.t0, self.outer, self.span = self.saved.pop()
+        return False
+
+    def open_span(self):
+        """While a device trace runs (``thread.annotation`` is set):
+        this stage's annotation, entered. The caller exits it."""
+        span = self.thread.annotation(f"fpx.{self.name}")
+        span.__enter__()
+        return span
+
+    def add(self, dur_s: float) -> None:
+        """An observation timed elsewhere: a wait that began on another
+        thread, or work whose clock pair was taken by hand. It is no
+        scope, so it subtracts from nothing."""
+        self.sum += dur_s
+        self.count += 1
+
+
+class _StageThread:
+    """The stage accumulators of ONE thread. Only that thread opens
+    their scopes, so none needs a lock; a scrape reads them from its
+    own thread, a float or an int at a time."""
+
+    __slots__ = ("clock", "child", "stages", "gc", "annotation",
+                 "_annotate")
+
+    def __init__(self, clock: Callable[[], float], annotate=None):
+        self.clock = clock
+        # Seconds taken by the scopes closed so far inside the
+        # innermost open one: what that one subtracts when it closes.
+        self.child = 0.0
+        self.stages: dict[str, _Stage] = {}
+        # ``annotate`` is jax.profiler.TraceAnnotation in the process
+        # that owns the chip and None everywhere else; ``annotation``
+        # is it while a device trace runs.
+        self._annotate = annotate
+        self.annotation = None
+        self.gc = self.stage("gc")
+
+    def stage(self, name: str) -> _Stage:
+        stage = self.stages.get(name)
+        if stage is None:
+            stage = self.stages[name] = _Stage(self, name)
+        return stage
+
+    def refresh(self) -> None:
+        """Learn whether a device trace is running. Cheap but not
+        free, so a thread asks once a batch of scopes (the loop once a
+        selector wait, a collector thread once a collection) and its
+        scopes read the answer."""
+        annotate = self._annotate
+        if annotate is not None:
+            self.annotation = annotate if annotate.is_enabled() else None
+
+
 class RuntimeMetrics:
     """The drain-granular runtime metrics every role exports when the
-    metrics endpoint is on (with or without tracing): drain-stage
-    latency histograms, inbound queue depth (messages per drain
-    batch), and WAL group-commit fsync latency. These feed the shared
-    "runtime" Grafana row and the promdb scrapes."""
+    metrics endpoint is on (with or without tracing): per-stage self
+    time, inbound queue depth (messages per drain batch), and WAL
+    group-commit fsync latency. These feed the shared "runtime"
+    Grafana row and the promdb scrapes.
 
-    def __init__(self, collectors, role: str):
+    Stage time lives in per-thread accumulators (_StageThread): the
+    event loop's, which ``stage`` serves with no thread lookup, and
+    one for every helper thread that asks (``thread_stages``). The
+    series ``fpx_runtime_drain_stage_seconds_{sum,count}{role,stage}``
+    are made from them when scraped, summed over the threads."""
+
+    def __init__(self, collectors, role: str,
+                 clock: Callable[[], float] = time.perf_counter,
+                 device_clock: bool = False):
+        """``device_clock``: this process owns the chip, so while a
+        device trace runs its stage scopes are also profiler
+        annotations (_Stage). No other process imports JAX for it."""
         self.role = role
-        self._stage_hist = collectors.histogram(
+        self.clock = clock
+        annotate = None
+        if device_clock:
+            from jax.profiler import TraceAnnotation as annotate
+        self._annotate = annotate
+        self._loop = _StageThread(clock, annotate)
+        #: A scope of stage ``name`` on the EVENT LOOP's thread (the
+        #: only thread that may call this).
+        self.stage = self._loop.stage
+        # Every thread's accumulators, by thread ident: the loop's once
+        # bind_loop_thread has run, the helpers' own, and one for each
+        # other thread a garbage collection was seen on.
+        self._threads: list[_StageThread] = [self._loop]
+        self._by_ident: dict[int, _StageThread] = {}
+        collectors.sampled_summary(
             "fpx_runtime_drain_stage_seconds",
-            help="Per-drain-stage latency (decode/handler/quorum-kernel/"
-                 "wal-fsync/send-release)",
-            labels=("role", "stage"))
+            help="Self time per stage of the event loop and its "
+                 "helper threads (decode/handler/drain/flush/"
+                 "loop-wait/...); *-wait stages are time waited",
+            labels=("role", "stage"), read=self.read_stages)
         self._depth_gauge = collectors.gauge(
             "fpx_runtime_inbound_queue_depth",
             help="Messages delivered in the current drain batch",
@@ -133,7 +291,8 @@ class RuntimeMetrics:
             "fpx_runtime_wal_fsync_seconds",
             help="WAL group-commit fsync latency (one per drain)",
             labels=("role",)).labels(role)
-        self._stage_children: dict = {}
+        fsync = self._loop.stage("wal-fsync")
+        fsync.also, fsync.sticky = self._fsync_hist, True
         # paxload (serve/): the admission/backpressure families every
         # /metrics role exports -- registered here (not lazily) so the
         # series exist at zero on every role, admission enabled or not
@@ -338,13 +497,74 @@ class RuntimeMetrics:
         self._pipe_children: dict = {}
 
     def observe_stage(self, stage: str, dur_s: float) -> None:
-        child = self._stage_children.get(stage)
-        if child is None:
-            child = self._stage_hist.labels(self.role, stage)
-            self._stage_children[stage] = child
-        child.observe(dur_s)
-        if stage == "wal-fsync":
-            self._fsync_hist.observe(dur_s)
+        """Record ``dur_s`` under ``stage`` after the fact, on the
+        event loop's thread: a wait that ended there."""
+        self._loop.stage(stage).add(dur_s)
+
+    def loop_wait(self) -> _Stage:
+        """The scope of one selector wait of the event loop. The loop
+        passes here once a pass, so this is also where its thread
+        learns whether a device trace is running."""
+        loop = self._loop
+        loop.refresh()
+        return loop.stage("loop-wait")
+
+    def share_clock(self, stage: str, sink) -> bool:
+        """Have the scope of ``stage`` that is open on the event
+        loop's thread also give its whole duration to ``sink.observe``
+        when it closes, so that a second series over the same call
+        needs no clock reads of its own. False, and nothing attached,
+        when no such scope is open or it already feeds a sink."""
+        open_stage = self._loop.stages.get(stage)
+        if open_stage is None or not open_stage.depth \
+                or open_stage.also is not None:
+            return False
+        open_stage.also = sink
+        return True
+
+    def thread_stages(self) -> _StageThread:
+        """Accumulators of their own for the CALLING thread (a
+        collector thread): it alone opens their scopes, and their
+        time shows under the same series as the loop's."""
+        thread = _StageThread(self.clock, self._annotate)
+        self._threads.append(thread)
+        self._by_ident[threading.get_ident()] = thread
+        return thread
+
+    def bind_loop_thread(self) -> None:
+        """Called on the event loop's thread: a garbage collection that
+        stops this thread is taken out of the loop's open stage."""
+        self._by_ident[threading.get_ident()] = self._loop
+
+    def watch_gc(self) -> None:
+        """Account every garbage collection of this process as stage
+        ``gc``, start to stop, on the thread it stopped. It nests, so
+        its time is taken OUT of the stage it interrupted."""
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        thread = self._by_ident.get(threading.get_ident())
+        if thread is None:
+            # A thread that opens no stage of its own (the /metrics
+            # server's, the main thread).
+            thread = self.thread_stages()
+        if phase == "start":
+            thread.gc.__enter__()
+        elif thread.gc.depth:
+            thread.gc.__exit__()
+
+    def read_stages(self) -> dict:
+        """``{(role, stage): (seconds, observations)}`` as of now, over
+        all threads: what a scrape exports."""
+        out: dict = {}
+        for thread in list(self._threads):
+            for stage in list(thread.stages.values()):
+                if stage.count:
+                    key = (self.role, stage.name)
+                    seconds, count = out.get(key, (0.0, 0))
+                    out[key] = (seconds + stage.sum,
+                                count + stage.count)
+        return out
 
     def observe_batch(self, depth: int) -> None:
         self._depth_gauge.set(depth)
@@ -501,15 +721,20 @@ class _Scope:
     so sends made inside it propagate its context."""
 
     __slots__ = ("tracer", "name", "cat", "ctx", "parent_id", "prev",
-                 "t0", "m0")
+                 "t0", "m0", "timed")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 ctx: TraceContext, parent_id: int):
+                 ctx: TraceContext, parent_id: int,
+                 timed: "Optional[_Stage]" = None):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.ctx = ctx
         self.parent_id = parent_id
+        # A sampled stage span's accounting half: the same _Stage an
+        # untraced stage is, so traced and untraced runs feed the
+        # accumulators alike.
+        self.timed = timed
 
     def __enter__(self) -> "_Scope":
         tracer = self.tracer
@@ -523,9 +748,13 @@ class _Scope:
             # t0 stays on the shared wall clock so role tracks align.
             self.m0 = (self.t0 if tracer.mono is tracer.clock
                        else tracer.mono())
+        if self.timed is not None:
+            self.timed.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self.timed is not None:
+            self.timed.__exit__()
         tracer = self.tracer
         tracer.current = self.prev
         if self.ctx.sampled:
@@ -536,29 +765,6 @@ class _Scope:
                 t0=self.t0, dur=m1 - self.m0,
                 trace_id=self.ctx.trace_id, span_id=self.ctx.span_id,
                 parent_id=self.parent_id))
-            if self.cat == "stage" and tracer.runtime_metrics is not None:
-                tracer.runtime_metrics.observe_stage(
-                    self.name[len("stage:"):], m1 - self.m0)
-        return False
-
-
-class _MetricStage:
-    """Stage timing with metrics only (tracing off but /metrics on):
-    feeds the drain-stage histogram without emitting spans."""
-
-    __slots__ = ("metrics", "stage", "t0")
-
-    def __init__(self, metrics: RuntimeMetrics, stage: str):
-        self.metrics = metrics
-        self.stage = stage
-
-    def __enter__(self) -> "_MetricStage":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        self.metrics.observe_stage(self.stage,
-                                   time.perf_counter() - self.t0)
         return False
 
 
@@ -577,12 +783,13 @@ NOOP_SCOPE = _Noop()
 
 def stage_scope(tracer: "Optional[Tracer]",
                 metrics: Optional[RuntimeMetrics], name: str):
-    """The one stage-timing entry point (Actor.trace_stage): a traced
-    sub-span, a metrics-only timer, or a shared no-op."""
+    """A stage scope on the event loop's thread (what
+    Actor.trace_stage opens): a traced sub-span over the stage's
+    accumulator, the accumulator's scope alone, or a shared no-op."""
     if tracer is not None:
         return tracer.stage(name)
     if metrics is not None:
-        return _MetricStage(metrics, name)
+        return metrics.stage(name)
     return NOOP_SCOPE
 
 
@@ -689,25 +896,28 @@ class Tracer:
 
     def stage(self, name: str):
         """A drain-stage sub-span under the current context (decode,
-        handler, quorum-kernel, wal-fsync, send-release)."""
+        handler, drain, fan-out, wal-fsync, send-release, ...)."""
         parent = self.current
+        metrics = self.runtime_metrics
         if parent is None or not parent.sampled:
             # No span for unsampled work -- but the RUNTIME METRICS
             # must not be starved by the sampling rate (the Grafana
-            # row charts every fsync, not 1-in-N), so fall back to the
-            # metrics-only timer when one is attached. It leaves
-            # ``current`` untouched, which matches the unsampled span
-            # behavior exactly: an unsampled stage reuses the parent
-            # context anyway.
-            if self.runtime_metrics is not None:
-                return _MetricStage(self.runtime_metrics, name)
+            # row charts every fsync, not 1-in-N), so the stage's
+            # accumulator scope stands alone when one is attached. It
+            # leaves ``current`` untouched, which matches the
+            # unsampled span behavior exactly: an unsampled stage
+            # reuses the parent context anyway.
+            if metrics is not None:
+                return metrics.stage(name)
             ctx = parent if parent is not None else TraceContext(
                 trace_id=0, span_id=0, sampled=False)
             return _Scope(self, f"stage:{name}", "stage", ctx, 0)
         ctx = TraceContext(trace_id=parent.trace_id,
                            span_id=self._new_id(), sampled=True)
         return _Scope(self, f"stage:{name}", "stage", ctx,
-                      parent.span_id)
+                      parent.span_id,
+                      metrics.stage(name) if metrics is not None
+                      else None)
 
     def record_stage(self, name: str, m0: float,
                      ctx: Optional[TraceContext]) -> None:
@@ -715,7 +925,8 @@ class Tracer:
         ``tracer.mono()`` reading from its start): the TCP receive
         path times message decode before any span scope can be open,
         because decode errors must stay inside the transport's
-        corrupt-frame guard."""
+        corrupt-frame guard. A span only: the stage's time on
+        /metrics is the scope the transport holds open around it."""
         if ctx is None or not ctx.sampled:
             return
         if self.mono is self.clock:
@@ -728,8 +939,6 @@ class Tracer:
             name=f"stage:{name}", cat="stage", role=self.role,
             t0=t0, dur=dur, trace_id=ctx.trace_id,
             span_id=self._new_id(), parent_id=ctx.span_id))
-        if self.runtime_metrics is not None:
-            self.runtime_metrics.observe_stage(name, dur)
 
     def event(self, text: str) -> None:
         """An instantaneous flight-recorder note (crash post-mortems:

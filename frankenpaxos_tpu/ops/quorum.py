@@ -167,6 +167,16 @@ def _predicate_hit(votes_block: jax.Array, masks_t: tuple,
     return _quorum_hit(votes_block, masks, thresholds, combine_any)
 
 
+def _named(name: str):
+    """Give a function a fixed name before it is jitted: a device trace
+    then shows ``jit_<name>`` for the tracker's entry points, whatever
+    the Python functions are called after a refactor."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return rename
+
+
 def _apply_sparse_votes(board: VoteBoard, slots, true_slots, nodes,
                         vote_rounds, valid):
     """Shared traced body of the sparse scatter kernels: ring
@@ -211,6 +221,7 @@ def _apply_sparse_votes(board: VoteBoard, slots, true_slots, nodes,
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(6, 7))
+@_named("fpx_quorum_record_votes")
 def _record_and_check(
     board: VoteBoard,
     slots: jax.Array,      # [B] int32, already reduced mod window
@@ -234,6 +245,7 @@ def _record_and_check(
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@_named("fpx_quorum_record_votes_epochs")
 def _record_and_check_epochs(
     board: VoteBoard,
     slots: jax.Array,        # [B] int32, reduced mod window
@@ -264,6 +276,7 @@ def _record_and_check_epochs(
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(5, 6, 7))
+@_named("fpx_quorum_record_block")
 def _record_block(
     board: VoteBoard,
     start: jax.Array,        # [] int32 ring offset of the block
@@ -287,43 +300,56 @@ def _record_block(
     """
     n = board.votes.shape[0]
 
-    touched = block.any(axis=0)                                # [B]
-    # Ring self-reclaim (see VoteBoard): claim columns still owned by an
-    # older slot; drop votes for slots the column has moved past.
-    slot_ids = true_start + jnp.arange(block_size, dtype=jnp.int32)
-    old_owner = jax.lax.dynamic_slice(board.owner, (start,), (block_size,))
-    claim = touched & (slot_ids > old_owner)
-    stale = touched & (slot_ids < old_owner)
-    touched = touched & ~stale
-    new_owner = jnp.where(claim, slot_ids, old_owner)
-    block = block & touched[None, :].astype(jnp.uint8)
+    # named_scope: a trace read in Perfetto maps each device operation
+    # (the copies and dynamic_update_slices) to one of these three.
+    with jax.named_scope("owner-update"):
+        touched = block.any(axis=0)                            # [B]
+        # Ring self-reclaim (see VoteBoard): claim columns still owned
+        # by an older slot; drop votes for slots the column has moved
+        # past.
+        slot_ids = true_start + jnp.arange(block_size, dtype=jnp.int32)
+        old_owner = jax.lax.dynamic_slice(board.owner, (start,),
+                                          (block_size,))
+        claim = touched & (slot_ids > old_owner)
+        stale = touched & (slot_ids < old_owner)
+        touched = touched & ~stale
+        new_owner = jnp.where(claim, slot_ids, old_owner)
+        block = block & touched[None, :].astype(jnp.uint8)
+        owner = jax.lax.dynamic_update_slice(board.owner, new_owner,
+                                             (start,))
 
-    old_rounds = jax.lax.dynamic_slice(board.rounds, (start,), (block_size,))
-    old_rounds = jnp.where(claim, jnp.int32(-1), old_rounds)
-    new_rounds = jnp.where(touched,
-                           jnp.maximum(old_rounds, vote_round), old_rounds)
-    preempted = new_rounds > old_rounds
-    cols = jax.lax.dynamic_slice(board.votes, (0, start), (n, block_size))
-    cols = jnp.where((claim | preempted)[None, :], jnp.uint8(0), cols)
-    live = touched & (vote_round == new_rounds)                # [B]
-    cols = cols | (block & live[None, :].astype(jnp.uint8))
+    with jax.named_scope("record"):
+        old_rounds = jax.lax.dynamic_slice(board.rounds, (start,),
+                                           (block_size,))
+        old_rounds = jnp.where(claim, jnp.int32(-1), old_rounds)
+        new_rounds = jnp.where(touched,
+                               jnp.maximum(old_rounds, vote_round),
+                               old_rounds)
+        preempted = new_rounds > old_rounds
+        cols = jax.lax.dynamic_slice(board.votes, (0, start),
+                                     (n, block_size))
+        cols = jnp.where((claim | preempted)[None, :], jnp.uint8(0), cols)
+        live = touched & (vote_round == new_rounds)            # [B]
+        cols = cols | (block & live[None, :].astype(jnp.uint8))
+        votes = jax.lax.dynamic_update_slice(board.votes, cols,
+                                             (0, start))
+        rounds = jax.lax.dynamic_update_slice(board.rounds, new_rounds,
+                                              (start,))
 
-    hit = _predicate_hit(cols, masks_t, meta)
-    old_chosen = jax.lax.dynamic_slice(board.chosen, (start,), (block_size,))
-    old_chosen = jnp.where(claim, False, old_chosen)
-    newly = hit & ~old_chosen & touched
-    return VoteBoard(
-        votes=jax.lax.dynamic_update_slice(board.votes, cols, (0, start)),
-        rounds=jax.lax.dynamic_update_slice(board.rounds, new_rounds,
-                                            (start,)),
-        chosen=jax.lax.dynamic_update_slice(board.chosen, hit | old_chosen,
-                                            (start,)),
-        owner=jax.lax.dynamic_update_slice(board.owner, new_owner,
-                                           (start,)),
-    ), newly
+    with jax.named_scope("check"):
+        hit = _predicate_hit(cols, masks_t, meta)
+        old_chosen = jax.lax.dynamic_slice(board.chosen, (start,),
+                                           (block_size,))
+        old_chosen = jnp.where(claim, False, old_chosen)
+        newly = hit & ~old_chosen & touched
+        chosen = jax.lax.dynamic_update_slice(board.chosen,
+                                              hit | old_chosen, (start,))
+    return VoteBoard(votes=votes, rounds=rounds, chosen=chosen,
+                     owner=owner), newly
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@_named("fpx_quorum_release")
 def _release(board: VoteBoard, slots: jax.Array, valid: jax.Array) -> VoteBoard:
     """Reset columns for GC'd slots so the ring can wrap
     (BufferMap.scala:55-62)."""
@@ -345,6 +371,7 @@ def _check_batch(present: jax.Array, masks_t: tuple, meta: tuple) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
+@_named("fpx_quorum_check_block")
 def _check_block(block: jax.Array, masks_t: tuple, meta: tuple) -> jax.Array:
     """``[N, B]`` slot-major vote block -> ``[B]`` bool (stateless).
 

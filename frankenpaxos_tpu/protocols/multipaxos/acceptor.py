@@ -176,8 +176,7 @@ class Acceptor(Actor, DurableRole):
     def receive(self, src: Address, message) -> None:
         # timed(label) handler latency summaries (Leader.scala:281-293).
         if self.options.measure_latencies:
-            with self.metrics_latency.labels(
-                    type(message).__name__).time():
+            with self.receive_timer(self.metrics_latency, message):
                 self._receive_impl(src, message)
         else:
             self._receive_impl(src, message)

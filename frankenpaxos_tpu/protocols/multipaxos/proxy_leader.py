@@ -17,6 +17,7 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import random
+import time
 
 import numpy as np
 
@@ -124,7 +125,7 @@ class ProxyLeader(Actor):
 
         self.wire_sinks = {
             CONTROL_BATCH_TAG: (parse_ack_batch,
-                                self._handle_ack_columns),
+                                self._handle_ack_columns, "vote-intake"),
         }
         # (slot, round) -> pending value; moved to _done once chosen.
         self.pending: dict[tuple[int, int], object] = {}
@@ -188,11 +189,19 @@ class ProxyLeader(Actor):
                 self._collecting = 0
 
                 def collect_loop():
+                    # This thread's own stage accumulators (the loop's
+                    # belong to the loop), where /metrics is on.
+                    metrics = self.transport.runtime_metrics
+                    stages = (None if metrics is None
+                              else metrics.thread_stages())
                     while True:
-                        dispatch = self._collector.get()
+                        dispatch, queued_at = self._collector.get()
+                        if stages is not None:
+                            stages.stage("dispatch-wait").add(
+                                time.perf_counter() - queued_at)
                         self._collecting = 1
                         try:
-                            self._collect_and_post(dispatch)
+                            self._collect_and_post(dispatch, stages)
                         finally:
                             self._collecting = 0
 
@@ -213,8 +222,7 @@ class ProxyLeader(Actor):
     def receive(self, src: Address, message) -> None:
         # timed(label) handler latency summaries (Leader.scala:281-293).
         if self.options.measure_latencies:
-            with self.metrics_latency.labels(
-                    type(message).__name__).time():
+            with self.receive_timer(self.metrics_latency, message):
                 self._receive_impl(src, message)
         else:
             self._receive_impl(src, message)
@@ -515,20 +523,36 @@ class ProxyLeader(Actor):
         as ranges)."""
         from frankenpaxos_tpu import native
 
-        slots, rounds = native.unpack_votes2(m.packed)
-        if self._epoch_tracker is not None:
-            self._epoch_tracker.record_votes(slots, rounds, src)
-            return
-        self.tracker.record_votes(slots, rounds, m.group_index,
-                                  m.acceptor_index)
+        with self.trace_stage("vote-intake"):
+            slots, rounds = native.unpack_votes2(m.packed)
+            if self._epoch_tracker is not None:
+                self._epoch_tracker.record_votes(slots, rounds, src)
+                return
+            self.tracker.record_votes(slots, rounds, m.group_index,
+                                      m.acceptor_index)
 
     def on_drain(self) -> None:
-        # paxtrace drain stage: the batched quorum check (dict tracker
-        # or TPU kernel dispatch) plus the Chosen emission it unlocks.
-        with self.trace_stage("quorum-kernel"):
-            self._emit_chosen(self.tracker.drain())
-            if self._epoch_tracker is not None:
-                self._emit_chosen(self._epoch_tracker.drain())
+        # Stage ``drain``: the batched quorum check of a drain that had
+        # votes (dict tally, or the tpu tracker's host side and its
+        # kernel dispatch) and the hand-over of what it dispatched.
+        # What it unlocks goes out under ``fan-out``.
+        if self.tracker.has_votes():
+            with self.trace_stage("drain"):
+                chosen = self.tracker.drain()
+                self._hand_over_dispatches()
+            self._emit_chosen(chosen)
+        else:
+            self._hand_over_dispatches()
+        if self._epoch_tracker is not None \
+                and self._epoch_tracker.has_votes():
+            with self.trace_stage("drain"):
+                chosen = self._epoch_tracker.drain()
+            self._emit_chosen(chosen)
+
+    def _hand_over_dispatches(self) -> None:
+        """After a drain: publish the tracker's counts, and pass what
+        it dispatched to the collector thread (or arm the sim's flush
+        timer)."""
         if self.options.quorum_backend == "tpu":
             self._publish_tpu_counts()
         if self._collector is not None:
@@ -544,7 +568,9 @@ class ProxyLeader(Actor):
                 self.metrics_tpu_inflight.observe(
                     self._collector.qsize()
                     + getattr(self, "_collecting", 0))
-                self._collector.put(dispatch)
+                # With the time it was queued: the collector thread
+                # accounts the wait as ``dispatch-wait``.
+                self._collector.put((dispatch, time.perf_counter()))
         elif self._flush_timer is not None:
             # (Re)arm the quiescence flush while a dispatch is in
             # flight; the timer collects it if no further messages come.
@@ -566,15 +592,33 @@ class ProxyLeader(Actor):
             series.inc(now - then)
         self._tpu_published = counts
 
-    def _collect_and_post(self, dispatch) -> None:
+    def _collect_and_post(self, dispatch, stages) -> None:
         """Runs on the collector thread: block on the device fetch, then
-        hand the results back to the single-threaded event loop."""
+        hand the results back to the single-threaded event loop.
+        ``stages`` are this thread's stage accumulators, or None."""
         try:
-            with self.metrics_tpu_collect.time():
-                results = self.tracker.collect(dispatch)
+            # Stage ``collect`` and the collect summary share ONE clock
+            # pair, taken by hand: between collect() returning and the
+            # hand-back nothing is done but the second reading (and,
+            # while a device trace runs, closing the annotation); the
+            # adds come after the results are on their way.
+            stage = span = None
+            if stages is not None:
+                stage = stages.stage("collect")
+                stages.refresh()
+                if stages.annotation is not None:
+                    span = stage.open_span()
+            t0 = time.perf_counter()
+            results = self.tracker.collect(dispatch)
+            t1 = time.perf_counter()
+            if span is not None:
+                span.__exit__(None, None, None)
             if results:
                 self.transport.loop.call_soon_threadsafe(
-                    self._emit_chosen, results)
+                    self._emit_handed_back, results, t1)
+            if stage is not None:
+                stage.add(t1 - t0)
+            self.metrics_tpu_collect.observe(t1 - t0)
         except RuntimeError as e:
             # Loop closed during teardown: dropping in-flight results is
             # expected, but say so.
@@ -584,6 +628,15 @@ class ProxyLeader(Actor):
             # dispatch's Chosen broadcasts and wedge its clients.
             self.logger.error(f"tpu collect failed: {e!r}")
 
+    def _emit_handed_back(self, keys, collected_at: float) -> None:
+        """On the event loop: what a collector thread fetched. Stage
+        ``handback-wait`` is the time it waited for the loop."""
+        metrics = self.transport.runtime_metrics
+        if metrics is not None:
+            metrics.observe_stage("handback-wait",
+                                  time.perf_counter() - collected_at)
+        self._emit_chosen(keys)
+
     def _collect_all(self) -> None:
         while True:
             dispatch = self.tracker.take_dispatch()
@@ -592,11 +645,16 @@ class ProxyLeader(Actor):
             self._emit_chosen(self.tracker.collect(dispatch))
 
     def _emit_chosen(self, keys) -> None:
-        if self._runs and len(keys) > 1:
-            self._emit_chosen_grouped(keys)
+        if not keys:
             return
-        for key in keys:
-            self._emit_one(key)
+        # Stage ``fan-out``: ChosenRun build + broadcast, one scope for
+        # everything a drain or a collect reported.
+        with self.trace_stage("fan-out"):
+            if self._runs and len(keys) > 1:
+                self._emit_chosen_grouped(keys)
+                return
+            for key in keys:
+                self._emit_one(key)
 
     def _emit_one(self, key) -> None:
         value = self.pending.pop(key, None)

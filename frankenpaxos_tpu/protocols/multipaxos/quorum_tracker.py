@@ -51,6 +51,11 @@ class QuorumTracker(abc.ABC):
             self.record(int(slot), int(round), group_index,
                         acceptor_index)
 
+    def has_votes(self) -> bool:
+        """Would :meth:`drain` have work? A drain without is neither
+        called nor timed (stage ``drain``). Default: always."""
+        return True
+
     @abc.abstractmethod
     def drain(self) -> list[tuple[int, int]]:
         """Flush buffered votes; return [(slot, round)] newly at quorum."""
@@ -83,6 +88,9 @@ class DictQuorumTracker(QuorumTracker):
                 return
         self.states[key] = None  # Done
         self._newly.append(key)
+
+    def has_votes(self) -> bool:
+        return bool(self._newly)
 
     def drain(self) -> list[tuple[int, int]]:
         newly, self._newly = self._newly, []
@@ -272,11 +280,13 @@ class TpuQuorumTracker(QuorumTracker):
             (slots, group_index * self._row_size + acceptor_index,
              np.asarray(rounds, dtype=np.int32)))
 
+    def has_votes(self) -> bool:
+        return bool(self._slots or self._ranges or self._array_votes)
+
     def drain(self) -> list[tuple[int, int]]:
         """At most a few device calls (usually one, often zero) per
         event-loop drain; see the class docstring for the two modes."""
-        if not self._slots and not self._ranges \
-                and not self._array_votes:
+        if not self.has_votes():
             return []
         if self.pipelined:
             return self._drain_pipelined()

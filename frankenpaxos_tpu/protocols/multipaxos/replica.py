@@ -383,8 +383,7 @@ class Replica(Actor, DurableRole):
     def receive(self, src: Address, message) -> None:
         # timed(label) handler latency summaries (Leader.scala:281-293).
         if self.options.measure_latencies:
-            with self.metrics_latency.labels(
-                    type(message).__name__).time():
+            with self.receive_timer(self.metrics_latency, message):
                 self._receive_impl(src, message)
         else:
             self._receive_impl(src, message)
@@ -470,7 +469,8 @@ class Replica(Actor, DurableRole):
                            all_new=all_new, encode=encode_value_array)
 
     def _handle_chosen(self, src: Address, chosen: Chosen) -> None:
-        """(Replica.scala:572-628)."""
+        """(Replica.scala:572-628). One command a message, so it opens
+        no stage of its own: its time is the transport's ``handler``."""
         if self._log_chosen(chosen.slot, (chosen.value,)) == 0:
             return  # duplicate Chosen
         if self.wal is not None:
@@ -490,30 +490,36 @@ class Replica(Actor, DurableRole):
     def _handle_chosen_run(self, src: Address, run: ChosenRun) -> None:
         """A contiguous drain of chosen values in one message: log the
         whole run, execute once, and ship each client ONE reply array
-        for the drain instead of one ClientReply per command."""
-        new = self._log_chosen(run.start_slot, run.values)
-        if new == 0:
-            return
-        if self.wal is not None:
-            self._wal_log_chosen_run(run.start_slot, run.values,
-                                     all_new=(new == len(run.values)))
-        replies = self._execute_log()
+        for the drain instead of one ClientReply per command. Stages
+        ``log``, ``execute`` and ``reply``, one scope each a run."""
+        with self.trace_stage("log"):
+            new = self._log_chosen(run.start_slot, run.values)
+            if new == 0:
+                return
+            if self.wal is not None:
+                self._wal_log_chosen_run(
+                    run.start_slot, run.values,
+                    all_new=(new == len(run.values)))
+        with self.trace_stage("execute"):
+            replies = self._execute_log()
         if replies:
-            proxy = self._proxy_replica_address()
-            if proxy is not None:
-                self._wal_send(proxy,
-                               ClientReplyBatch(batch=tuple(replies)))
-            else:
-                by_client: dict = {}
-                for r in replies:
-                    cid = r.command_id
-                    by_client.setdefault(cid.client_address, []).append(
-                        (cid.client_pseudonym, cid.client_id, r.slot,
-                         r.result))
-                for address, entries in by_client.items():
-                    self._wal_send(address,
-                                   ClientReplyArray(entries=tuple(entries)))
+            with self.trace_stage("reply"):
+                self._reply_to_run(replies)
         self._restart_recover_timer()
+
+    def _reply_to_run(self, replies: list) -> None:
+        proxy = self._proxy_replica_address()
+        if proxy is not None:
+            self._wal_send(proxy, ClientReplyBatch(batch=tuple(replies)))
+            return
+        by_client: dict = {}
+        for r in replies:
+            cid = r.command_id
+            by_client.setdefault(cid.client_address, []).append(
+                (cid.client_pseudonym, cid.client_id, r.slot, r.result))
+        for address, entries in by_client.items():
+            self._wal_send(address,
+                           ClientReplyArray(entries=tuple(entries)))
 
     def _restart_recover_timer(self) -> None:
         # Recover timer runs only while there are unexecuted chosen slots

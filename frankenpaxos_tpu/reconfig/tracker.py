@@ -146,6 +146,11 @@ class EpochQuorumTracker:
             self._newly.append(key)
 
     # --- drain -------------------------------------------------------------
+    def has_votes(self) -> bool:
+        """Would :meth:`drain` have work (QuorumTracker.has_votes)?"""
+        return bool(self._newly if self.backend == "dict"
+                    else self._slots)
+
     def drain(self) -> list:
         if self.backend == "dict":
             newly, self._newly = self._newly, []

@@ -11,7 +11,7 @@ from __future__ import annotations
 import abc
 from typing import Any, Callable, Generic, TypeVar
 
-from frankenpaxos_tpu.obs.trace import stage_scope
+from frankenpaxos_tpu.obs.trace import NOOP_SCOPE, stage_scope
 from frankenpaxos_tpu.runtime.logger import Logger
 from frankenpaxos_tpu.runtime.serializer import DEFAULT_SERIALIZER, Serializer
 from frankenpaxos_tpu.runtime.transport import Address, Timer, Transport
@@ -82,8 +82,10 @@ class Actor(abc.ABC):
     # the parsed descriptor to ``handler(src, parsed)`` with normal
     # handler semantics -- no per-message objects in between. The
     # parsed object must expose ``count`` (messages represented) for
-    # drain bookkeeping. Sinks are bypassed whenever a tracer is
-    # attached (per-message span semantics win) -- and role-level
+    # drain bookkeeping. A sink may name the stage its handler's time
+    # is accounted to as a third element, ``(parser, handler, stage)``
+    # (the proxy leader's "vote-intake"); without one it is "handler".
+    # Under a tracer a sink batch gets ONE receive span. Role-level
     # admission is the SINK's job: the transport's client-lane inbox
     # shed does not see sink frames.
     wire_sinks = None
@@ -93,6 +95,9 @@ class Actor(abc.ABC):
         self.address = address
         self.transport = transport
         self.logger = logger
+        # receive_timer's children of the role's latency summary, by
+        # message type.
+        self._latency_children: dict = {}
         transport.register(address, self)
 
     @abc.abstractmethod
@@ -162,14 +167,33 @@ class Actor(abc.ABC):
         self.transport.flush(self.address, dst)
 
     def trace_stage(self, name: str):
-        """A drain-stage scope (paxtrace, obs/): times ``name`` as a
-        sub-span of the current trace and/or an observation into the
-        runtime drain-stage histogram, whichever sinks are attached to
-        the transport; a shared no-op otherwise. The canonical stages
-        are decode, handler, quorum-kernel, wal-fsync, send-release."""
+        """A stage scope (paxtrace, obs/): times ``name`` as a sub-span
+        of the current trace and/or into the stage's accumulator
+        behind ``fpx_runtime_drain_stage_seconds``, whichever sinks
+        are attached to the transport; a shared no-op otherwise. Open
+        one per batch of work, never per vote or per command. The
+        accumulators hold self time: a stage opened inside another
+        subtracts from it (docs/OBSERVABILITY.md lists the stages)."""
         transport = self.transport
         return stage_scope(transport.tracer, transport.runtime_metrics,
                            name)
+
+    def receive_timer(self, summary, message):
+        """The reference's ``timed(label)`` around a role's handler
+        (Leader.scala:281-293): a scope that feeds the per-message-type
+        child of ``summary`` the duration of the work inside it. Where
+        the transport has its ``handler`` stage open around the same
+        call, that scope's one clock pair feeds both series and this
+        is a no-op; otherwise it is the summary's own timer."""
+        kind = type(message)
+        child = self._latency_children.get(kind)
+        if child is None:
+            child = self._latency_children[kind] = summary.labels(
+                kind.__name__)
+        metrics = self.transport.runtime_metrics
+        if metrics is not None and metrics.share_clock("handler", child):
+            return NOOP_SCOPE
+        return child.time()
 
     def timer(self, name: str, delay_s: float,
               f: Callable[[], None]) -> Timer:

@@ -143,6 +143,49 @@ class Collectors(abc.ABC):
                   ) -> Histogram:
         ...
 
+    @abc.abstractmethod
+    def sampled_summary(self, name: str, help: str = "",
+                        labels: Sequence[str] = (),
+                        read=None) -> "SampledSummary":
+        """A family of ``name_sum`` / ``name_count`` series whose
+        values are READ WHEN SCRAPED: ``read()`` returns ``{label
+        values: (sum, count)}``. For numbers a hot path keeps in plain
+        accumulators of its own (obs.RuntimeMetrics' stages), where an
+        ``observe`` per event would cost more than the event. Several
+        readers may share one name; equal label values are summed."""
+
+
+class SampledSummary:
+    """A scrape-time family (Collectors.sampled_summary). ``labels()``
+    gives a read-only child with the Summary getters, so tests read it
+    like any other family."""
+
+    def __init__(self):
+        self.readers: list = []
+
+    def read(self) -> dict:
+        merged: dict = {}
+        for reader in self.readers:
+            for values, (total, count) in reader().items():
+                had_total, had_count = merged.get(values, (0.0, 0))
+                merged[values] = (had_total + total, had_count + count)
+        return merged
+
+    def labels(self, *values: str) -> "_SampledChild":
+        return _SampledChild(self, values)
+
+
+class _SampledChild:
+    def __init__(self, family: SampledSummary, values: tuple):
+        self._family = family
+        self._values = values
+
+    def get_sum(self) -> float:
+        return self._family.read().get(self._values, (0.0, 0))[0]
+
+    def get_count(self) -> float:
+        return self._family.read().get(self._values, (0.0, 0))[1]
+
 
 # --- Fake backend (FakeCollectors.scala) ----------------------------------
 
@@ -263,6 +306,12 @@ class FakeCollectors(Collectors):
                   buckets=LATENCY_BUCKETS):
         return self.metrics.setdefault(name, FakeHistogram(buckets))
 
+    def sampled_summary(self, name, help="", labels=(), read=None):
+        family = self.metrics.setdefault(name, SampledSummary())
+        if read is not None:
+            family.readers.append(read)
+        return family
+
 
 # --- Prometheus backend (PrometheusCollectors.scala) -----------------------
 
@@ -300,6 +349,15 @@ class PrometheusCollectors(Collectors):
                 name, help or name, list(labels),
                 buckets=list(buckets), registry=self._registry)
         return _PromHistogram(self._cache[name])
+
+    def sampled_summary(self, name, help="", labels=(), read=None):
+        if name not in self._cache:
+            self._cache[name] = _PromSampledSummary(
+                name, help or name, list(labels), self._registry)
+        family = self._cache[name]
+        if read is not None:
+            family.readers.append(read)
+        return family
 
 
 class _PromCounter(Counter):
@@ -369,6 +427,33 @@ class _PromHistogram(Histogram):
 
     def get_sum(self) -> float:
         return self._m._sum.get()
+
+
+class _PromSampledSummary(SampledSummary):
+    """A custom collector of the registry: the exposition carries
+    ``name_sum`` and ``name_count`` per label set, no buckets and no
+    quantiles, computed from the readers at each scrape."""
+
+    def __init__(self, name, help, labels, registry):
+        super().__init__()
+        from prometheus_client.core import SummaryMetricFamily
+
+        self._family = SummaryMetricFamily
+        self._name, self._help, self._labels = name, help, labels
+        registry.register(self)
+
+    def describe(self):
+        # Named at registration without calling the readers.
+        return [self._family(self._name, self._help,
+                             labels=self._labels)]
+
+    def collect(self):
+        family = self._family(self._name, self._help,
+                              labels=self._labels)
+        for values, (total, count) in sorted(self.read().items()):
+            family.add_metric(list(values), count_value=count,
+                              sum_value=total)
+        return [family]
 
 
 def instrument_actor(actor, collectors: Collectors, protocol: str,

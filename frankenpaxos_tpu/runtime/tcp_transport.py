@@ -41,12 +41,14 @@ from __future__ import annotations
 
 import asyncio
 import os
+import selectors
 import struct
 import threading
 import time
 from typing import Callable, Optional
 
-from frankenpaxos_tpu.obs.trace import TraceContext
+from frankenpaxos_tpu import native
+from frankenpaxos_tpu.obs.trace import NOOP_SCOPE, stage_scope, TraceContext
 from frankenpaxos_tpu.runtime import paxwire
 from frankenpaxos_tpu.runtime.actor import Actor
 from frankenpaxos_tpu.runtime.logger import Logger, PrintLogger
@@ -74,8 +76,6 @@ def _encode_frame(src: Address, data: bytes,
     # The framing hot path runs through the native C++ codec when built
     # (frankenpaxos_tpu/native/codec.cpp), with an identical pure-Python
     # fallback inside `native.encode_frame`.
-    from frankenpaxos_tpu import native
-
     host, port = src
     # paxtrace: the trace context rides the FRAME HEADER
     # (``host:port|<ctx>``), never the message codecs -- the wire tag
@@ -132,6 +132,25 @@ class TcpTimer(Timer):
             return
         with tracer.timer_span(str(self._address), self._name):
             self._f()
+
+
+class _TimedSelector(selectors.DefaultSelector):
+    """The event loop's selector with every ``select`` timed as stage
+    ``loop-wait``: the time the loop had nothing to run (the wait for
+    I/O or the next timer, and after it the wait to get the
+    interpreter back from the process's other threads). Without it a
+    busy loop and a starved one look the same on /metrics."""
+
+    def __init__(self, transport: "TcpTransport"):
+        super().__init__()
+        self._transport = transport
+
+    def select(self, timeout=None):
+        metrics = self._transport.runtime_metrics
+        if metrics is None:
+            return super().select(timeout)
+        with metrics.loop_wait():
+            return super().select(timeout)
 
 
 class _Conn:
@@ -239,6 +258,8 @@ class TcpTransport(Transport):
     async def serve(self) -> None:
         """Bind (if a listen address was given) and run until cancelled."""
         self.loop = asyncio.get_running_loop()
+        if self.runtime_metrics is not None:
+            self.runtime_metrics.bind_loop_thread()
         if self.listen_address is not None:
             await self._bind(self.listen_address)
         for address in list(self.actors):
@@ -264,7 +285,9 @@ class TcpTransport(Transport):
         """Spawn the event loop on a daemon thread and wait until bound."""
         def runner():
             try:
-                asyncio.run(self.serve())
+                asyncio.run(self.serve(), loop_factory=lambda:
+                            asyncio.SelectorEventLoop(
+                                _TimedSelector(self)))
             except asyncio.CancelledError:
                 pass
 
@@ -301,8 +324,6 @@ class TcpTransport(Transport):
         # buffer every 4096-frame pass (quadratic on deep backlogs),
         # and the per-pass ``del buf[:consumed]`` memmoved the tail the
         # same way -- now the prefix compacts only when it is large.
-        from frankenpaxos_tpu import native
-
         buf = bytearray()
         pos = 0  # buf[:pos] is already dispatched
         try:
@@ -310,46 +331,58 @@ class TcpTransport(Transport):
                 chunk = await reader.read(1 << 16)
                 if not chunk:
                     break
-                buf += chunk
-                # Dispatch every complete frame currently buffered.
-                # The head-frame length check gates each scan: while a
-                # large frame is still arriving, each chunk costs one
-                # unpack and no rescan of the whole buffer; the
-                # oversize check is against the frame's DECLARED
-                # length, never the buffer size (a near-cap frame
-                # pipelined with the next frame's first bytes is
-                # legitimate). The inner loop re-scans because the
-                # native scanner caps one pass at 4096 frames -- a
-                # single pass over a deeper backlog would strand the
-                # remainder until the peer happened to send more.
-                while len(buf) - pos >= 4:
-                    (inner,) = _LEN.unpack_from(buf, pos)
-                    if inner > MAX_FRAME:
-                        self.logger.error(
-                            f"oversized frame ({inner} bytes)")
-                        return
-                    if len(buf) - pos < 4 + inner:
-                        break
-                    try:
-                        frames, pos = native.scan_frames(buf, offset=pos)
-                    except ValueError as e:  # a mid-buffer oversized frame
-                        self.logger.error(str(e))
-                        return
-                    for start, end in frames:
-                        if not self._dispatch_frame(buf, start, end,
-                                                    local):
-                            return
-                # Compact the dispatched prefix only when it is big
-                # enough to matter (or the buffer is fully consumed):
-                # each del memmoves the tail, so doing it per pass is
-                # the quadratic copy this cursor exists to avoid.
-                if pos and (pos >= len(buf) or pos >= (1 << 18)):
-                    del buf[:pos]
-                    pos = 0
+                # Stage ``decode``, one scope a chunk: inbound bytes
+                # to messages or columns (buffer, frame scan, header,
+                # payload decode). The handlers run inside it and
+                # subtract themselves (self time), so what it holds is
+                # the transport's receive side alone. It ends before
+                # the next await.
+                with stage_scope(None, self.runtime_metrics, "decode"):
+                    buf += chunk
+                    pos = self._dispatch_buffered(buf, pos, local)
+                if pos < 0:
+                    return
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass
         finally:
             writer.close()
+
+    def _dispatch_buffered(self, buf: bytearray, pos: int,
+                           local: Address) -> int:
+        """Dispatch every complete frame in ``buf[pos:]``; returns the
+        new cursor, or -1 to drop the connection."""
+        # The head-frame length check gates each scan: while a large
+        # frame is still arriving, each chunk costs one unpack and no
+        # rescan of the whole buffer; the oversize check is against the
+        # frame's DECLARED length, never the buffer size (a near-cap
+        # frame pipelined with the next frame's first bytes is
+        # legitimate). The loop re-scans because the native scanner
+        # caps one pass at 4096 frames -- a single pass over a deeper
+        # backlog would strand the remainder until the peer happened to
+        # send more.
+        while len(buf) - pos >= 4:
+            (inner,) = _LEN.unpack_from(buf, pos)
+            if inner > MAX_FRAME:
+                self.logger.error(f"oversized frame ({inner} bytes)")
+                return -1
+            if len(buf) - pos < 4 + inner:
+                break
+            try:
+                frames, pos = native.scan_frames(buf, offset=pos)
+            except ValueError as e:  # a mid-buffer oversized frame
+                self.logger.error(str(e))
+                return -1
+            for start, end in frames:
+                if not self._dispatch_frame(buf, start, end, local):
+                    return -1
+        # Compact the dispatched prefix only when it is big enough to
+        # matter (or the buffer is fully consumed): each del memmoves
+        # the tail, so doing it per pass is the quadratic copy the
+        # cursor exists to avoid.
+        if pos and (pos >= len(buf) or pos >= (1 << 18)):
+            del buf[:pos]
+            pos = 0
+        return pos
 
     def _dispatch_frame(self, buf: bytearray, start: int, end: int,
                         local: Address) -> bool:
@@ -391,64 +424,46 @@ class TcpTransport(Transport):
             # whole undecoded batch payload to the actor's column
             # parser -- no per-message decode, no expansion. Only the
             # PARSE runs under this corrupt-frame guard; the handler
-            # runs below with ordinary handler semantics. Bypassed
-            # under a tracer (per-message span semantics win).
+            # runs below with ordinary handler semantics. A tracer
+            # rides the same path (one receive span a sink batch).
+            tracer = self.tracer
+            # One decode SPAN a sampled frame; the time on /metrics is
+            # the caller's per-chunk ``decode`` scope.
+            m0 = (tracer.mono() if tracer is not None and ctx is not None
+                  and ctx.sampled else None)
             fast = None
             sinks = getattr(actor, "wire_sinks", None)
-            if sinks is not None and self.tracer is None:
+            if sinks is not None:
                 sink = sinks.get(paxwire.leading_tag(data))
                 if sink is not None:
-                    metrics = self.runtime_metrics
-                    if metrics is not None:
-                        p0 = time.perf_counter()
-                        parsed = sink[0](data)
-                        metrics.observe_stage(
-                            "decode", time.perf_counter() - p0)
-                    else:
-                        parsed = sink[0](data)
+                    parsed = sink[0](data)
                     if parsed is not None:
-                        fast = (actor, sink[1], parsed)
-            if fast is not None:
-                pass
-            elif paxwire.is_batch_payload(data):
-                segments = paxwire.split_batch(data)
-            else:
-                segments = (data,)
+                        fast = (sink, parsed)
             deliveries = []
-            tracer = self.tracer
-            metrics = self.runtime_metrics
-            for segment in segments if fast is None else ():
-                if tracer is not None and ctx is not None \
-                        and ctx.sampled:
-                    m0 = tracer.mono()
-                    delivery = self._decode(local, src, segment,
-                                            actor)
+            if fast is None:
+                segments = (paxwire.split_batch(data)
+                            if paxwire.is_batch_payload(data)
+                            else (data,))
+                for segment in segments:
+                    delivery = self._decode(local, src, segment, actor)
                     if delivery is not None:
-                        tracer.record_stage("decode", m0, ctx)
-                elif metrics is not None:
-                    # Unsampled (or context-less) frame with /metrics
-                    # on: the drain-stage histogram still sees EVERY
-                    # decode -- sampling must not starve it.
-                    p0 = time.perf_counter()
-                    delivery = self._decode(local, src, segment,
-                                            actor)
-                    if delivery is not None:
-                        metrics.observe_stage(
-                            "decode", time.perf_counter() - p0)
-                else:
-                    delivery = self._decode(local, src, segment,
-                                            actor)
-                if delivery is not None:
-                    deliveries.append(delivery)
+                        deliveries.append(delivery)
+            if m0 is not None and (fast is not None or deliveries):
+                tracer.record_stage("decode", m0, ctx)
         except Exception as e:
             self.logger.error(
                 f"dropping connection on corrupt frame: {e!r}")
             return False
         if fast is not None:
-            actor, handler, parsed = fast
+            sink, parsed = fast
+            # The sink names the stage its handler's time goes to.
             # Handler semantics match receive(): exceptions on a VALID
             # frame propagate (a FatalError stays fatal).
-            handler(src, parsed)
+            stage = sink[2] if len(sink) > 2 else "handler"
+            span = (NOOP_SCOPE if tracer is None else tracer.receive_span(
+                str(actor.address), type(parsed).__name__, ctx))
+            with span, stage_scope(tracer, self.runtime_metrics, stage):
+                sink[1](src, parsed)
             self._note_delivered(actor, parsed.count)
             return True
         for delivery in deliveries:
@@ -511,16 +526,10 @@ class TcpTransport(Transport):
             return
         tracer = self.tracer
         if tracer is None:
-            metrics = self.runtime_metrics
-            if metrics is not None:
-                # Metrics-only mode: the handler stage (usually the
-                # largest) must reach the drain-stage histogram like
-                # every other canonical stage does.
-                p0 = time.perf_counter()
-                actor.receive(src, message)
-                metrics.observe_stage("handler",
-                                      time.perf_counter() - p0)
-            else:
+            # Stage ``handler``: the role's receive, less the stages it
+            # opens itself. A role's own per-type latency summary rides
+            # this scope's clock pair (Actor.receive_timer).
+            with stage_scope(None, self.runtime_metrics, "handler"):
                 actor.receive(src, message)
         else:
             span = tracer.receive_span(
@@ -797,8 +806,11 @@ class TcpTransport(Transport):
         self._flush_scheduled = False
         queue, self._flush_queue = self._flush_queue, []
         self._flush_dirty.clear()
-        for conn in queue:
-            self._flush_conn(conn)
+        # Stage ``flush``: coalesce + encode (plan_flush) and writev of
+        # everything this loop pass sent, one scope a pass.
+        with stage_scope(None, self.runtime_metrics, "flush"):
+            for conn in queue:
+                self._flush_conn(conn)
 
     async def _connect(self, conn: _Conn, dst: Address) -> None:
         host, port = dst

@@ -438,3 +438,452 @@ class TestMetricsOnlyStages:
         assert results
         fsync = collectors.metrics["fpx_runtime_wal_fsync_seconds"]
         assert fsync.labels("sim").get_count() > 0
+
+
+class TestStageAccounting:
+    """One stage accounting: self time per thread in plain
+    accumulators, the series made from them at scrape time, one clock
+    pair a scope, annotations only where the chip is."""
+
+    SERIES = "fpx_runtime_drain_stage_seconds"
+
+    def make(self, role="r0", **kwargs):
+        collectors = FakeCollectors()
+        clock = VirtualClock(tick_s=1.0)
+        metrics = RuntimeMetrics(collectors, role, clock=clock, **kwargs)
+        return collectors.metrics[self.SERIES], clock, metrics
+
+    def test_nested_stages_record_self_time(self):
+        """Parent + children = the outer scope's duration: every tick
+        of a VirtualClock between the outer enter and exit is in
+        exactly one stage."""
+        series, clock, metrics = self.make()
+        with metrics.stage("handler"):
+            t_in = clock.now
+            with metrics.stage("log"):
+                pass
+            with metrics.stage("execute"):
+                with metrics.stage("reply"):
+                    pass
+        outer = clock.now - t_in
+        got = {stage: series.labels("r0", stage).get_sum()
+               for stage in ("handler", "log", "execute", "reply")}
+        assert got == {"handler": 3.0, "log": 1.0, "execute": 2.0,
+                       "reply": 1.0}
+        assert sum(got.values()) == outer == 7.0
+        # A sibling opened after a closed scope has no parent left
+        # over from it.
+        with metrics.stage("flush"):
+            pass
+        assert series.labels("r0", "flush").get_sum() == 1.0
+        assert series.labels("r0", "handler").get_sum() == 3.0
+
+    def test_a_stage_reentered_on_its_thread_keeps_both_activations(self):
+        """The scope object is reused, so one opened inside itself has
+        to put the outer activation aside: both count, and the sum is
+        the outer duration."""
+        series, clock, metrics = self.make()
+        with metrics.stage("fan-out"):
+            t_in = clock.now
+            with metrics.stage("fan-out"):
+                pass
+        child = series.labels("r0", "fan-out")
+        assert (child.get_count(), child.get_sum()) == (2, 3.0)
+        assert clock.now - t_in == 3.0
+
+    def test_stages_of_two_threads_do_not_nest(self):
+        """A helper thread has accumulators of its own: what it times
+        subtracts nothing from the stage the event loop has open
+        meanwhile, and both show under the one series."""
+        series, _, metrics = self.make()
+
+        def collect():
+            stages = metrics.thread_stages()
+            with stages.stage("collect"):
+                pass
+            stages.stage("dispatch-wait").add(5.0)
+
+        with metrics.stage("drain"):
+            worker = threading.Thread(target=collect)
+            worker.start()
+            worker.join(timeout=10)
+            assert not worker.is_alive()
+        assert series.labels("r0", "collect").get_sum() == 1.0
+        assert series.labels("r0", "dispatch-wait").get_sum() == 5.0
+        assert series.labels("r0", "drain").get_sum() == 3.0
+
+    def test_a_collection_is_taken_out_of_the_stage_it_interrupted(self):
+        """``gc`` nests like any scope: on the thread it stopped, its
+        time leaves the open stage's self time. On a thread with no
+        stages of its own it is counted all the same."""
+        series, _, metrics = self.make()
+        metrics.bind_loop_thread()
+        with metrics.stage("execute"):
+            metrics._on_gc("start", {"generation": 2})
+            metrics._on_gc("stop", {"generation": 2})
+        assert series.labels("r0", "gc").get_sum() == 1.0
+        assert series.labels("r0", "execute").get_sum() == 2.0
+        elsewhere = threading.Thread(target=lambda: (
+            metrics._on_gc("start", {}), metrics._on_gc("stop", {})))
+        elsewhere.start()
+        elsewhere.join(timeout=10)
+        assert not elsewhere.is_alive()
+        child = series.labels("r0", "gc")
+        assert (child.get_count(), child.get_sum()) == (2, 2.0)
+
+    def test_watch_gc_counts_the_interpreters_own_collections(self):
+        import gc
+
+        series, _, metrics = self.make()
+        metrics.bind_loop_thread()
+        metrics.watch_gc()
+        try:
+            gc.collect()
+            gc.collect(0)
+        finally:
+            gc.callbacks.remove(metrics._on_gc)
+        assert series.labels("r0", "gc").get_count() == 2
+
+    @pytest.mark.parametrize("backend", ["fake", "prometheus"])
+    def test_the_scrape_time_series_equal_the_accumulators(self, backend):
+        """``_sum`` and ``_count`` of every (role, stage) are read from
+        the accumulators when scraped, with no observation per scope
+        in between: a scrape after more scopes reads more."""
+        from frankenpaxos_tpu.bench.metrics import parse_exposition
+        from frankenpaxos_tpu.runtime.monitoring import (
+            PrometheusCollectors,
+        )
+
+        if backend == "fake":
+            collectors = FakeCollectors()
+
+            def scrape():
+                return {
+                    f'{self.SERIES}_{part}{{role="{role}",'
+                    f'stage="{stage}"}}': float(value)
+                    for (role, stage), pair in
+                    collectors.metrics[self.SERIES].read().items()
+                    for part, value in zip(("sum", "count"), pair)}
+        else:
+            import prometheus_client
+
+            registry = prometheus_client.CollectorRegistry()
+            collectors = PrometheusCollectors(registry)
+
+            def scrape():
+                found = parse_exposition(
+                    prometheus_client.generate_latest(registry).decode())
+                return {name: value for name, value in found.items()
+                        if name.startswith(self.SERIES)}
+
+        metrics = RuntimeMetrics(collectors, "r0",
+                                 clock=VirtualClock(tick_s=0.5))
+        helper = metrics.thread_stages()
+        assert scrape() == {}
+        for _ in range(3):
+            with metrics.stage("decode"):
+                with metrics.stage("handler"):
+                    pass
+        with helper.stage("decode"):
+            pass
+        metrics.observe_stage("handback-wait", 0.25)
+
+        def series(stage, seconds, count):
+            labels = f'{{role="r0",stage="{stage}"}}'
+            return {f"{self.SERIES}_sum{labels}": seconds,
+                    f"{self.SERIES}_count{labels}": float(count)}
+
+        assert scrape() == {**series("decode", 3 * 1.0 + 0.5, 4),
+                            **series("handler", 3 * 0.5, 3),
+                            **series("handback-wait", 0.25, 1)}
+        assert metrics.read_stages() == {
+            ("r0", "decode"): (3.5, 4), ("r0", "handler"): (1.5, 3),
+            ("r0", "handback-wait"): (0.25, 1)}
+        with metrics.stage("handler"):
+            pass
+        assert scrape()[f'{self.SERIES}_count{{role="r0",'
+                        f'stage="handler"}}'] == 4.0
+
+    def test_one_clock_pair_feeds_stage_and_summary(self):
+        """``share_clock``: the open stage's own duration reaches the
+        second series, whole (not self time), with no clock read of
+        its own; a stage of another name, or one that already feeds a
+        sink, declines, and the next scope of the stage feeds
+        nothing."""
+        collectors = FakeCollectors()
+        clock = VirtualClock(tick_s=1.0)
+        metrics = RuntimeMetrics(collectors, "r0", clock=clock)
+        summary = collectors.summary("role_latency").labels("Phase2a")
+        assert not metrics.share_clock("handler", summary)  # none open
+        with metrics.stage("handler"):
+            assert not metrics.share_clock("drain", summary)
+            assert metrics.share_clock("handler", summary)
+            assert not metrics.share_clock("handler", summary)
+            reads = clock.now
+            with metrics.stage("log"):
+                pass
+        assert clock.now - reads == 3.0  # log's pair and handler's exit
+        series = collectors.metrics[self.SERIES]
+        assert series.labels("r0", "handler").get_sum() == 2.0
+        assert (summary.get_count(), summary.get_sum()) == (1, 3.0)
+        with metrics.stage("handler"):
+            pass
+        assert summary.get_count() == 1
+
+    def test_an_actor_times_its_receive_on_the_handler_stages_clock(self):
+        """``Actor.receive_timer``: inside the transport's ``handler``
+        scope the role's per-type summary costs no clock read and no
+        ``labels()`` call a delivery; outside one it times itself."""
+        from frankenpaxos_tpu.runtime import Actor
+
+        class Role(Actor):
+            def receive(self, src, message):
+                with self.receive_timer(summary, message):
+                    reads.append(clock.now)
+
+        class CountingSummary(type(FakeCollectors().summary("x"))):
+            lookups = 0
+
+            def labels(self, *values):
+                CountingSummary.lookups += 1
+                return super().labels(*values)
+
+        collectors = FakeCollectors()
+        clock = VirtualClock(tick_s=1.0)
+        transport = SimTransport(FakeLogger(LogLevel.FATAL))
+        transport.runtime_metrics = RuntimeMetrics(collectors, "r0",
+                                                   clock=clock)
+        summary = CountingSummary()
+        reads: list = []
+        role = Role("role", transport, FakeLogger(LogLevel.FATAL))
+        for _ in range(3):
+            with transport.runtime_metrics.stage("handler"):
+                before = clock.now
+                role.receive("src", "a message")
+            assert clock.now - before == 1.0  # the scope's exit alone
+        child = summary.labels("str")
+        assert (child.get_count(), child.get_sum()) == (3, 3.0)
+        assert CountingSummary.lookups == 2  # the first delivery's, mine
+        role.receive("src", "outside any handler scope")
+        assert child.get_count() == 4
+
+    def test_a_sampled_tracer_stage_is_the_same_scope(self):
+        """Traced or not, a stage feeds the accumulators through the
+        one self-time scope: a traced parent and its traced child, then
+        the same pair unsampled."""
+        series, _, metrics = self.make()
+        tracer = Tracer(role="r0", clock=VirtualClock(),
+                        runtime_metrics=metrics)
+        with tracer.receive_span("a", "M", None):
+            with tracer.stage("handler"):
+                with tracer.stage("log"):
+                    pass
+        assert series.labels("r0", "handler").get_sum() == 2.0
+        assert series.labels("r0", "log").get_sum() == 1.0
+        names = [s.name for s in tracer.spans]
+        assert names == ["stage:log", "stage:handler", "receive:M@a"]
+        tracer.sample_every = 0
+        with tracer.receive_span("a", "M", None):
+            with tracer.stage("handler"):
+                with tracer.stage("log"):
+                    pass
+        assert series.labels("r0", "handler").get_sum() == 4.0
+        assert series.labels("r0", "log").get_count() == 2
+        assert len(tracer.spans) == 3
+
+    def test_a_stage_scope_imports_no_jax_without_a_claimed_device(
+            self, monkeypatch):
+        """A process that never claimed a device (every role but the
+        chip owner) opens its stages, times its selector and accounts
+        its collections with JAX's profiler out of reach; only
+        ``device_clock=True`` asks for it."""
+        import sys
+
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        series, _, metrics = self.make("acceptor_0")
+        with metrics.stage("handler"):
+            with stage_scope(None, metrics, "flush"):
+                metrics._on_gc("start", {})
+                metrics._on_gc("stop", {})
+        with metrics.loop_wait():
+            pass
+        with metrics.thread_stages().stage("collect"):
+            pass
+        assert series.labels("acceptor_0", "flush").get_count() == 1
+        with pytest.raises(ImportError):
+            RuntimeMetrics(FakeCollectors(), "proxy_leader_0_1",
+                           device_clock=True)
+
+    def test_fpx_annotations_exist_only_while_a_device_trace_runs(
+            self, tmp_path):
+        """On the chip owner (``device_clock=True``) a scope is also a
+        profiler annotation ``fpx.<stage>``, built only while a trace
+        runs: the loop's thread learns that once a selector wait, a
+        helper thread when it refreshes."""
+        import jax
+        from jax.profiler import ProfileData
+
+        series, _, metrics = self.make("proxy_leader_0_1",
+                                       device_clock=True)
+        helper = metrics.thread_stages()
+        with metrics.loop_wait():
+            pass
+        assert metrics._loop.annotation is None
+        with metrics.stage("drain") as scope:
+            assert scope.span is None
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with metrics.stage("drain") as scope:
+                assert scope.span is None  # not asked since
+            with metrics.loop_wait():
+                pass
+            with metrics.stage("drain"):
+                with metrics.stage("fan-out"):
+                    pass
+            helper.refresh()
+            with helper.stage("collect"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        with metrics.loop_wait():
+            pass
+        with metrics.stage("drain") as scope:
+            assert scope.span is None
+        assert series.labels("proxy_leader_0_1", "drain").get_count() == 4
+        found = [os.path.join(base, name)
+                 for base, _, names in os.walk(tmp_path)
+                 for name in names if name.endswith(".xplane.pb")]
+        assert len(found) == 1
+        events = [event.name
+                  for plane in ProfileData.from_file(found[0]).planes
+                  for line in plane.lines for event in line.events]
+        assert events.count("fpx.drain") == 1
+        assert events.count("fpx.fan-out") == 1
+        assert events.count("fpx.collect") == 1
+        assert events.count("fpx.loop-wait") == 1
+
+
+SERVED = {"quorum_backend": "tpu", "tpu_pipelined": "true",
+          "tpu_window": "4096", "coalesce_writes": "true"}
+
+
+@pytest.fixture(scope="class")
+def served():
+    """The served MultiPaxos path at toy size, in-process over
+    TcpTransport: tpu tracker (CPU XLA here), pipelined, coalesced
+    writes."""
+    from tests.protocols.tcp_multipaxos import TcpMultiPaxos
+
+    deployment = TcpMultiPaxos.launch(SERVED)
+    try:
+        deployment.closed_loops(1, 1)  # warm: the first commit
+        yield deployment
+    finally:
+        deployment.stop()
+
+
+class TestServedPathStages:
+    #: Rounds of closed loops to try before giving up on acks arriving
+    #: as column batches (one was enough in every run seen).
+    ROUNDS = 50
+
+    def test_each_stage_is_observed_once_per_unit_of_work(self, served):
+        from frankenpaxos_tpu.runtime.paxwire import CONTROL_BATCH_TAG
+
+        owner = served.collectors[served.OWNER].metrics
+        requests = owner["multipaxos_proxy_leader_requests_total"]
+        assert CONTROL_BATCH_TAG in served.actors[served.OWNER][0].wire_sinks
+        # Until acks have arrived as column batches (the wire-sink
+        # path), so that the test cannot pass on the slow path.
+        for _ in range(self.ROUNDS):
+            served.closed_loops(24, 10)
+            if requests.labels("AckColumns").get() >= 3:
+                break
+        assert requests.labels("AckColumns").get() >= 3
+        served.settle()
+
+        # Read on the owner's loop, between deliveries.
+        latency = owner["multipaxos_proxy_leader_requests_latency_seconds"]
+        stages, delivered = served.on_loop(served.OWNER, lambda: (
+            served.stage_counts(served.OWNER),
+            sum(child.count for child in latency._children.values())))
+        # vote-intake: one a sink batch or packed-votes message.
+        assert stages["vote-intake"] == (
+            requests.labels("AckColumns").get()
+            + requests.labels("Phase2bVotes").get())
+        # drain: one a tracker drain that had votes; each dispatched
+        # once, waited once, collected once, on one clock pair with
+        # the collect summary.
+        drains = owner["multipaxos_proxy_leader_tpu_drains_total"]
+        had_votes = (drains.labels("device").get()
+                     + drains.labels("host").get())
+        dispatched = owner[
+            "multipaxos_proxy_leader_tpu_dispatches_total"].get()
+        collect = owner["multipaxos_proxy_leader_tpu_collect_seconds"]
+        assert stages["drain"] == had_votes == dispatched
+        assert stages["dispatch-wait"] == dispatched
+        assert stages["collect"] == collect.get_count() == dispatched
+        series = served.collectors[served.OWNER].metrics[
+            "fpx_runtime_drain_stage_seconds"]
+        assert series.labels(served.OWNER, "collect").get_sum() == \
+            pytest.approx(collect.get_sum())
+        # fan-out: one a hand-back (a collection that chose something),
+        # and one a drain whose host side chose something.
+        assert 0 < stages["handback-wait"] <= stages["collect"]
+        assert stages["handback-wait"] <= stages["fan-out"] \
+            <= stages["handback-wait"] + stages["drain"]
+        assert "quorum-kernel" not in stages
+        # handler: one a message, sharing its clock pair with the
+        # role's per-type latency summary (so equal counts).
+        assert stages["handler"] == delivered
+        # A replica: log and execute once a ChosenRun, reply when it
+        # executed something.
+        for label in ("replica_0", "replica_1"):
+            replica, runs = served.on_loop(label, lambda: (
+                served.stage_counts(label),
+                served.collectors[label].metrics[
+                    "multipaxos_replica_requests_latency_seconds"].labels(
+                        "ChosenRun").get_count()))
+            assert replica["log"] == replica["execute"] == runs > 0
+            assert 0 < replica["reply"] <= runs
+            assert replica["handler"] >= runs
+        # Every process: a decode a chunk read, a flush a pass that
+        # sent, a loop-wait a selector call.
+        for label in served.transports:
+            counts = served.stage_counts(label)
+            assert counts["loop-wait"] > 0 and counts["decode"] > 0, label
+        for label in (served.OWNER, "replica_0", "acceptor_0", "client",
+                      "leader_0"):
+            assert served.stage_counts(label)["flush"] > 0, label
+
+    def test_the_handler_stage_and_the_role_summary_share_a_clock(
+            self, served):
+        """An acceptor opens no stage inside its handlers, so the
+        handler stage's self time IS the summaries' total."""
+        served.closed_loops(4, 5)
+        served.settle()
+        acceptor = served.collectors["acceptor_0"].metrics
+        latency = acceptor["multipaxos_acceptor_requests_latency_seconds"]
+        handler = acceptor["fpx_runtime_drain_stage_seconds"].labels(
+            "acceptor_0", "handler")
+        # Read on the acceptor's loop, between deliveries.
+        stage, summaries = served.on_loop("acceptor_0", lambda: (
+            (handler.get_count(), handler.get_sum()),
+            [(child.count, child.value)
+             for child in latency._children.values()]))
+        assert stage[0] == sum(count for count, _ in summaries) > 0
+        assert stage[1] == pytest.approx(sum(s for _, s in summaries))
+
+    def test_a_collection_on_a_loops_thread_is_stage_gc(self, served):
+        """``watch_gc`` in a served role: a collection that stops the
+        replica's loop is counted there, under the replica's label."""
+        import gc
+
+        metrics = served.metrics["replica_0"]
+        before = served.stage_counts("replica_0").get("gc", 0)
+        metrics.watch_gc()
+        try:
+            assert served.on_loop("replica_0", gc.collect) is not None
+        finally:
+            gc.callbacks.remove(metrics._on_gc)
+        assert served.stage_counts("replica_0")["gc"] > before

@@ -101,9 +101,18 @@ def test_unreplicated_over_tcp_with_batching(transports):
             client.propose(pseudonym, b"cmd-%d-1" % pseudonym,
                            lambda r, p=pseudonym: on_reply(p, 1, r))
 
-    for p in range(4):
-        client.propose(p, b"cmd-%d-0" % p,
-                       lambda r, p=p: on_reply(p, 0, r))
+    def propose_round_0():
+        for p in range(4):
+            client.propose(p, b"cmd-%d-0" % p,
+                           lambda r, p=p: on_reply(p, 0, r))
+
+    # In one pass of the client's loop, so that the four leave in one
+    # write and the server answers all four before any second-round
+    # proposal can exist: proposed one by one from this thread, a
+    # descheduled caller let pseudonym 0's whole round trip overtake
+    # pseudonym 3's first proposal, the server's count of four then
+    # straddled the rounds, and the last replies sat unflushed.
+    client_t.loop.call_soon_threadsafe(propose_round_0)
     assert done.wait(timeout=10)
     assert len(server.state_machine.get()) == 8
     assert {(p, r) for p, r, _ in results} == {(p, r) for p in range(4)
@@ -223,3 +232,123 @@ def test_corrupt_frame_drops_connection_not_server(transports):
     client.echo("still alive", got.append)
     assert wait_for(lambda: got == ["still alive"])
     assert server.num_messages_received == 1
+
+
+# --- the wire-sink path under a tracer ---------------------------------------
+
+
+def _sink_batches(transports, traced: bool, batches: int = 5):
+    """Send ``batches`` control batch frames of vote acks to an actor
+    with a wire sink; returns (sink handler calls, per-message receive
+    calls, the receiver's tracer or None, its stage series)."""
+    from frankenpaxos_tpu.ingest.columns import parse_ack_batch
+    from frankenpaxos_tpu.obs import RuntimeMetrics, Tracer
+    from frankenpaxos_tpu.protocols.multipaxos.messages import Phase2bRange
+    from frankenpaxos_tpu.runtime import FakeCollectors
+    from frankenpaxos_tpu.runtime.actor import Actor
+    from frankenpaxos_tpu.runtime.paxwire import CONTROL_BATCH_TAG
+
+    sunk, received = [], []
+
+    class Sink(Actor):
+        def __init__(self, address, transport, logger):
+            super().__init__(address, transport, logger)
+            self.wire_sinks = {CONTROL_BATCH_TAG: (
+                parse_ack_batch, lambda src, acks: sunk.append(acks.count),
+                "vote-intake")}
+
+        def receive(self, src, message):
+            received.append(message)
+
+    class Source(Actor):
+        def receive(self, src, message):
+            pass
+
+    logger = FakeLogger()
+    sink_address = ("127.0.0.1", free_port())
+    receiver = transports(sink_address)
+    collectors = FakeCollectors()
+    receiver.runtime_metrics = RuntimeMetrics(collectors, "sink")
+    if traced:
+        receiver.tracer = Tracer(role="sink",
+                                 runtime_metrics=receiver.runtime_metrics)
+    Sink(sink_address, receiver, logger)
+    source_address = ("127.0.0.1", free_port())
+    source = Source(source_address, transports(source_address), logger)
+    for batch in range(batches):
+        # Adjacent ranges have no coalescer: they leave as one control
+        # batch frame, as an acceptor's acks of several runs do. Sent
+        # in ONE pass of the source's loop, as a role sends: from this
+        # thread each message would be a loop callback of its own, and
+        # a connect or a flush landing between two of them splits the
+        # frame.
+        source.transport.loop.call_soon_threadsafe(
+            source.send_batch, sink_address, [
+                Phase2bRange(group_index=0, acceptor_index=i,
+                             slot_start_inclusive=8 * batch,
+                             slot_end_exclusive=8 * batch + 4, round=0)
+                for i in range(3)])
+        assert wait_for(lambda: len(sunk) == batch + 1), (sunk, received)
+    return sunk, received, receiver.tracer, \
+        collectors.metrics["fpx_runtime_drain_stage_seconds"]
+
+
+def test_a_tracer_rides_the_wire_sink_path(transports):
+    """Attaching a tracer no longer switches the sink fast path off: a
+    traced and an untraced run deliver the same number of sink batches,
+    none falls back to per-message delivery, and each traced batch is
+    one receive span with ``vote-intake`` as its stage."""
+    plain, plain_received, _, plain_series = _sink_batches(transports,
+                                                           False)
+    traced, traced_received, tracer, traced_series = _sink_batches(
+        transports, True)
+    assert plain == traced == [3] * 5
+    assert plain_received == traced_received == []
+    for series in (plain_series, traced_series):
+        assert series.labels("sink", "vote-intake").get_count() == 5
+        assert series.labels("sink", "handler").get_count() == 0
+        # One decode a chunk read; a chunk held at least one batch.
+        assert 1 <= series.labels("sink", "decode").get_count() <= 5
+    spans = list(tracer.spans)
+    receives = [s for s in spans if s.cat == "receive"]
+    assert len(receives) == 5
+    assert all(s.name.startswith("receive:AckColumns@") for s in receives)
+    stages = [s for s in spans if s.name == "stage:vote-intake"]
+    assert sorted(s.parent_id for s in stages) == sorted(
+        s.span_id for s in receives)
+
+
+def test_the_loops_selector_times_every_wait_as_loop_wait():
+    """``_TimedSelector``: one ``loop-wait`` scope a select, on the
+    injected clock; with no metrics attached it is the plain selector.
+    A stage opened between two waits and the waits add up to the
+    clock's whole reading: busy and waiting are told apart."""
+    import types
+
+    from frankenpaxos_tpu.obs import RuntimeMetrics, VirtualClock
+    from frankenpaxos_tpu.runtime import FakeCollectors
+    from frankenpaxos_tpu.runtime.tcp_transport import _TimedSelector
+
+    collectors = FakeCollectors()
+    clock = VirtualClock(tick_s=1.0)
+    transport = types.SimpleNamespace(runtime_metrics=None)
+    selector = _TimedSelector(transport)
+    try:
+        assert selector.select(0) == []
+        assert clock.now == 0.0
+        transport.runtime_metrics = metrics = RuntimeMetrics(
+            collectors, "r0", clock=clock)
+        series = collectors.metrics["fpx_runtime_drain_stage_seconds"]
+        wait = series.labels("r0", "loop-wait")
+        assert selector.select(0) == [] and selector.select(0.001) == []
+        assert (wait.get_count(), wait.get_sum()) == (2, 2.0)
+        with metrics.stage("handler"):
+            pass
+        assert selector.select(0) == []
+        assert (wait.get_count(), wait.get_sum()) == (3, 3.0)
+        assert series.labels("r0", "handler").get_sum() == 1.0
+        # Eight readings, four scopes: between a scope's two readings
+        # lies a tick, and between scopes another that no stage holds.
+        assert clock.now == 8.0
+    finally:
+        selector.close()
