@@ -127,8 +127,8 @@ def settle(bench) -> None:
     name = "multipaxos_replica_executed_commands_total"
     ports = [port for label, port in bench.prometheus_ports.items()
              if label.startswith("replica_")]
-    deadline = time.time() + GRACE_S
-    while time.time() < deadline:
+    deadline = time.monotonic() + GRACE_S
+    while time.monotonic() < deadline:
         executed = [scrape(port).get(name) for port in ports]
         if len(set(executed)) <= 1:
             return

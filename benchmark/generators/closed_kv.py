@@ -10,12 +10,21 @@ answer.
 
 The process builds one ``TcpTransport`` and one MultiPaxos ``Client``
 (the entry a user's request takes), prints ``ready`` and waits on its
-standard input for ``go <start> <end>``, two wall-clock instants. From
-``go`` each of its loops issues one operation at a time, the next when
-the last is answered; operations issued in ``[start, end)`` are the
-window's. After ``end`` no loop issues, every outstanding operation is
-waited for (up to ``GRACE_S``: late is late, not lost), and every key
-this process wrote is read back with a linearizable read.
+standard input for ``go <start> <end>``, two instants of
+``time.monotonic()``. From ``go`` each of its loops issues one operation
+at a time, the next when the last is answered; operations issued in
+``[start, end)`` are the window's. After ``end`` no loop issues, every
+outstanding operation is waited for (up to ``GRACE_S``: late is late,
+not lost), and every key this process wrote is read back with a
+linearizable read.
+
+One clock. Every instant this process records or compares (an
+operation's issue and its answer, "is the window over") is a read of
+``time.monotonic()``: ``CLOCK_MONOTONIC``, which all processes of a
+Linux host share and which is never stepped. The launcher's ``go`` and
+the reference's real-time checks stand on the same clock. The wall clock
+is read twice, at ``go`` and at exit, only to report whether it moved
+against the monotonic one meanwhile (``wall_minus_mono_s``).
 
 The traffic file's parameters (upstream's ``UniformReadWriteWorkload``:
 ``num_keys``, ``read_fraction``, ``write_size_mean``):
@@ -33,10 +42,12 @@ padded to ``value_bytes``. The reference finds each write in the
 replicas' executed logs by it, and holds every read to the writes that
 were acknowledged before it was issued.
 
-Written to ``<out>.npz``, one row per operation: ``issue_unix_s``,
-``latency_s`` (-1: never answered), ``kind`` (0 write, 1 read), ``key``
-and ``value`` (a write's id; for a read the id it returned, ``ABSENT``
-or ``UNREADABLE``). To ``<out>.json``: the counts.
+Written to ``<out>.npz``, one row per operation: ``issue_mono_s``,
+``latency_s`` (the answer's instant less the issue's, both monotonic;
+-1: never answered), ``kind`` (0 write, 1 read), ``key`` and ``value``
+(a write's id; for a read the id it returned, ``ABSENT`` or
+``UNREADABLE``). To ``<out>.json``: the counts, ``end_mono_s`` and the
+two readings of ``wall_minus_mono_s``.
 """
 
 from __future__ import annotations
@@ -72,6 +83,13 @@ def read_id(value) -> int:
         return int(value[:ID_DIGITS], 16)
     except ValueError:
         return UNREADABLE
+
+
+def wall_minus_mono() -> float:
+    """The wall clock against the monotonic one. A step of the wall clock
+    (NTP, a VM put right after a pause) shows as a change between two
+    readings; nothing is compared with it."""
+    return time.time() - time.monotonic()
 
 
 def main(argv=None) -> None:
@@ -135,7 +153,7 @@ def main(argv=None) -> None:
     writes_of = [0] * num_loops
     written: set = set()            # keys with an acknowledged write
     outstanding = [False] * num_loops
-    issue_unix_s = array.array("d")
+    issue_mono_s = array.array("d")
     latency_s = array.array("d")
     kinds = array.array("b")
     op_keys = array.array("i")
@@ -148,19 +166,20 @@ def main(argv=None) -> None:
     if word != "go":
         raise SystemExit(f"expected 'go <start> <end>', got {word!r}")
     end = float(end)
+    wall_minus_mono_s = [wall_minus_mono()]
 
     def send(p: int, key: int, read: bool, then) -> None:
         """One operation of loop ``p``; ``then()`` once it is answered."""
         row = len(latency_s)
-        issue_unix_s.append(time.time())
+        issued = time.monotonic()
+        issue_mono_s.append(issued)
         latency_s.append(-1.0)
         kinds.append(READ if read else WRITE)
         op_keys.append(key)
         outstanding[p] = True
-        t0 = time.perf_counter()
 
         def answered() -> None:
-            latency_s[row] = time.perf_counter() - t0
+            latency_s[row] = time.monotonic() - issued
             outstanding[p] = False
             then()
 
@@ -193,7 +212,7 @@ def main(argv=None) -> None:
                 ((keys[key], f"{mine:016x}" + padding),))), on_write)
 
     def issue(p: int) -> None:
-        if time.time() >= end:
+        if time.monotonic() >= end:
             counts["live"] -= 1
             if counts["live"] == 0:
                 done.set()
@@ -206,7 +225,7 @@ def main(argv=None) -> None:
 
     for p in range(num_loops):
         transport.loop.call_soon_threadsafe(issue, p)
-    done.wait(timeout=max(0.0, end - time.time()) + GRACE_S)
+    done.wait(timeout=max(0.0, end - time.monotonic()) + GRACE_S)
 
     # Read back every key this process wrote, on the loops that are free
     # (one operation per pseudonym), each loop its keys one after another.
@@ -235,15 +254,17 @@ def main(argv=None) -> None:
         transport.loop.call_soon_threadsafe(read_all)
         all_read.wait(timeout=GRACE_S)
     transport.stop()
+    wall_minus_mono_s.append(wall_minus_mono())
 
     np.savez(args.out + ".npz",
-             issue_unix_s=np.frombuffer(issue_unix_s, dtype=np.float64),
+             issue_mono_s=np.frombuffer(issue_mono_s, dtype=np.float64),
              latency_s=np.frombuffer(latency_s, dtype=np.float64),
              kind=np.frombuffer(kinds, dtype=np.int8),
              key=np.frombuffer(op_keys, dtype=np.int32),
              value=np.frombuffer(op_values, dtype=np.int64))
     with open(args.out + ".json", "w") as f:
-        json.dump({"index": args.index, "keys": keys, "end_unix_s": end,
+        json.dump({"index": args.index, "keys": keys, "end_mono_s": end,
+                   "wall_minus_mono_s": wall_minus_mono_s,
                    "gave_up": counts["gave_up"],
                    "loops_stuck": sum(outstanding)}, f)
 

@@ -12,8 +12,8 @@ def window_write_latencies(run):
     ops = run.ops
     start, end = run.window
     return ops["latency_s"][(ops["kind"] == WRITE)
-                            & (ops["issue_unix_s"] >= start)
-                            & (ops["issue_unix_s"] < end)
+                            & (ops["issue_mono_s"] >= start)
+                            & (ops["issue_mono_s"] < end)
                             & (ops["latency_s"] >= 0)]
 
 

@@ -93,9 +93,9 @@ def main(argv: list, wrap_tracker=None, wrap_store=None) -> None:
 
     class RecordingTracker(base):
         def __init__(self, *args, **kwargs):
-            t0 = time.time()
+            t0 = time.monotonic()
             super().__init__(*args, **kwargs)
-            self.init_s = time.time() - t0
+            self.init_s = time.monotonic() - t0
             # In arrival order: a 5-tuple is a range of votes, a 4-tuple
             # an array of votes, an [n, 2] array what one drain or
             # collect reported (an array, so that the reported tuples die
@@ -203,9 +203,9 @@ def main(argv: list, wrap_tracker=None, wrap_store=None) -> None:
                 cache["misses"] += 1
 
         jax.monitoring.register_event_listener(on_event)
-        t0 = time.time()
+        t0 = time.monotonic()
         claimed["device"] = claim_tpu()
-        claimed["claim_s"] = time.time() - t0
+        claimed["claim_s"] = time.monotonic() - t0
         return claimed["device"]
 
     device.claim_tpu = timed_claim
@@ -215,6 +215,7 @@ def main(argv: list, wrap_tracker=None, wrap_store=None) -> None:
 
         from frankenpaxos_tpu.bench.metrics import parse_exposition
 
+        # A label for a reader of the file; nothing compares it.
         return {"unix_s": time.time(),
                 "metrics": parse_exposition(
                     prometheus_client.generate_latest().decode()),
@@ -230,18 +231,18 @@ def main(argv: list, wrap_tracker=None, wrap_store=None) -> None:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
-        t0 = time.time()
+        t0 = time.monotonic()
         jax.profiler.start_trace(trace_dir, profiler_options=options)
-        start_trace_s = time.time() - t0
+        start_trace_s = time.monotonic() - t0
         with jax.profiler.TraceAnnotation(TRACED_SPAN):
             before = snapshot()
             time.sleep(trace_s)
             after = snapshot()
-        t0 = time.time()
+        t0 = time.monotonic()
         jax.profiler.stop_trace()
         meta = {"before": before, "after": after,
                 "start_trace_s": start_trace_s,
-                "stop_trace_s": time.time() - t0}
+                "stop_trace_s": time.monotonic() - t0}
         with open(os.path.join(record_dir, f"{label}.trace.json.tmp"),
                   "w") as f:
             json.dump(meta, f)

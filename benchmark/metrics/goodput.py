@@ -7,7 +7,7 @@ from harness.readings import WRITE
 def read(run, metric):
     ops = run.ops
     start, end = run.window
-    acked_at = ops["issue_unix_s"] + ops["latency_s"]
+    acked_at = ops["issue_mono_s"] + ops["latency_s"]
     acked = ((ops["kind"] == WRITE) & (ops["latency_s"] >= 0)
              & (acked_at >= start) & (acked_at < end))
     return int(acked.sum()) / (end - start)

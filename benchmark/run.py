@@ -17,13 +17,23 @@ that names; none is named here.
 ``setup_s`` runs from this process's start to the first instant of the
 measured window, the generators' warm-up included. The reference and the
 reading of the trace run after the window and count in neither.
+
+One clock. Every instant that the window, a metric or ``correct``
+compares (this process's start, ``go <start> <end>``, the generators'
+issue and answer instants) is a read of ``time.monotonic()``:
+``CLOCK_MONOTONIC``, one clock for all processes of a Linux host, never
+stepped. The wall clock decides nothing. The launcher and every generator
+read it against the monotonic one at ``go`` and at their end; the largest
+change is printed as ``clock wall_step_ms`` and carried, with each
+process's own, under ``clock`` in the result line, so that a reader learns
+whether it was stepped inside the run.
 """
 
 from __future__ import annotations
 
 import time
 
-STARTED = time.time()
+STARTED = time.monotonic()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
@@ -55,7 +65,8 @@ class Run:
         self.config: dict = {}
         self.traffic: dict = {}
         self.seconds = 0.0
-        self.window = (0.0, 0.0)     # unix seconds
+        self.window = (0.0, 0.0)     # time.monotonic() seconds
+        self.wall_minus_mono_s = 0.0  # the launcher's reading at go
         self.setup_s = 0.0
         self.ops: dict = {}          # the generators' rows, concatenated
         self.scrapes: dict = {}      # "start" / "end" -> {label: /metrics}
@@ -68,7 +79,7 @@ class Run:
 
 
 def log(message: str) -> None:
-    print(f"[bench {time.time() - STARTED:7.2f}s] {message}",
+    print(f"[bench {time.monotonic() - STARTED:7.2f}s] {message}",
           file=sys.stderr, flush=True)
 
 
@@ -118,10 +129,27 @@ def cpu_seconds(pids: dict) -> dict:
     return out
 
 
-def sleep_until(unix_s: float) -> None:
-    delay = unix_s - time.time()
+def sleep_until(mono_s: float) -> None:
+    delay = mono_s - time.monotonic()
     if delay > 0:
         time.sleep(delay)
+
+
+def wall_minus_mono() -> float:
+    """The wall clock against the monotonic one; see ``clock_report``."""
+    return time.time() - time.monotonic()
+
+
+def clock_report(readings: dict) -> dict:
+    """``readings``: for each process its ``wall_minus_mono`` at ``go`` and
+    at its end. The change in each, in milliseconds, and the one of
+    largest size as ``wall_step_ms``: how far the wall clock was stepped
+    (or slewed) inside the run. It decides nothing; no wall-clock instant
+    is compared."""
+    moved = {name: 1e3 * (last - first)
+             for name, (first, last) in readings.items()}
+    return {"wall_step_ms": max(moved.values(), key=abs),
+            "by_process_ms": moved}
 
 
 def drive(bench, run: Run, manifest: Manifest, seed: int,
@@ -136,7 +164,8 @@ def drive(bench, run: Run, manifest: Manifest, seed: int,
         f"{bench.chip_owner}")
     generators = start_generators(bench, run, manifest, cluster_path, seed)
     try:
-        start = time.time() + run.traffic["warmup_s"]
+        run.wall_minus_mono_s = wall_minus_mono()
+        start = time.monotonic() + run.traffic["warmup_s"]
         end = start + run.seconds
         for _, process in generators:
             process.stdin.write(f"go {start!r} {end!r}\n")
@@ -178,8 +207,8 @@ def drive(bench, run: Run, manifest: Manifest, seed: int,
     if trace_s > 0:
         span_path = os.path.join(record_dir,
                                  f"{bench.chip_owner}.trace.json")
-        deadline = time.time() + grace_s
-        while not os.path.exists(span_path) and time.time() < deadline:
+        deadline = time.monotonic() + grace_s
+        while not os.path.exists(span_path) and time.monotonic() < deadline:
             time.sleep(0.1)
     # SIGTERM, then wait: the role entries dump their records at exit,
     # which bench.cleanup()'s five seconds might cut short.
@@ -308,9 +337,15 @@ def main(argv=None) -> int:
                                        "unit": metric["unit"]}
 
     log("comparing with the plain reference")
-    compared = reference.compare(np, run.config, generators, run.records)
-    in_window = ((run.ops["issue_unix_s"] >= run.window[0])
-                 & (run.ops["issue_unix_s"] < run.window[1]))
+    evidence: dict = {}
+    compared = reference.compare(np, run.config, generators, run.records,
+                                 evidence)
+    clock = clock_report({
+        "launcher": (run.wall_minus_mono_s, wall_minus_mono()),
+        **{f"generator_{g['info']['index']}": g["info"]["wall_minus_mono_s"]
+           for g in generators}})
+    in_window = ((run.ops["issue_mono_s"] >= run.window[0])
+                 & (run.ops["issue_mono_s"] < run.window[1]))
     result = {
         "correct": all(value <= limit for value, limit in compared.values()),
         "attempted": int(in_window.sum()),
@@ -320,9 +355,12 @@ def main(argv=None) -> int:
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    if evidence:
+        result["offenders"] = evidence
+    result["clock"] = clock
     result["compared"] = {name: [value, limit]
                           for name, (value, limit) in compared.items()}
-    acked_at = (run.ops["issue_unix_s"] + run.ops["latency_s"])[
+    acked_at = (run.ops["issue_mono_s"] + run.ops["latency_s"])[
         run.ops["latency_s"] >= 0]
     log("answers in each second of the window: " + str(np.histogram(
         acked_at, bins=np.arange(run.window[0], run.window[1] + 0.5)
@@ -334,11 +372,22 @@ def main(argv=None) -> int:
          for label, r in sorted(run.records.items())}))
     log(f"done; set-up {run.setup_s:.2f}s, claim "
         f"{owner.get('claim_s', 0):.2f}s, compile cache {owner['cache']}")
+    print_compared(compared, evidence, clock)
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+def print_compared(compared: dict, evidence: dict, clock: dict) -> None:
+    """The last lines of standard error: for each number above its limit
+    the rows it was counted from, whether the wall clock moved, and each
+    number compared beside its limit."""
+    for name, rows in evidence.items():
+        for row in rows:
+            print(f"offender {name}: {json.dumps(row)}", file=sys.stderr)
+    print(f"clock wall_step_ms: {clock['wall_step_ms']}", file=sys.stderr)
     for name, (value, limit) in compared.items():
         print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
     sys.stderr.flush()
-    print(json.dumps(result), flush=True)
-    return 0
 
 
 if __name__ == "__main__":

@@ -3,10 +3,12 @@ from the real one (toy size for the CPU, or another role entry), and one
 run of ``benchmark/run.py`` as a child process under the tests' CPU pin.
 
     python3 tests/benchmark/bench_util.py <role entry> <out dir>
+    python3 tests/benchmark/bench_util.py --generator <generator> <out dir>
 
-writes the real manifest with every configuration's ``role_entry``
-replaced, at the cells' own size, and prints its path: the control and
-the planted faults, for runs on the chip.
+writes the real manifest with every configuration's ``role_entry``, or
+every traffic mix's generator, replaced, at the cells' own size, and
+prints its path: the control and the planted faults, for runs on the
+chip.
 """
 
 import fcntl
@@ -32,16 +34,26 @@ def manifest(path: str = os.path.join(REPO, "BENCHMARK.json")) -> dict:
 
 
 def derive(out_dir: str, *, toy: bool, role_entry: "str | None" = None,
-           change=None) -> str:
+           generator: "str | None" = None, change=None) -> str:
     """A copy of the real manifest under ``out_dir`` whose configurations
     and traffic mixes are the real files with a few keys replaced: at
     ``toy`` size a 4096-slot window, a few loops and a short warm-up;
-    with ``role_entry`` that entry in the benchmark's place. ``change``
-    may edit the manifest before it is written. Returns its path."""
+    with ``role_entry`` that entry in the benchmark's place; with
+    ``generator`` that file as every mix's generator. ``change`` may edit
+    the manifest before it is written. Returns its path."""
     derived = manifest()
     out_dir = os.path.abspath(out_dir)
-    os.makedirs(os.path.join(out_dir, "configs"), exist_ok=True)
-    os.makedirs(os.path.join(out_dir, "traffic"), exist_ok=True)
+    for kind in ("configs", "traffic", "generators"):
+        os.makedirs(os.path.join(out_dir, kind), exist_ok=True)
+    generator_name = None
+    if generator is not None:
+        # Found by name, as the harness finds any generator: a link under
+        # the derived manifest's first path.
+        generator_name = os.path.splitext(os.path.basename(generator))[0]
+        link = os.path.join(out_dir, "generators", generator_name + ".py")
+        if os.path.lexists(link):
+            os.remove(link)
+        os.symlink(os.path.abspath(generator), link)
     derived["paths"] = [out_dir] + derived["paths"]
     for entry in derived["configs"]:
         with open(os.path.join(REPO, entry["file"])) as f:
@@ -55,13 +67,16 @@ def derive(out_dir: str, *, toy: bool, role_entry: "str | None" = None,
                                      entry["name"] + ".json")
         with open(entry["file"], "w") as f:
             json.dump(config, f)
-    for name in {cell["traffic"] for cell in derived["workloads"]} if toy \
-            else ():
+    for name in ({cell["traffic"] for cell in derived["workloads"]}
+                 if toy or generator is not None else ()):
         with open(os.path.join(BENCHMARK, "traffic", name + ".json")) as f:
             traffic = json.load(f)
-        many = traffic["client_procs"] > 1
-        traffic.update(client_procs=2 if many else 1,
-                       loops_per_proc=8 if many else 4, warmup_s=0.5)
+        if toy:
+            many = traffic["client_procs"] > 1
+            traffic.update(client_procs=2 if many else 1,
+                           loops_per_proc=8 if many else 4, warmup_s=0.5)
+        if generator is not None:
+            traffic["generator"] = generator_name
         with open(os.path.join(out_dir, "traffic", name + ".json"),
                   "w") as f:
             json.dump(traffic, f)
@@ -73,9 +88,9 @@ def derive(out_dir: str, *, toy: bool, role_entry: "str | None" = None,
     return path
 
 
-def toy_manifest(tmp_path_factory, role_entry: "str | None" = None) -> str:
-    return derive(str(tmp_path_factory.mktemp("toy")), toy=True,
-                  role_entry=role_entry)
+def toy_manifest(tmp_path_factory, **planted) -> str:
+    """``planted``: ``role_entry`` or ``generator``, as ``derive`` takes."""
+    return derive(str(tmp_path_factory.mktemp("toy")), toy=True, **planted)
 
 
 def run_cell(manifest_path: str, cell: str, *, trace: int = 0,
@@ -105,4 +120,7 @@ def run_cell(manifest_path: str, cell: str, *, trace: int = 0,
 
 
 if __name__ == "__main__":
-    print(derive(sys.argv[2], toy=False, role_entry=sys.argv[1]))
+    if sys.argv[1] == "--generator":
+        print(derive(sys.argv[3], toy=False, generator=sys.argv[2]))
+    else:
+        print(derive(sys.argv[2], toy=False, role_entry=sys.argv[1]))

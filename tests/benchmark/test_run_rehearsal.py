@@ -27,10 +27,20 @@ def test_cell_runs_at_toy_size(toy, cell, trace):
     code, result, errors = run_cell(toy, cell, trace=trace)
     assert code == 0, errors[-3000:]
     wanted = RESULT_KEYS | ({"breakdown"} if trace else set())
-    # What the driver reads, and the compared numbers, which come last.
-    assert set(result) == wanted | {"compared"}
+    # What the driver reads, whether the wall clock moved, and the
+    # compared numbers, which come last. A correct run has no offenders.
+    assert set(result) == wanted | {"clock", "compared"}
     assert list(result)[-1] == "compared"
     assert result["correct"] is True, result["compared"]
+    # How far the wall clock moved, whatever this host's did meanwhile:
+    # reported for every process, and decides nothing.
+    moved = result["clock"]["by_process_ms"]
+    assert set(moved) == {"launcher"} | {
+        f"generator_{n}" for n in range(len(moved) - 1)} and len(moved) > 1
+    assert result["clock"]["wall_step_ms"] == max(moved.values(), key=abs)
+    assert (f"clock wall_step_ms: {result['clock']['wall_step_ms']}"
+            in errors)
+    assert "offender " not in errors
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["device"]["platform"] == "cpu"
     assert set(result["device"]) == {

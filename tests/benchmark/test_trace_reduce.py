@@ -2,6 +2,7 @@
 made by hand, and whole runs whose timed path is broken underneath the
 recorders: those have to print ``correct: false``."""
 
+import json
 import os
 
 from bench_util import BENCHMARK, FAULTS, run_cell, toy_manifest
@@ -185,13 +186,13 @@ def wid(loop: int, count: int, generator: int = 0) -> int:
 def clients(ops, end=100.0) -> "reference.PlainRegisters":
     """``ops`` rows: (issued, acknowledged or None, kind, key, value)."""
     arrays = {
-        "issue_unix_s": np.array([o[0] for o in ops], dtype=np.float64),
+        "issue_mono_s": np.array([o[0] for o in ops], dtype=np.float64),
         "latency_s": np.array([-1.0 if o[1] is None else o[1] - o[0]
                                for o in ops]),
         "kind": np.array([o[2] for o in ops], dtype=np.int8),
         "key": np.array([o[3] for o in ops], dtype=np.int32),
         "value": np.array([o[4] for o in ops], dtype=np.int64)}
-    info = {"keys": ["0", "1"], "end_unix_s": end, "gave_up": 0}
+    info = {"keys": ["0", "1"], "end_mono_s": end, "gave_up": 0}
     return reference.PlainRegisters(np, [{"info": info, "ops": arrays}])
 
 
@@ -292,6 +293,212 @@ def test_reads_are_held_to_the_writes_acknowledged_before_them():
                                      OPS[-1]]) == (1, 0)
 
 
+# --- one clock: a whole run's records, and a wall clock that steps -------
+
+ALL_EIGHTEEN = {
+    "ops_unanswered", "keys_not_read_back", "reads_wrong",
+    "replicas_missing", "replica_writes_lost", "replica_writes_repeated",
+    "replica_writes_unknown", "replica_order_wrong",
+    "replica_realtime_wrong", "replica_store_wrong", "replica_logs_differ",
+    "chosen_early", "chosen_extra", "chosen_twice", "chosen_missing",
+    "window_violations", "board_shape_wrong", "chip_owners_wrong"}
+STEP_AT = 1001.0      # time.monotonic() seconds
+
+
+def sound_run(wall_step_s: float = 0.0, extra_ops=(), log_edit=None):
+    """The records of one sound run, made by hand: two generators of
+    sixteen closed loops write one shared key for two seconds (50-150 ms
+    a write, so 32 are in flight at any instant), the system orders
+    each write somewhere between its issue and its answer, both replicas
+    execute that order, the tracker hears two acceptors a slot and
+    reports it, and each generator reads the key back after the window.
+
+    Returns ``(config, generators, records, wall)``: every instant in
+    ``generators`` is monotonic; ``wall`` is what ``time.time()`` would
+    have read at each operation's issue, on a host whose wall clock is
+    stepped by ``wall_step_s`` at ``STEP_AT``."""
+    with open(os.path.join(BENCHMARK, "configs",
+                           "mp_f1_majority.json")) as f:
+        config = json.load(f)
+    rng = np.random.default_rng(7)
+    start, end = 1000.0, 1002.0
+    ops = []          # (generator, issued, answered, kind, value, ordered)
+    for generator in range(2):
+        for loop in range(16):
+            at, count = start + rng.uniform(0, 0.05), 0
+            while at < end:
+                answered = at + rng.uniform(0.05, 0.15)
+                ops.append((generator, at, answered, W,
+                            wid(loop, count, generator),
+                            rng.uniform(at, answered)))
+                at, count = answered + 1e-4, count + 1
+    ops.extend(extra_ops)
+    log = [op[4] for op in sorted(ops, key=lambda op: op[5])]
+    if log_edit is not None:
+        log = log_edit(log)
+    closed = max(op[2] for op in ops) + 0.01
+    ops += [(generator, closed, closed + 0.02, R, log[-1], None)
+            for generator in range(2)]
+
+    def wall_of(mono: float) -> float:
+        return mono + 1.7e9 + (wall_step_s if mono >= STEP_AT else 0.0)
+
+    generators, wall = [], []
+    for generator in range(2):
+        mine = [op for op in ops if op[0] == generator]
+        generators.append({
+            "info": {"index": generator, "keys": ["0"], "end_mono_s": end,
+                     "gave_up": 0, "wall_minus_mono_s": [1.7e9, 1.7e9]},
+            "ops": {
+                "issue_mono_s": np.array([op[1] for op in mine]),
+                "latency_s": np.array([op[2] - op[1] for op in mine]),
+                "kind": np.array([op[3] for op in mine], dtype=np.int8),
+                "key": np.zeros(len(mine), dtype=np.int32),
+                "value": np.array([op[4] for op in mine], dtype=np.int64)}})
+        wall.append({"issue": np.array([wall_of(op[1]) for op in mine]),
+                     "end": wall_of(end)})
+    keys, values, names = log_of([("probe", "0")] + [("0", v) for v in log])
+    replica = {"record": {"claimed": False, "trackers": [],
+                          "key_names": names,
+                          "stores": [{"probe": "0",
+                                      "0": f"{log[-1]:016x}"}]},
+               "replica": {"keys": keys, "values": values}, "trackers": []}
+    votes, reports = record(
+        [event for slot in range(len(log) + 1)
+         for event in ((slot, slot + 1, 0, 0, 0), (slot, slot + 1, 0, 0, 2),
+                       [(slot, 0)])])
+    owner = {"record": {"claimed": True, "trackers": [
+                 {"window_violations": 0,
+                  "board_shape": [config["board"]["nodes"],
+                                  config["board"]["window"]]}]},
+             "replica": None,
+             "trackers": [{"votes": votes, "reports": reports}]}
+    records = {"proxy_leader_0_1": owner, "replica_0": replica,
+               "replica_1": dict(replica)}
+    return config, generators, records, wall
+
+
+def on_the_wall_clock(generators, wall) -> list:
+    """The same records as the parent's generators wrote them: the issue
+    instant from ``time.time()``, the latency from a clock that does not
+    step, the answer's instant their sum."""
+    return [{"info": {**g["info"], "end_mono_s": w["end"]},
+             "ops": {**g["ops"], "issue_mono_s": w["issue"]}}
+            for g, w in zip(generators, wall)]
+
+
+@pytest.mark.parametrize("wall_step_ms", [-250, 250, 0])
+def test_a_step_of_the_wall_clock_inside_the_run_counts_nothing(
+        wall_step_ms):
+    config, generators, records, wall = sound_run(wall_step_ms / 1e3)
+    evidence = {}
+    compared = reference.compare(np, config, generators, records, evidence)
+    assert set(compared) == ALL_EIGHTEEN
+    assert all(value == 0 and limit == 0
+               for value, limit in compared.values()), compared
+    assert evidence == {}
+    # The yardstick before this clock rule: the same run, its instants
+    # as time.time() read them. A step reads as writes out of real-time
+    # order, and as nothing else.
+    stepped = reference.compare(np, config,
+                                on_the_wall_clock(generators, wall), records)
+    over = {name for name, (value, _) in stepped.items() if value}
+    assert over == ({"replica_realtime_wrong"} if wall_step_ms else set())
+    if wall_step_ms:
+        # In both replicas, among the 32 writes in flight across it. (A
+        # step back counts more: every write issued inside the step's
+        # length less a latency before it. A step forward counts only
+        # writes the system ordered after a later-issued one.)
+        assert stepped["replica_realtime_wrong"][0] >= 2 * 8
+
+
+def test_a_true_inversion_is_counted_and_its_offenders_are_printed(capsys):
+    """Loop 101's write is issued 5 ms after loop 100's was answered, and
+    the replicas' logs have it first."""
+    first, second = wid(100, 0, 1), wid(101, 0, 0)
+    extra = [(1, 1003.000, 1003.050, W, first, 1003.025),
+             (0, 1003.055, 1003.100, W, second, 1003.080)]
+
+    def swap(log: list) -> list:
+        a, b = log.index(first), log.index(second)
+        log[a], log[b] = second, first
+        return log
+
+    config, generators, records, _ = sound_run(extra_ops=extra,
+                                               log_edit=swap)
+    evidence = {}
+    compared = reference.compare(np, config, generators, records, evidence)
+    over = {name for name, (value, _) in compared.items() if value}
+    assert over == {"replica_realtime_wrong"}, compared
+    offenders = evidence["replica_realtime_wrong"]
+    assert len(offenders) == min(compared["replica_realtime_wrong"][0],
+                                 reference.EVIDENCE_ROWS)
+    planted = [o for o in offenders if o["replica"] == "replica_0"
+               and o["write"]["loop"] == 101
+               and o["placed_before"]["loop"] == 100]
+    assert planted, offenders
+    write, before = planted[0]["write"], planted[0]["placed_before"]
+    assert (write["generator"], write["sequence"]) == (0, 0)
+    assert (before["generator"], before["sequence"]) == (1, 0)
+    assert (write["issued"], write["answered"]) == (1003.055, 1003.100)
+    assert (before["issued"], before["answered"]) == (1003.000, 1003.050)
+    assert write["place"] < before["place"]
+    assert planted[0]["answered_before_issue_by_ms"] == pytest.approx(5.0)
+    # As run.py prints them: the offenders, the clock, and last the
+    # numbers compared.
+    launcher = load_module(os.path.join(BENCHMARK, "run.py"))
+    launcher.print_compared(compared, evidence, {"wall_step_ms": 0.0})
+    lines = capsys.readouterr().err.splitlines()
+    assert lines[0] == ("offender replica_realtime_wrong: "
+                        + json.dumps(offenders[0]))
+    assert lines[len(offenders)] == "clock wall_step_ms: 0.0"
+    assert lines[-len(compared):] == [
+        f"compared {name}: {value} (limit {limit})"
+        for name, (value, limit) in compared.items()]
+
+
+def test_every_number_above_its_limit_leaves_its_rows():
+    """The other seventeen: each fault of the tests above, through
+    ``compare``, leaves the rows its count was made from."""
+    config, generators, records, _ = sound_run()
+    log = records["replica_0"]["replica"]
+    broken = dict(records)
+    # A replica that lost its last write, executed its first twice and
+    # holds another value than it last executed; a tracker that reported
+    # slot 1 before its second vote, slot 2 twice, slot 900 never voted.
+    keys = np.r_[log["keys"][:-1], log["keys"][1]]
+    values = np.r_[log["values"][:-1], log["values"][1]]
+    broken["replica_1"] = {**records["replica_1"],
+                           "replica": {"keys": keys, "values": values}}
+    votes, reports = record([(1, 2, 0, 0, 0), [(1, 0)], (1, 3, 0, 0, 1),
+                             (2, 3, 0, 0, 2), [(2, 0), (2, 0)], [(900, 0)],
+                             (3, 4, 0, 0, 0), (3, 4, 0, 0, 1)])
+    broken["proxy_leader_0_1"] = {
+        "record": {"claimed": False, "trackers": [
+            {"window_violations": 3, "board_shape": [3, 64]}]},
+        "replica": None, "trackers": [{"votes": votes, "reports": reports}]}
+    generators[0]["ops"]["latency_s"][0] = -1.0
+    evidence = {}
+    compared = reference.compare(np, config, generators, broken, evidence)
+    over = {name for name, (value, _) in compared.items() if value}
+    assert over == set(evidence)
+    assert over >= {
+        "ops_unanswered", "replica_writes_lost", "replica_writes_repeated",
+        "replica_store_wrong", "replica_logs_differ", "chosen_early",
+        "chosen_extra", "chosen_twice", "chosen_missing",
+        "window_violations", "board_shape_wrong", "chip_owners_wrong"}
+    for name, rows in evidence.items():
+        assert 0 < len(rows) <= reference.EVIDENCE_ROWS
+        assert len(rows) == min(compared[name][0], reference.EVIDENCE_ROWS) \
+            or name in ("window_violations", "replica_logs_differ")
+        json.dumps(rows)           # plain numbers and strings throughout
+    assert evidence["chosen_early"][0]["slot"] == 1
+    assert evidence["chosen_extra"][0]["slot"] == 900
+    assert evidence["replica_writes_lost"][0]["replica"] == "replica_1"
+    assert evidence["window_violations"] == [
+        {"tracker": "proxy_leader_0_1.tracker0", "votes_dropped": 3}]
+
+
 # --- whole runs with the timed path broken underneath ----------------------
 
 
@@ -317,7 +524,39 @@ def test_a_broken_run_prints_correct_false(tmp_path_factory, cell, fault,
     over = {name for name, (value, limit) in result["compared"].items()
             if value > limit}
     assert numbers <= over, result["compared"]
+    # Each number above its limit leaves the rows it was counted from.
+    assert set(result["offenders"]) == over
+    for name in over:
+        assert f"offender {name}: " + json.dumps(
+            result["offenders"][name][0]) in errors
     # Nothing else is broken: the fault fails its own numbers only.
     allowed = numbers | {"chosen_extra", "chosen_early",
                          "replica_store_wrong", "reads_wrong"}
     assert over <= allowed, result["compared"]
+
+
+@pytest.mark.parametrize("fault, step_ms", [("wall_step", 250),
+                                            ("wall_step_back", -250)])
+def test_a_stepped_wall_clock_is_reported_and_decides_nothing(
+        tmp_path_factory, fault, step_ms):
+    """The real cell at toy size, with generators whose ``time.time()``
+    jumps halfway through the window: the host's fault, not the
+    system's, so the run is correct, and says that the clock moved."""
+    stepped = toy_manifest(tmp_path_factory,
+                           generator=os.path.join(FAULTS, fault + ".py"))
+    code, result, errors = run_cell(stepped, "majority.saturated")
+    assert code == 0, errors[-3000:]
+    assert result["correct"] is True, result["compared"]
+    assert set(result["compared"]) == ALL_EIGHTEEN
+    assert all(value == 0 for value, _ in result["compared"].values())
+    assert "offenders" not in result and "offender " not in errors
+    assert result["attempted"] > 0 and result["failed"] == 0
+    # Every generator saw the step and the launcher did not (measured
+    # against the launcher, so that a step of this host's own clock
+    # meanwhile does not fail the test).
+    moved = result["clock"]["by_process_ms"]
+    for name in ("generator_0", "generator_1"):
+        assert moved[name] - moved["launcher"] == pytest.approx(step_ms,
+                                                                abs=5)
+    assert result["clock"]["wall_step_ms"] == max(moved.values(), key=abs)
+    assert f"clock wall_step_ms: {result['clock']['wall_step_ms']}" in errors
