@@ -65,7 +65,22 @@ class VoteBoard(NamedTuple):
     owner: jax.Array   # [window] int32: slot currently occupying the column
 
 
+def _named(name: str):
+    """Give a function a fixed name before it is jitted: a device trace
+    then shows ``jit_<name>`` for the tracker's entry points, whatever
+    the Python functions are called after a refactor."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return rename
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+@_named("fpx_quorum_make_board")
 def make_vote_board(window: int, num_nodes: int) -> VoteBoard:
+    """An empty board, made on the device by ONE program (eager
+    ``jnp.zeros`` / ``jnp.full`` are a compiled program each, and a
+    ``convert_element_type`` beside every fill value)."""
     return VoteBoard(
         votes=jnp.zeros((num_nodes, window), dtype=jnp.uint8),
         rounds=jnp.full((window,), -1, dtype=jnp.int32),
@@ -167,16 +182,6 @@ def _predicate_hit(votes_block: jax.Array, masks_t: tuple,
     return _quorum_hit(votes_block, masks, thresholds, combine_any)
 
 
-def _named(name: str):
-    """Give a function a fixed name before it is jitted: a device trace
-    then shows ``jit_<name>`` for the tracker's entry points, whatever
-    the Python functions are called after a refactor."""
-    def rename(fn):
-        fn.__name__ = fn.__qualname__ = name
-        return fn
-    return rename
-
-
 def _apply_sparse_votes(board: VoteBoard, slots, true_slots, nodes,
                         vote_rounds, valid):
     """Shared traced body of the sparse scatter kernels: ring
@@ -275,15 +280,37 @@ def _record_and_check_epochs(
     return VoteBoard(votes, new_rounds, chosen, owner), newly
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(5, 6, 7))
+#: Bytes of the three int32 scalars that ride below a dense block's votes.
+_WHERE_BYTES = 12
+
+
+def _stage_block(block: np.ndarray, width: int, start: int,
+                 true_start: int, vote_round: int) -> np.ndarray:
+    """The ONE host buffer of a ``_record_block`` call: ``block``'s votes
+    in the first N rows, zero-padded to ``width`` columns, and below them
+    the call's three scalars as little-endian int32 bytes (one more row
+    at any width from 12 columns up). Every argument the host hands a
+    jitted call is a transfer of its own on the calling thread; on the
+    chip one buffer read a seventh less host time a drain than the
+    scalars in an ``int32[3]`` of their own, and little more than half
+    of three scalar arguments (``PERF.md``, PR 30)."""
+    n, b = block.shape
+    staged = np.zeros((n + -(-_WHERE_BYTES // width), width),
+                      dtype=np.uint8)
+    staged[:n, :b] = block
+    staged[n:].reshape(-1)[:_WHERE_BYTES] = np.array(
+        (start, true_start, vote_round), dtype="<i4").view(np.uint8)
+    return staged
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2, 3))
 @_named("fpx_quorum_record_block")
 def _record_block(
     board: VoteBoard,
-    start: jax.Array,        # [] int32 ring offset of the block
-    true_start: jax.Array,   # [] int32 slot number of column `start`
-    block: jax.Array,        # [N, B] uint8 vote arrivals for these slots
-    vote_round: jax.Array,   # [] int32: round all these votes belong to
-    block_size: int,         # static
+    staged: jax.Array,       # uint8, from _stage_block: [N, B] vote
+                             # arrivals for these slots, then the ring
+                             # offset of the block, the slot number of
+                             # that column and the votes' round
     masks_t: tuple,
     meta: tuple,
 ) -> tuple[VoteBoard, jax.Array]:
@@ -298,7 +325,10 @@ def _record_block(
     NOT bumped, so an older-round slot mid-run keeps collecting its own
     round's votes (matching the per-(slot, round) dict semantics).
     """
-    n = board.votes.shape[0]
+    n, block_size = board.votes.shape[0], staged.shape[1]
+    block = staged[:n]
+    start, true_start, vote_round = jax.lax.bitcast_convert_type(
+        staged[n:].reshape(-1)[:_WHERE_BYTES].reshape(3, 4), jnp.int32)
 
     # named_scope: a trace read in Perfetto maps each device operation
     # (the copies and dynamic_update_slices) to one of these three.
@@ -429,13 +459,20 @@ def _shard_board(board: VoteBoard, mesh, window: int) -> VoteBoard:
     )
 
 
-def _replicate(x: jax.Array, mesh) -> jax.Array:
-    """Place ``x`` fully REPLICATED over ``mesh`` (the epoch-plane
-    rule: predicate planes are tiny and every shard checks its own
-    slots against all of them, so replication beats any split)."""
-    from jax.sharding import NamedSharding, PartitionSpec
+def _place_planes(planes: tuple, mesh) -> tuple:
+    """Put host predicate planes on the device once, so that no drain
+    transfers them again; with a ``mesh`` fully REPLICATED over it
+    (the epoch-plane rule: predicate planes are tiny and every shard
+    checks its own slots against all of them, so replication beats any
+    split; explicit placement, so the drain kernels never re-lay them
+    out and DEV1203 stays clean)."""
+    if mesh is None:
+        placement = jax.devices()[0]
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec
 
-    return jax.device_put(x, NamedSharding(mesh, PartitionSpec()))
+        placement = NamedSharding(mesh, PartitionSpec())
+    return tuple(jax.device_put(plane, placement) for plane in planes)
 
 
 def _spec_statics(spec: QuorumSpec) -> tuple[tuple, tuple]:
@@ -480,8 +517,7 @@ def reshape_block(block: np.ndarray, old_universe,
     ``[N_old, B]`` vote block (drain blocks crossing an epoch
     boundary)."""
     cmap = epoch_column_map(old_universe, new_universe)
-    return np.asarray(_reshape_columns(
-        jnp.asarray(block), jnp.asarray(cmap)))
+    return np.asarray(_reshape_columns(np.asarray(block), cmap))
 
 
 class TpuQuorumChecker:
@@ -535,7 +571,12 @@ class TpuQuorumChecker:
         The returned array keeps the PADDED bucket length (entries past
         the input width are padding) -- slicing it on device would
         dispatch a fresh variable-shape executable per width; slice on
-        the host after fetching instead."""
+        the host after fetching instead.
+
+        ONE runtime call with ONE host buffer (:func:`_stage_block`),
+        which the jitted program places itself: no eager ``jnp``
+        operation, each of which is a compiled program or a transfer
+        of its own."""
         n, b = block.shape
         if n != self.num_nodes:
             raise ValueError(f"block has {n} acceptor rows, spec has "
@@ -549,16 +590,12 @@ class TpuQuorumChecker:
         padded = 64
         while padded < b:
             padded *= 2
-        if padded != b and start + padded <= self.window:
-            block = np.concatenate(
-                [np.asarray(block, dtype=np.uint8),
-                 np.zeros((n, padded - b), dtype=np.uint8)], axis=1)
-        else:
+        if start + padded > self.window:
             padded = b
         self.board, newly = _record_block(
-            self.board, jnp.int32(start), jnp.int32(start_slot),
-            jnp.asarray(block, dtype=jnp.uint8),
-            jnp.int32(vote_round), padded, self._masks_t, self._meta)
+            self.board,
+            _stage_block(block, padded, start, start_slot, vote_round),
+            self._masks_t, self._meta)
         return newly
 
     def check_block_async(self, block: np.ndarray) -> jax.Array:
@@ -585,7 +622,7 @@ class TpuQuorumChecker:
             block = np.concatenate(
                 [np.asarray(block, dtype=np.uint8),
                  np.zeros((n, padded - b), dtype=np.uint8)], axis=1)
-        return _check_block(jnp.asarray(block, dtype=jnp.uint8),
+        return _check_block(np.asarray(block, dtype=np.uint8),
                             self._masks_t, self._meta)
 
     def check_block(self, block: np.ndarray) -> np.ndarray:
@@ -647,9 +684,7 @@ class TpuQuorumChecker:
         rounds_p[:b] = np.asarray(rounds, dtype=np.int32)
         valid[:b] = True
         self.board, newly = _record_and_check(
-            self.board, jnp.asarray(slots_p), jnp.asarray(true_p),
-            jnp.asarray(nodes_p),
-            jnp.asarray(rounds_p), jnp.asarray(valid),
+            self.board, slots_p, true_p, nodes_p, rounds_p, valid,
             self._masks_t, self._meta)
         return newly
 
@@ -711,7 +746,7 @@ class TpuQuorumChecker:
         tests/test_reconfig.py)."""
         cmap = epoch_column_map(self.spec.universe, new_spec.universe)
         self.board = VoteBoard(
-            votes=_reshape_columns(self.board.votes, jnp.asarray(cmap)),
+            votes=_reshape_columns(self.board.votes, cmap),
             rounds=self.board.rounds,
             chosen=self.board.chosen,
             owner=self.board.owner,
@@ -724,12 +759,11 @@ class TpuQuorumChecker:
         """GC slot columns below the chosen watermark so the ring can wrap."""
         slots = np.asarray(slots, dtype=np.int32) % self.window
         valid = np.ones(slots.shape[0], dtype=bool)
-        self.board = _release(self.board, jnp.asarray(slots),
-                              jnp.asarray(valid))
+        self.board = _release(self.board, slots, valid)
 
     def check_batch(self, present: np.ndarray) -> np.ndarray:
         """Stateless: evaluate the predicate for ``[B, N]`` responder rows."""
-        return np.asarray(_check_batch(jnp.asarray(present), self._masks_t,
+        return np.asarray(_check_batch(np.asarray(present), self._masks_t,
                                        self._meta))
 
 
@@ -794,25 +828,16 @@ class EpochSegmentedChecker:
         specs = [s.reindexed(self.universe) for s in self._own_specs]
         from frankenpaxos_tpu.quorums.spec import pad_specs
 
-        masks, thresholds, combine_any = pad_specs(specs)
-        self._masks = jnp.asarray(masks)
-        self._thresholds = jnp.asarray(thresholds)
-        self._combine_any = jnp.asarray(combine_any)
         # boundaries[k-1] = first slot of epoch k (epoch 0 governs
         # everything below boundaries[0]). int32 like the board's slot
         # state: x64 is off in jitted kernels, and no ring outlives
         # 2^31 slots between GCs.
-        self._boundaries = jnp.asarray(
-            np.asarray(self._starts[1:], dtype=np.int32))
+        (self._masks, self._thresholds, self._combine_any,
+         self._boundaries) = _place_planes(
+            (*pad_specs(specs),
+             np.asarray(self._starts[1:], dtype=np.int32)), self.mesh)
         self._boundaries_np = np.asarray(self._starts[1:],
                                          dtype=np.int64)
-        if getattr(self, "mesh", None) is not None:
-            # Replicated epoch planes: explicit placement so the drain
-            # kernels never re-lay them out (and DEV1203 stays clean).
-            self._masks = _replicate(self._masks, self.mesh)
-            self._thresholds = _replicate(self._thresholds, self.mesh)
-            self._combine_any = _replicate(self._combine_any, self.mesh)
-            self._boundaries = _replicate(self._boundaries, self.mesh)
 
     def column_of(self, node_id: int) -> int:
         return self.universe.index(node_id)
@@ -832,8 +857,7 @@ class EpochSegmentedChecker:
         if self.universe != old_universe:
             cmap = epoch_column_map(old_universe, self.universe)
             self.board = VoteBoard(
-                votes=_reshape_columns(self.board.votes,
-                                       jnp.asarray(cmap)),
+                votes=_reshape_columns(self.board.votes, cmap),
                 rounds=self.board.rounds,
                 chosen=self.board.chosen,
                 owner=self.board.owner,
@@ -852,8 +876,8 @@ class EpochSegmentedChecker:
         handover boundary."""
         config_idx = self.config_indices(slots)
         return np.asarray(_check_batch_multi(
-            jnp.asarray(present, dtype=jnp.uint8),
-            jnp.asarray(config_idx, dtype=jnp.int32),
+            np.asarray(present, dtype=np.uint8),
+            np.asarray(config_idx, dtype=np.int32),
             self._masks, self._thresholds, self._combine_any))
 
     def check_block(self, start_slot: int,
@@ -894,18 +918,16 @@ class EpochSegmentedChecker:
         rounds_p[:b] = np.asarray(rounds, dtype=np.int32)
         valid[:b] = True
         self.board, newly = _record_and_check_epochs(
-            self.board, jnp.asarray(slots_p), jnp.asarray(true_p),
-            jnp.asarray(nodes_p), jnp.asarray(rounds_p),
-            jnp.asarray(valid), self._boundaries, self._masks,
-            self._thresholds, self._combine_any)
+            self.board, slots_p, true_p, nodes_p, rounds_p, valid,
+            self._boundaries, self._masks, self._thresholds,
+            self._combine_any)
         return np.asarray(newly)[:b]
 
     def release(self, slots: Sequence[int] | np.ndarray) -> None:
         """GC chosen columns below the watermark (ring wrap)."""
         slots = np.asarray(slots, dtype=np.int32) % self.window
         valid = np.ones(slots.shape[0], dtype=bool)
-        self.board = _release(self.board, jnp.asarray(slots),
-                              jnp.asarray(valid))
+        self.board = _release(self.board, slots, valid)
 
 
 class MultiConfigQuorumChecker:
@@ -918,14 +940,12 @@ class MultiConfigQuorumChecker:
     def __init__(self, specs: Sequence[QuorumSpec]):
         from frankenpaxos_tpu.quorums.spec import pad_specs
 
-        masks, thresholds, combine_any = pad_specs(specs)
         self.universe = specs[0].universe
-        self._masks = jnp.asarray(masks)
-        self._thresholds = jnp.asarray(thresholds)
-        self._combine_any = jnp.asarray(combine_any)
+        self._masks, self._thresholds, self._combine_any = _place_planes(
+            pad_specs(specs), None)
 
     def check_batch(self, present: np.ndarray,
                     config_idx: np.ndarray) -> np.ndarray:
         return np.asarray(_check_batch_multi(
-            jnp.asarray(present), jnp.asarray(config_idx, dtype=jnp.int32),
+            np.asarray(present), np.asarray(config_idx, dtype=np.int32),
             self._masks, self._thresholds, self._combine_any))

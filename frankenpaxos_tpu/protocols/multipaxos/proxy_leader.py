@@ -112,6 +112,8 @@ class ProxyLeader(Actor):
             tpu_votes.labels("device"), tpu_votes.labels("host"),
             tpu_votes.labels("spilled"),
             collectors.counter(
+                "multipaxos_proxy_leader_tpu_launches_total"),
+            collectors.counter(
                 "multipaxos_proxy_leader_tpu_window_violations_total"))
         self._tpu_published = (0,) * len(self.metrics_tpu_work)
         self.grid = config.quorum_grid() if config.flexible else None
@@ -583,7 +585,7 @@ class ProxyLeader(Actor):
         increments: colocated proxy leaders share one series."""
         t = self.tracker
         counts = (t.device_drains, t.host_drains, t.device_votes,
-                  t.host_votes, t.spilled_votes,
+                  t.host_votes, t.spilled_votes, t.device_launches,
                   t.checker.window_violations)
         if counts == self._tpu_published:
             return

@@ -131,7 +131,11 @@ class TpuQuorumTracker(QuorumTracker):
     and the ``device_votes`` they carried went through a kernel,
     ``host_drains`` and their ``host_votes`` straight to the host
     tally, and ``spilled_votes`` are the votes of device drains that
-    the stateless check left below quorum and handed to the tally."""
+    the stateless check left below quorum and handed to the tally.
+    ``device_launches`` counts the jitted calls those device drains
+    made, dense or sparse: one a drain is the common case, and more
+    says what splits drains (a ring straddle, several rounds, a sparse
+    tail)."""
 
     def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
                  pipelined: bool = False, mesh=None,
@@ -141,6 +145,7 @@ class TpuQuorumTracker(QuorumTracker):
         self.config = config
         self.pipelined = pipelined
         self.device_drains = 0
+        self.device_launches = 0
         self.device_votes = 0
         self.host_drains = 0
         self.host_votes = 0
@@ -453,6 +458,7 @@ class TpuQuorumTracker(QuorumTracker):
                 block[col, s_arr[inseg] - seg_start] = 1
             dispatched.append((seg_start, seg_width, block,
                                self.checker.check_block_async(block)))
+        self.device_launches += len(dispatched)
         spilled = 0
         for seg_start, seg_width, block, mask in dispatched:
             hit = np.asarray(mask)[:seg_width]
@@ -691,6 +697,7 @@ class TpuQuorumTracker(QuorumTracker):
         if bucket <= room:
             newly = self.checker.record_block_async(start, block,
                                                     vote_round=rnd)
+            self.device_launches += 1
             parts.append(("block", start, bucket, rnd, newly))
         else:
             self._record_board_split(parts, start, block, room, rnd)
@@ -730,6 +737,7 @@ class TpuQuorumTracker(QuorumTracker):
             if sub.any():
                 newly = self.checker.record_block_async(
                     start + i, np.ascontiguousarray(sub), vote_round=rnd)
+                self.device_launches += 1
                 parts.append(("block", start + i, bucket, rnd, newly))
             i += bucket
 
@@ -737,6 +745,7 @@ class TpuQuorumTracker(QuorumTracker):
         """Scatter-path dispatch, chunked so only prewarmed widths run."""
         for at in range(0, idx.size, self.max_chunk):
             chunk = idx[at:at + self.max_chunk]
+            self.device_launches += 1
             parts.append(("votes", slots[chunk], rounds[chunk],
                           self.checker.record_and_check_async(
                               slots[chunk], cols[chunk], rounds[chunk],

@@ -197,18 +197,22 @@ def test_changed_since_runtime_budget():
     """Diff-aware mode on a one-module change stays under 10s (the
     full-run budget is 30s in tests/test_analysis.py): the project
     parses once, the global passes stay memoized, and every rule
-    family skips or narrows to the focus closure."""
+    family skips or narrows to the focus closure.
+
+    The budget is held on the process's own CPU time: the run is
+    single-threaded Python in this process, and a wall clock would
+    also count whatever the other test workers load the host with."""
     import time as _time
 
-    start = _time.monotonic()
+    start = _time.process_time()
     proj = Project(REPO_ROOT, package="frankenpaxos_tpu")
     proj.focus = diff_mod.affected_closure(
         proj, ["frankenpaxos_tpu/bench/pipeline.py"])
     run_rules(proj)
-    elapsed = _time.monotonic() - start
+    elapsed = _time.process_time() - start
     assert elapsed < 10.0, (
-        f"diff-aware paxlint run took {elapsed:.1f}s; the budget is "
-        f"10s on a one-module change (docs/ANALYSIS.md)")
+        f"diff-aware paxlint run took {elapsed:.1f}s of CPU; the budget "
+        f"is 10s on a one-module change (docs/ANALYSIS.md)")
 
 
 # --- the baseline is burned down and stays empty ----------------------------

@@ -1,7 +1,9 @@
 """TpuQuorumChecker vs. the host oracle, including round preemption and GC."""
 
 import itertools
+import logging
 import random
+import re
 
 import numpy as np
 import pytest
@@ -354,3 +356,162 @@ def test_fused_grid_pipeline_step_matches_generic():
                                   np.asarray(generic.chosen))
     np.testing.assert_array_equal(np.asarray(fused.sm_state),
                                   np.asarray(generic.sm_state))
+
+
+# --- how a drain's inputs reach the device --------------------------------
+
+#: Not a power of two and used by no other test, so the programs below
+#: are compiled here and not found in the process's jit cache.
+TRACKER_WINDOW = 3 * 4096
+
+
+def _tracker_config(grid: bool):
+    from frankenpaxos_tpu.deploy import get_protocol
+
+    protocol = get_protocol("multipaxos")
+    ports = iter(range(20000, 21000))
+
+    def port():
+        return ["127.0.0.1", next(ports)]
+
+    raw = protocol.cluster(1, port)
+    if grid:
+        raw["flexible"] = True
+        raw["acceptors"] = [[port() for _ in range(3)] for _ in range(2)]
+    return protocol.load_config(raw)
+
+
+class _TrackerAndOracle:
+    """A pipelined ``TpuQuorumTracker`` fed vote for vote beside the dict
+    oracle; a drain collects at once, and says how many jitted calls it
+    made."""
+
+    def __init__(self, grid: bool, window: int = TRACKER_WINDOW):
+        from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+            DictQuorumTracker,
+            TpuQuorumTracker,
+        )
+
+        config = _tracker_config(grid)
+        self.tracker = TpuQuorumTracker(config, window=window,
+                                        pipelined=True)
+        self.oracle = DictQuorumTracker(config)
+        # A write quorum: f+1 of the one group, or one of each grid row.
+        self.quorum = ((0, 0), (1, 0)) if grid else ((0, 0), (0, 1))
+
+    def vote(self, slots, round: int = 0, voters=None) -> None:
+        for slot in slots:
+            for group, index in voters or self.quorum:
+                self.tracker.record(slot, round, group, index)
+                self.oracle.record(slot, round, group, index)
+
+    def drain(self) -> int:
+        """Drain both, hold the tracker to the oracle's chosen list, and
+        return the number of launches the drain made."""
+        before = (self.tracker.device_drains, self.tracker.device_launches)
+        assert self.tracker.drain() == []
+        got = []
+        while (dispatch := self.tracker.take_dispatch()) is not None:
+            got.extend(self.tracker.collect(dispatch))
+        assert sorted(got) == sorted(self.oracle.drain())
+        assert self.tracker.device_drains == before[0] + 1
+        return self.tracker.device_launches - before[1]
+
+
+class _CompiledNames(logging.Handler):
+    """The names of the programs ``jax.log_compiles()`` reports."""
+
+    def __init__(self):
+        super().__init__()
+        self.names: list = []
+
+    def emit(self, record) -> None:
+        found = re.match(r"Compiling (?:jit\()?([\w.\-]+)",
+                         record.getMessage())
+        if found:
+            self.names.append(found.group(1))
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["majority", "grid2x3"])
+def test_tracker_compiles_only_its_named_programs_and_none_after_prewarm(
+        grid):
+    """A drain hands the device host buffers in one jitted call: no eager
+    ``jnp`` operation (each a compiled ``convert_element_type`` or
+    ``broadcast_in_dim`` of its own) on the way, and after the prewarm
+    nothing compiles at all, whatever shape the drain has."""
+    import jax
+
+    handler = _CompiledNames()
+    logger = logging.getLogger("jax")
+    logger.addHandler(handler)
+    try:
+        with jax.log_compiles():
+            both = _TrackerAndOracle(grid)
+            prewarm = list(handler.names)
+            del handler.names[:]
+            window = both.tracker.checker.window
+            # Every dense bucket: 64, 256, 1024, 4096 columns.
+            for start, width in ((0, 40), (100, 200), (400, 800),
+                                 (1300, 3000)):
+                both.vote(range(start, start + width))
+                assert both.drain() == 1
+            # A ring straddle, two rounds in one drain, a sparse tail.
+            both.vote(range(window - 64, window + 64))
+            assert both.drain() == 2
+            both.vote(range(window + 100, window + 140), round=1)
+            both.vote(range(window + 200, window + 206), round=2)
+            assert both.drain() == 2
+            both.vote(range(window + 1000, window + 2000, 10), round=1)
+            assert both.drain() == 1
+    finally:
+        logger.removeHandler(handler)
+    assert "fpx_quorum_record_block" in prewarm, prewarm
+    assert [name for name in prewarm
+            if not name.startswith("fpx_quorum_")] == []
+    assert handler.names == []
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["majority", "grid2x3"])
+def test_tracker_counts_one_launch_a_dense_drain_and_two_a_ring_straddle(
+        grid):
+    both = _TrackerAndOracle(grid, window=4096)
+    tracker = both.tracker
+    assert (tracker.device_drains, tracker.device_launches) == (0, 0)
+    # Single round, inside one bucket: one block, one launch; the votes
+    # below quorum stay on the board for the next drain.
+    both.vote(range(0, 30))
+    both.vote(range(30, 50), voters=both.quorum[:1])
+    assert both.drain() == 1
+    both.vote(range(30, 50), voters=both.quorum[1:])
+    assert both.drain() == 1
+    # The ring's end inside the block: one launch on each side of it.
+    both.vote(range(4096 - 20, 4096 + 20))
+    assert both.drain() == 2
+    assert (tracker.device_drains, tracker.device_launches) == (3, 4)
+    assert tracker.checker.window_violations == 0
+
+
+@pytest.mark.parametrize("width", [1, 5, 12, 13, 64])
+def test_record_block_scalars_survive_the_one_host_buffer(width):
+    """The block's ring offset, slot number and round ride below its votes
+    as bytes (``_stage_block``), one more row from 12 columns up and
+    several below: a slot near 2^31 and a round of four different bytes
+    come out of the kernel as they went in, at any width (the prewarm's
+    round -1 goes the same way in every tracker test)."""
+    window = 4096
+    checker = TpuQuorumChecker(SimpleMajority(range(3)).write_spec(),
+                               window=window)
+    start_slot = 2**31 - 1 - window + 7      # column 6 of a late ring
+    block = np.zeros((3, width), dtype=np.uint8)
+    vote_round = 0x01020304
+    block[0] = 1
+    assert not checker.record_block(start_slot, block, vote_round).any()
+    block[:] = 0
+    block[2] = 1
+    assert checker.record_block(start_slot, block, vote_round).all()
+    columns = slice(start_slot % window, start_slot % window + width)
+    np.testing.assert_array_equal(
+        np.asarray(checker.board.owner)[columns],
+        start_slot + np.arange(width))
+    assert (np.asarray(checker.board.rounds)[columns] == vote_round).all()
+    assert checker.window_violations == 0
