@@ -187,16 +187,20 @@ class Acceptor(Actor, DurableRole):
             self._handle_phase1a(src, message)
         elif isinstance(message, Phase2a):
             self.metrics_requests.labels("Phase2a").inc()
-            self._handle_phase2a(src, message)
+            with self.trace_stage("vote"):
+                self._handle_phase2a(src, message)
         elif isinstance(message, Phase2aRun):
             self.metrics_requests.labels("Phase2aRun").inc()
-            self._handle_phase2a_run(src, message)
+            with self.trace_stage("vote"):
+                self._handle_phase2a_run(src, message)
         elif isinstance(message, MaxSlotRequest):
             self.metrics_requests.labels("MaxSlotRequest").inc()
-            self._handle_max_slot_request(src, message)
+            with self.trace_stage("max-slot"):
+                self._handle_max_slot_request(src, message)
         elif isinstance(message, BatchMaxSlotRequest):
             self.metrics_requests.labels("BatchMaxSlotRequest").inc()
-            self._handle_batch_max_slot_request(src, message)
+            with self.trace_stage("max-slot"):
+                self._handle_batch_max_slot_request(src, message)
         elif isinstance(message, EpochCommit):
             self.metrics_requests.labels("EpochCommit").inc()
             self._handle_epoch_commit(src, message)

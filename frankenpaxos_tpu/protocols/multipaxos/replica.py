@@ -101,8 +101,12 @@ class Replica(Actor, DurableRole):
             "multipaxos_replica_executed_commands_total")
         self.metrics_reads = collectors.counter(
             "multipaxos_replica_executed_reads_total")
+        self.metrics_deferred_reads = collectors.counter(
+            "multipaxos_replica_deferred_reads_total")
         self.index = list(config.replica_addresses).index(address)
         self.log: BufferMap = BufferMap(options.log_grow_size)
+        # slot -> [(when it was parked, its commands)], one entry a
+        # parked read or a parked batch; taken out as the slot executes.
         self.deferred_reads: BufferMap = BufferMap(options.log_grow_size)
         # Every entry below executed_watermark has been executed; numChosen
         # counts chosen entries -- together they detect pending holes.
@@ -314,9 +318,9 @@ class Replica(Actor, DurableRole):
                     self._execute_command(slot, command, replies)
             else:
                 assert isinstance(value, Noop)
-            reads = self.deferred_reads.get(slot)
-            if reads is not None:
-                self._process_deferred_reads(reads)
+            parked = self.deferred_reads.pop(slot)
+            if parked is not None:
+                self._process_deferred_reads(parked)
             self.executed_watermark += 1
             self._wm_dirty = True
 
@@ -340,11 +344,23 @@ class Replica(Actor, DurableRole):
             for reply in replies:
                 self.send(reply.command_id.client_address, reply)
 
-    def _process_deferred_reads(self, reads: list[Command]) -> None:
+    def _process_deferred_reads(self, parked: list) -> None:
+        """Answer what ``_defer_read`` parked at a slot that has now
+        executed. Stage ``read``, one scope a slot (inside ``execute``,
+        which it subtracts from), and one ``read-park-wait``
+        observation a parked read or batch: how long it sat (a read
+        served at once observes 0, see ``_read_now``)."""
+        metrics = self.transport.runtime_metrics
+        if metrics is not None:
+            now = metrics.clock()
+            for parked_at, _ in parked:
+                metrics.observe_stage("read-park-wait", now - parked_at)
+        reads = [c for _, commands in parked for c in commands]
         self._deferred_read_count -= len(reads)
         if self.admission is not None:
             self.admission.set_inflight(self._deferred_read_count)
-        self._send_read_replies([self._execute_read(c) for c in reads])
+        with self.trace_stage("read"):
+            self._send_read_replies([self._execute_read(c) for c in reads])
 
     def _admit_read(self, command: Command, sync: bool = True) -> bool:
         """paxload read admission: the in-flight measure is the
@@ -371,13 +387,31 @@ class Replica(Actor, DurableRole):
             reason=admission.last_reason))
         return False
 
-    def _defer_read(self, slot: int, command: Command) -> None:
-        reads = self.deferred_reads.get(slot)
-        if reads is None:
-            self.deferred_reads.put(slot, [command])
+    def _read_now(self, commands) -> None:
+        """Execute reads whose slot has executed and answer each its
+        client: stage ``read``, one scope a message. It waited for no
+        slot, which ``read-park-wait`` counts as an observation of 0,
+        so that the stage's mean is over all reads and batches, parked
+        or not."""
+        metrics = self.transport.runtime_metrics
+        if metrics is not None:
+            metrics.observe_stage("read-park-wait", 0.0)
+        with self.trace_stage("read"):
+            self._send_read_replies(
+                [self._execute_read(c) for c in commands])
+
+    def _defer_read(self, slot: int, commands) -> None:
+        """Park one read, or one batch of reads, until ``slot`` has
+        executed (``_execute_log`` takes them out again)."""
+        metrics = self.transport.runtime_metrics
+        entry = (metrics.clock() if metrics is not None else 0.0, commands)
+        parked = self.deferred_reads.get(slot)
+        if parked is None:
+            self.deferred_reads.put(slot, [entry])
         else:
-            reads.append(command)
-        self._deferred_read_count += 1
+            parked.append(entry)
+        self._deferred_read_count += len(commands)
+        self.metrics_deferred_reads.inc(len(commands))
 
     # --- handlers ---------------------------------------------------------
     def receive(self, src: Address, message) -> None:
@@ -424,8 +458,9 @@ class Replica(Actor, DurableRole):
                         if self._admit_read(c, sync=False)]
         try:
             if commands:
-                self._send_read_replies(
-                    [self._execute_read(c) for c in commands])
+                with self.trace_stage("read"):
+                    self._send_read_replies(
+                        [self._execute_read(c) for c in commands])
         finally:
             if admission is not None:
                 admission.set_inflight(self._deferred_read_count)
@@ -448,11 +483,9 @@ class Replica(Actor, DurableRole):
             if not commands:
                 return
             if batch.slot >= self.executed_watermark:
-                for command in commands:
-                    self._defer_read(batch.slot, command)
+                self._defer_read(batch.slot, commands)
                 return
-            self._send_read_replies(
-                [self._execute_read(c) for c in commands])
+            self._read_now(commands)
         finally:
             # Settle to the true backlog: deferred reads are in
             # _deferred_read_count; immediately-executed ones release.
@@ -537,9 +570,9 @@ class Replica(Actor, DurableRole):
         if not self._admit_read(request.command):
             return
         if request.slot >= self.executed_watermark:
-            self._defer_read(request.slot, request.command)
+            self._defer_read(request.slot, (request.command,))
             return
-        self.send(src, self._execute_read(request.command))
+        self._read_now((request.command,))
 
     def _handle_sequential_read_request(self, src: Address,
                                         request: SequentialReadRequest
@@ -553,4 +586,5 @@ class Replica(Actor, DurableRole):
                                       request: EventualReadRequest) -> None:
         if not self._admit_read(request.command):
             return
-        self.send(src, self._execute_read(request.command))
+        with self.trace_stage("read"):
+            self.send(src, self._execute_read(request.command))

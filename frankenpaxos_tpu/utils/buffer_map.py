@@ -54,6 +54,16 @@ class BufferMap(Generic[V]):
                                           - len(self._buffer)))
         self._buffer[i] = value
 
+    def pop(self, key: int) -> Optional[V]:
+        """Take ``key``'s value out: what ``get`` would give, and the
+        map holds it no longer."""
+        i = key - self._watermark
+        if i < 0 or i >= len(self._buffer):
+            return None
+        value = self._buffer[i]
+        self._buffer[i] = None
+        return value
+
     def contains(self, key: int) -> bool:
         return self.get(key) is not None
 

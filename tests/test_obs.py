@@ -858,21 +858,28 @@ class TestServedPathStages:
 
     def test_the_handler_stage_and_the_role_summary_share_a_clock(
             self, served):
-        """An acceptor opens no stage inside its handlers, so the
-        handler stage's self time IS the summaries' total."""
+        """The role's summaries ride the handler scope's clock pair and
+        are given its WHOLE duration; the stages hold self time. An
+        acceptor opens ``vote`` (and, for reads, ``max-slot``) inside
+        its handlers, so the handler stage's self time plus theirs is
+        the summaries' total."""
         served.closed_loops(4, 5)
         served.settle()
         acceptor = served.collectors["acceptor_0"].metrics
         latency = acceptor["multipaxos_acceptor_requests_latency_seconds"]
-        handler = acceptor["fpx_runtime_drain_stage_seconds"].labels(
-            "acceptor_0", "handler")
+        stages = [acceptor["fpx_runtime_drain_stage_seconds"].labels(
+            "acceptor_0", stage) for stage in ("handler", "vote",
+                                                "max-slot")]
         # Read on the acceptor's loop, between deliveries.
-        stage, summaries = served.on_loop("acceptor_0", lambda: (
-            (handler.get_count(), handler.get_sum()),
+        handlers, inside, summaries = served.on_loop("acceptor_0", lambda: (
+            stages[0].get_count(),
+            [(stage.get_count(), stage.get_sum()) for stage in stages],
             [(child.count, child.value)
              for child in latency._children.values()]))
-        assert stage[0] == sum(count for count, _ in summaries) > 0
-        assert stage[1] == pytest.approx(sum(s for _, s in summaries))
+        assert handlers == sum(count for count, _ in summaries) > 0
+        assert inside[1][0] > 0                  # votes were cast
+        assert sum(s for _, s in inside) == pytest.approx(
+            sum(s for _, s in summaries))
 
     def test_a_collection_on_a_loops_thread_is_stage_gc(self, served):
         """``watch_gc`` in a served role: a collection that stops the

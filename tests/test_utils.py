@@ -49,6 +49,17 @@ class TestBufferMap:
         assert m.get(4) is None
         assert m.watermark == 5
 
+    @pytest.mark.parametrize("key, held", [(2, "2"), (5, "5"), (1, None),
+                                           (9, None), (500, None)])
+    def test_pop_takes_a_value_out_once(self, key, held):
+        m = BufferMap(grow_size=4)
+        for i in range(6):
+            m.put(i, str(i))
+        m.garbage_collect(2)
+        assert m.pop(key) == held
+        assert m.pop(key) is None and m.get(key) is None
+        assert m.to_dict() == {k: str(k) for k in range(2, 6) if k != key}
+
     def test_items(self):
         m = BufferMap()
         m.put(1, "b")
