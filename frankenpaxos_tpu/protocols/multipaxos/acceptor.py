@@ -17,8 +17,6 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     BatchMaxSlotReply,
     BatchMaxSlotRequest,
     CommandBatchOrNoop,
-    MaxSlotReply,
-    MaxSlotRequest,
     Nack,
     Phase1a,
     Phase1b,
@@ -193,10 +191,6 @@ class Acceptor(Actor, DurableRole):
             self.metrics_requests.labels("Phase2aRun").inc()
             with self.trace_stage("vote"):
                 self._handle_phase2a_run(src, message)
-        elif isinstance(message, MaxSlotRequest):
-            self.metrics_requests.labels("MaxSlotRequest").inc()
-            with self.trace_stage("max-slot"):
-                self._handle_max_slot_request(src, message)
         elif isinstance(message, BatchMaxSlotRequest):
             self.metrics_requests.labels("BatchMaxSlotRequest").inc()
             with self.trace_stage("max-slot"):
@@ -422,13 +416,6 @@ class Acceptor(Actor, DurableRole):
             runs.append(acks[start:i])
             start = i
         return runs
-
-    def _handle_max_slot_request(self, src: Address,
-                                 request: MaxSlotRequest) -> None:
-        self.send(src, MaxSlotReply(command_id=request.command_id,
-                                    group_index=self.group_index,
-                                    acceptor_index=self.index,
-                                    slot=self.max_voted_slot))
 
     def _handle_batch_max_slot_request(self, src: Address,
                                        request: BatchMaxSlotRequest) -> None:

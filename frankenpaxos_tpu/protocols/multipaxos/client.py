@@ -7,13 +7,30 @@ pending-operation state machines with resend timers:
     batcher (or the round's leader when there are no batchers); NotLeader
     bounces trigger LeaderInfoRequest round discovery.
   * linearizable reads (readImpl + handleMaxSlotReply,
-    Client.scala:604-700, 851-933): MaxSlotRequest to f+1 of a random
-    acceptor group (or a grid read quorum); on quorum, read at
+    Client.scala:604-700, 851-933): a max-slot question to f+1 of a
+    random acceptor group (or a grid read quorum); on quorum, read at
     ``max_slot + num_groups - 1`` (grid: ``max_slot``) at a random
-    replica, deferred there until executed.
+    replica, deferred there until executed. Without read batchers the
+    reads ONE event-loop pass issues share one quorum round and travel
+    as one batch (below).
   * sequential reads (Client.scala:697+): read at the largest slot this
     pseudonym has seen.
   * eventual reads (Client.scala:739+): straight to a random replica.
+
+A pass's linearizable reads are one batch (runs/client.py stages them
+as it stages coalesced writes): ONE BatchMaxSlotRequest to the quorum,
+on its answers ONE ReadRequestBatch to one replica, which answers with
+ONE ReadReplyBatch; one resend timer a batch and phase, the retry
+budget still charged read by read. A pass with one read is a batch of
+one. The guarantee does not move: a read is staged when issued and its
+batch's BatchMaxSlotRequest leaves at the end of that pass, so every
+acceptor of the quorum is asked AFTER the read was issued. A write
+acknowledged before the read was issued had f+1 votes by then; any f+1
+acceptors of the group (any row of the grid) intersect them, so the
+batch's slot is at or above that write's, and the replica answers only
+once that slot has executed. Still f+1 acceptors a read, still one
+replica that has executed the slot. A batch is closed when its request
+is sent: a max-slot answer is never used for a read issued later.
 """
 
 from __future__ import annotations
@@ -24,6 +41,8 @@ from typing import Callable, Optional
 
 from frankenpaxos_tpu.protocols.multipaxos.config import MultiPaxosConfig
 from frankenpaxos_tpu.protocols.multipaxos.messages import (
+    BatchMaxSlotReply,
+    BatchMaxSlotRequest,
     ClientReply,
     ClientReplyArray,
     ClientRequest,
@@ -33,11 +52,11 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     EventualReadRequest,
     LeaderInfoReplyClient,
     LeaderInfoRequestClient,
-    MaxSlotReply,
-    MaxSlotRequest,
     NotLeaderClient,
     ReadReply,
+    ReadReplyBatch,
     ReadRequest,
+    ReadRequestBatch,
     SequentialReadRequest,
 )
 from frankenpaxos_tpu.roundsystem import ClassicRoundRobin
@@ -92,19 +111,19 @@ class _PendingWrite:
     backoff_pending: bool = False
 
 
-@dataclasses.dataclass
-class _MaxSlot:
-    # No backoff_pending: while the state is _MaxSlot the only
-    # outstanding requests are MaxSlotRequests to acceptors, which
-    # carry no admission controller and never draw a Rejected (the
-    # state becomes _PendingRead in the same handler that sends the
-    # rejectable ReadRequest).
-    id: int
-    command: bytes
-    callback: Callback
-    replies: dict[tuple[int, int], int]
-    resend: object
-    attempts: int = 0
+class _BatchTimed:
+    """The ``resend`` of a read that travels in a batch: it has no
+    timer of its own, its batch's timer re-sends it while it is
+    pending (``Client._live_reads``)."""
+
+    def start(self) -> None:
+        pass
+
+    def stop(self) -> None:
+        pass
+
+
+_BATCH_TIMED = _BatchTimed()
 
 
 @dataclasses.dataclass
@@ -119,6 +138,29 @@ class _PendingRead:
     # read can be re-issued after backoff without re-deriving the slot.
     request: object = None
     replica: object = None
+    # The batch a linearizable read travels in (no read batchers): set
+    # when the batch's BatchMaxSlotRequest leaves, None again once the
+    # read goes on alone (``_reissue``). While the batch has no slot
+    # yet the only outstanding requests are to acceptors, which carry
+    # no admission controller and never draw a Rejected.
+    batch: object = None
+
+
+@dataclasses.dataclass
+class _ReadBatch:
+    """The linearizable reads one pass issued: one quorum round, then
+    one read request. ``reads`` holds (pseudonym, state, Command) of
+    the reads last sent; ``replica`` is None until the quorum
+    answered."""
+    id: int
+    reads: list
+    request: BatchMaxSlotRequest
+    resend_to: list
+    timer: object = None
+    replies: dict = dataclasses.field(default_factory=dict)
+    slot: int = -1
+    replica: object = None
+    unanswered: int = 0
 
 
 class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
@@ -157,6 +199,11 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         # write): timer construction was a measurable per-command cost
         # at drain widths in the thousands.
         self._write_timers: dict[int, object] = {}
+        # Read batches waiting for their max-slot quorum, by the batch
+        # id this client counts up (it rides BatchMaxSlotRequest's
+        # read_batcher_id); a batch leaves as its ReadRequestBatch does.
+        self._read_batches: dict[int, _ReadBatch] = {}
+        self._next_read_batch_id = 0
 
     # --- public API -------------------------------------------------------
     def write(self, pseudonym: int, command: bytes,
@@ -244,36 +291,14 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
                                                   replica=batcher)
             self.ids[pseudonym] = id + 1
             return
-        request = MaxSlotRequest(CommandId(self.address, pseudonym, id))
-        if not self.config.flexible:
-            group_index = self.rng.randrange(self.config.num_acceptor_groups)
-            group = list(self.config.acceptor_addresses[group_index])
-            quorum = self.rng.sample(group, self.config.f + 1)
-            resend_to = group
-        else:
-            quorum = [self._acceptor_address(flat)
-                      for flat in self.grid.random_read_quorum(self.rng)]
-            resend_to = [a for g in self.config.acceptor_addresses
-                         for a in g]
-        for acceptor in quorum:
-            self.send(acceptor, request)
-
-        def resend():
-            state = self.states.get(pseudonym)
-            if not isinstance(state, _MaxSlot) \
-                    or not self._consume_retry(pseudonym, state,
-                                               "failover"):
-                return
-            for acceptor in resend_to:
-                self.send(acceptor, request)
-            timer.start()
-
-        timer = self.timer(f"resendMaxSlot{pseudonym}",
-                           self.options.resend_max_slot_requests_period_s,
-                           resend)
-        timer.start()
-        self.states[pseudonym] = _MaxSlot(id, command, callback, {}, timer)
+        # Stage for the end-of-pass flush (runs/client.py): the reads
+        # of one pass share one quorum round, asked after all of them
+        # were issued.
+        state = _PendingRead(id, command, callback, _BATCH_TIMED)
+        self.states[pseudonym] = state
         self.ids[pseudonym] = id + 1
+        self._stage_read((pseudonym, state, Command(
+            CommandId(self.address, pseudonym, id), command)))
 
     def sequential_read(self, pseudonym: int, command: bytes,
                         callback: Optional[Callback] = None) -> None:
@@ -347,6 +372,82 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
                                      key=(self.address, -1))
         self.send(dst, ClientRequestArray(commands=tuple(staged)))
 
+    def _flush_staged_reads(self, staged: list) -> None:
+        """Open the quorum round of the reads this pass issued: ONE
+        BatchMaxSlotRequest to f+1 acceptors of a random group (a
+        random read quorum of the grid). The batch is closed here; a
+        read issued from now on waits for the next round."""
+        batch_id = self._next_read_batch_id
+        self._next_read_batch_id += 1
+        if not self.config.flexible:
+            group_index = self.rng.randrange(self.config.num_acceptor_groups)
+            group = list(self.config.acceptor_addresses[group_index])
+            quorum = self.rng.sample(group, self.config.f + 1)
+            resend_to = group
+        else:
+            quorum = [self._acceptor_address(flat)
+                      for flat in self.grid.random_read_quorum(self.rng)]
+            resend_to = [a for g in self.config.acceptor_addresses
+                         for a in g]
+        batch = _ReadBatch(
+            batch_id, staged,
+            BatchMaxSlotRequest(read_batcher_index=-1,
+                                read_batcher_id=batch_id),
+            resend_to)
+        for _, state, _ in staged:
+            state.batch = batch
+        self._read_batches[batch_id] = batch
+        for acceptor in quorum:
+            self.send(acceptor, batch.request)
+        batch.timer = self.timer(
+            f"resendMaxSlotBatch{batch_id}",
+            self.options.resend_max_slot_requests_period_s,
+            lambda: self._resend_read_batch(batch))
+        batch.timer.start()
+
+    def _live_reads(self, batch: _ReadBatch) -> list:
+        """The reads of ``batch`` that are still its to re-send:
+        pending, not answered, given up, backing off or gone on
+        alone."""
+        states = self.states
+        return [read for read in batch.reads
+                if states.get(read[0]) is read[1]
+                and read[1].batch is batch
+                and not read[1].backoff_pending]
+
+    def _close_read_batch(self, batch: _ReadBatch) -> None:
+        """Nothing of ``batch`` is left to re-send: let go of its timer
+        and its reads (each points back at it), so that nothing waits
+        for the cycle collector."""
+        if batch.timer is not None:
+            batch.timer.stop()
+            batch.timer = None
+        batch.reads = ()
+
+    def _send_read_request_batch(self, batch: _ReadBatch) -> None:
+        batch.unanswered = len(batch.reads)
+        self.send(batch.replica, ReadRequestBatch(
+            slot=batch.slot,
+            commands=tuple(command for _, _, command in batch.reads)))
+
+    def _resend_read_batch(self, batch: _ReadBatch) -> None:
+        """A batch's resend timer, either phase. A timeout charges each
+        read still pending its own retry; one that exhausts its budget
+        completes with RETRY_EXHAUSTED and leaves the batch."""
+        batch.reads = [
+            read for read in self._live_reads(batch)
+            if self._consume_retry(read[0], read[1], "failover")]
+        if not batch.reads:
+            self._read_batches.pop(batch.id, None)
+            self._close_read_batch(batch)
+            return
+        if batch.replica is None:
+            for acceptor in batch.resend_to:
+                self.send(acceptor, batch.request)
+        else:
+            self._send_read_request_batch(batch)
+        batch.timer.start()
+
     def _note_shed_source(self, src: Address, rejected) -> float:
         """Attribute a Rejected to its ingest shard: floor reissue
         backoff against THAT shard only (runs/client.py hook)."""
@@ -378,12 +479,18 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
 
     # --- handlers ---------------------------------------------------------
     def receive(self, src: Address, message) -> None:
+        # Reads a handler's callbacks issue wait for on_drain
+        # (runs/client.py: one batch a pass).
+        self._in_pass = True
         if isinstance(message, ClientReply):
             self._handle_client_reply(src, message)
         elif isinstance(message, ClientReplyArray):
             self._handle_client_reply_array(src, message)
-        elif isinstance(message, MaxSlotReply):
-            self._handle_max_slot_reply(src, message)
+        elif isinstance(message, ReadReplyBatch):
+            for reply in message.batch:
+                self._handle_read_reply(src, reply)
+        elif isinstance(message, BatchMaxSlotReply):
+            self._handle_batch_max_slot_reply(src, message)
         elif isinstance(message, ReadReply):
             self._handle_read_reply(src, message)
         elif isinstance(message, NotLeaderClient):
@@ -411,8 +518,22 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
                 self._stage_write(request.command)
             else:
                 self._send_client_request(request)
-        elif isinstance(state, _PendingRead) and state.request is not None:
-            self.send(state.replica, state.request)
+        elif isinstance(state, _PendingRead):
+            batch = state.batch
+            if batch is not None and batch.replica is not None:
+                # A read its batch's replica refused goes on alone, at
+                # the batch's slot and replica, on a timer of its own.
+                state.batch = None
+                state.replica = batch.replica
+                state.request = ReadRequest(
+                    slot=batch.slot,
+                    command=Command(
+                        CommandId(self.address, pseudonym, state.id),
+                        state.command))
+                state.resend = self._make_read_resend_timer(
+                    pseudonym, state.replica, state.request)
+            if state.request is not None:
+                self.send(state.replica, state.request)
 
     def _handle_client_reply(self, src: Address, reply: ClientReply) -> None:
         pseudonym = reply.command_id.client_pseudonym
@@ -446,45 +567,44 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
             self.metrics_replies.inc()
             state.callback(result)
 
-    def _handle_max_slot_reply(self, src: Address,
-                               reply: MaxSlotReply) -> None:
-        pseudonym = reply.command_id.client_pseudonym
-        state = self.states.get(pseudonym)
-        if not isinstance(state, _MaxSlot) \
-                or reply.command_id.client_id != state.id:
-            self.logger.debug(f"stale MaxSlotReply {reply}")
+    def _handle_batch_max_slot_reply(self, src: Address,
+                                     reply: BatchMaxSlotReply) -> None:
+        batch = self._read_batches.get(reply.read_batcher_id)
+        if batch is None:
+            self.logger.debug(f"stale BatchMaxSlotReply {reply}")
             return
-        state.replies[(reply.group_index, reply.acceptor_index)] = reply.slot
+        replies = batch.replies
+        replies[(reply.group_index, reply.acceptor_index)] = reply.slot
         if not self.config.flexible:
-            if len(state.replies) < self.config.f + 1:
+            if len(replies) < self.config.f + 1:
                 return
         else:
-            flat = {g * self._row_size + i for g, i in state.replies}
+            flat = {g * self._row_size + i for g, i in replies}
             if not self.grid.is_superset_of_read_quorum(flat):
                 return
 
-        max_slot = max(state.replies.values())
+        del self._read_batches[batch.id]
+        batch.timer.stop()
+        batch.reads = self._live_reads(batch)
+        if not batch.reads:
+            self._close_read_batch(batch)
+            return
+        max_slot = max(replies.values())
         if self.options.unsafe_read_at_first_slot:
-            slot = 0
+            batch.slot = 0
         elif self.config.flexible or self.options.unsafe_read_at_i:
-            slot = max_slot
+            batch.slot = max_slot
         else:
             # Slots round-robin over groups; the true global max voted slot
             # can exceed this group's by at most num_groups - 1.
-            slot = max_slot + self.config.num_acceptor_groups - 1
-        request = ReadRequest(
-            slot=slot,
-            command=Command(CommandId(self.address, pseudonym, state.id),
-                            state.command))
-        replica = self._random_replica()
-        self.send(replica, request)
-        state.resend.stop()
-        timer = self._make_read_resend_timer(pseudonym, replica, request)
-        self.states[pseudonym] = _PendingRead(state.id, state.command,
-                                              state.callback, timer,
-                                              attempts=state.attempts,
-                                              request=request,
-                                              replica=replica)
+            batch.slot = max_slot + self.config.num_acceptor_groups - 1
+        batch.replica = self._random_replica()
+        self._send_read_request_batch(batch)
+        batch.timer = self.timer(
+            f"resendReadBatch{batch.id}",
+            self.options.resend_read_request_period_s,
+            lambda: self._resend_read_batch(batch))
+        batch.timer.start()
 
     def _handle_read_reply(self, src: Address, reply: ReadReply) -> None:
         pseudonym = reply.command_id.client_pseudonym
@@ -497,6 +617,13 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         self.largest_seen_slots[pseudonym] = max(
             self.largest_seen_slots.get(pseudonym, -1), reply.slot)
         del self.states[pseudonym]
+        batch = state.batch
+        if batch is not None:
+            # The count says when to look; the look decides (a read
+            # that left the batch another way was counted too).
+            batch.unanswered -= 1
+            if batch.unanswered <= 0 and not self._live_reads(batch):
+                self._close_read_batch(batch)
         state.callback(reply.result)
 
     def _handle_not_leader(self, src: Address, _: NotLeaderClient) -> None:

@@ -265,19 +265,6 @@ class ClientReplyBatch:
 
 
 @dataclasses.dataclass(frozen=True)
-class MaxSlotRequest:
-    command_id: CommandId
-
-
-@dataclasses.dataclass(frozen=True)
-class MaxSlotReply:
-    command_id: CommandId
-    group_index: int
-    acceptor_index: int
-    slot: int
-
-
-@dataclasses.dataclass(frozen=True)
 class ReadRequest:
     slot: int
     command: Command
@@ -328,6 +315,11 @@ class EventualReadRequestBatch:
 
 @dataclasses.dataclass(frozen=True)
 class BatchMaxSlotRequest:
+    """The max-slot question of a batch of linearizable reads, from a
+    read batcher or from a client (the reads one of its loop passes
+    issued). The acceptor answers whoever sent it and echoes both
+    fields; ``read_batcher_id`` is the sender's own count of its
+    batches. A client has no batcher index and puts -1 there."""
     read_batcher_index: int
     read_batcher_id: int
 

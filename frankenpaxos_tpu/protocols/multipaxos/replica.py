@@ -103,6 +103,10 @@ class Replica(Actor, DurableRole):
             "multipaxos_replica_executed_reads_total")
         self.metrics_deferred_reads = collectors.counter(
             "multipaxos_replica_deferred_reads_total")
+        # One a read request message handled, whatever its width; with
+        # executed_reads it gives reads a request message.
+        self.metrics_read_messages = collectors.counter(
+            "multipaxos_replica_read_messages_total")
         self.index = list(config.replica_addresses).index(address)
         self.log: BufferMap = BufferMap(options.log_grow_size)
         # slot -> [(when it was parked, its commands)], one entry a
@@ -338,11 +342,23 @@ class Replica(Actor, DurableRole):
 
     def _send_read_replies(self, replies: list[ReadReply]) -> None:
         proxy = self._proxy_replica_address()
-        if len(replies) > 1 and proxy is not None:
-            self.send(proxy, ReadReplyBatch(batch=tuple(replies)))
-        else:
+        if len(replies) <= 1:
             for reply in replies:
                 self.send(reply.command_id.client_address, reply)
+        elif proxy is not None:
+            self.send(proxy, ReadReplyBatch(batch=tuple(replies)))
+        else:
+            # A batch is answered as a batch: one message a client
+            # address (as _reply_to_run groups a run's write replies).
+            by_client: dict = {}
+            for reply in replies:
+                by_client.setdefault(reply.command_id.client_address,
+                                     []).append(reply)
+            for address, batch in by_client.items():
+                if len(batch) == 1:
+                    self.send(address, batch[0])
+                else:
+                    self.send(address, ReadReplyBatch(batch=tuple(batch)))
 
     def _process_deferred_reads(self, parked: list) -> None:
         """Answer what ``_defer_read`` parked at a slot that has now
@@ -449,6 +465,7 @@ class Replica(Actor, DurableRole):
         a Rejected, like the single-message path. Sync once per batch
         so the limit binds within it, then settle back to the
         deferred-read backlog."""
+        self.metrics_read_messages.inc()
         admission = self.admission
         if admission is None:
             commands = batch.commands
@@ -469,6 +486,7 @@ class Replica(Actor, DurableRole):
                                    batch: ReadRequestBatch) -> None:
         """Batched deferrable reads (Replica.scala:478-530
         handleDeferrableReads)."""
+        self.metrics_read_messages.inc()
         admission = self.admission
         if admission is None:
             # Admission-off fast path: no per-command filter call (the
@@ -567,6 +585,7 @@ class Replica(Actor, DurableRole):
                              request: ReadRequest) -> None:
         """Linearizable read at a slot; defer until executed
         (Replica.scala:455-530)."""
+        self.metrics_read_messages.inc()
         if not self._admit_read(request.command):
             return
         if request.slot >= self.executed_watermark:
@@ -584,6 +603,7 @@ class Replica(Actor, DurableRole):
 
     def _handle_eventual_read_request(self, src: Address,
                                       request: EventualReadRequest) -> None:
+        self.metrics_read_messages.inc()
         if not self._admit_read(request.command):
             return
         with self.trace_stage("read"):

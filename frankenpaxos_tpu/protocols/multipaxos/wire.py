@@ -42,8 +42,6 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     LeaderInfoReplyClient,
     LeaderInfoRequestBatcher,
     LeaderInfoRequestClient,
-    MaxSlotReply,
-    MaxSlotRequest,
     Nack,
     NOOP,
     Noop,
@@ -561,44 +559,13 @@ class ClientReplyArrayCodec(MessageCodec):
 
 
 # --- read-path codecs -------------------------------------------------------
-# The read hot path (the Evelyn read-scale mechanism): MaxSlotRequest ->
-# MaxSlotReply quorum, then a Read*Request to one replica answered with
-# a ReadReplyBatch. These carry every benchmarked read, so they get
-# fixed layouts like the write path; the read-BATCHER shapes
-# (ReadRequestBatch et al.) stay pickled until a deployment exercises
-# them (grandfathered under COD301 in .paxlint-baseline.json).
-
-
-class MaxSlotRequestCodec(MessageCodec):
-    message_type = MaxSlotRequest
-    tag = 119
-
-    def encode(self, out, message):
-        _put_cid(out, message.command_id)
-
-    def decode(self, buf, at):
-        cid, at = _take_cid(buf, at)
-        return MaxSlotRequest(command_id=cid), at
-
-
-_IIQ = struct.Struct("<iiq")
-
-
-class MaxSlotReplyCodec(MessageCodec):
-    message_type = MaxSlotReply
-    tag = 120
-
-    def encode(self, out, message):
-        _put_cid(out, message.command_id)
-        out += _IIQ.pack(message.group_index, message.acceptor_index,
-                         message.slot)
-
-    def decode(self, buf, at):
-        cid, at = _take_cid(buf, at)
-        group, acceptor, slot = _IIQ.unpack_from(buf, at)
-        return MaxSlotReply(command_id=cid, group_index=group,
-                            acceptor_index=acceptor,
-                            slot=slot), at + _IIQ.size
+# The read hot path (the Evelyn read-scale mechanism): a max-slot
+# quorum round, then a read request to one replica, answered with a
+# ReadReply or a ReadReplyBatch. These carry every benchmarked read, so
+# they get fixed layouts like the write path. A client's (and a read
+# batcher's) round is BatchMaxSlotRequest / BatchMaxSlotReply and its
+# request a ReadRequestBatch: those sit with the other batch shapes on
+# the extended tag page below. Tags 119 and 120 are free.
 
 
 class _SlotCommandCodec(MessageCodec):
@@ -684,12 +651,12 @@ class ClientReplyBatchCodec(_ReplyBatchCodec):
 
 
 # The read-BATCHER path and the leader-change client redirects, on the
-# extended tag page (133+; primary 1..127 is fully allocated). paxflow
-# FLOW405 surfaced the batch shapes: they are named in serve/lanes.py's
-# client lane, but the frame-layer classifier is TAG-based, so without
-# codecs their pickled frames rode the control lane and could never be
-# shed. The redirect shapes (NotLeader*/LeaderInfo*) are hot exactly
-# during failover storms, when every queued client op resends at once.
+# extended tag page (133+). paxflow FLOW405 surfaced the batch shapes:
+# they are named in serve/lanes.py's client lane, but the frame-layer
+# classifier is TAG-based, so without codecs their pickled frames rode
+# the control lane and could never be shed. The redirect shapes
+# (NotLeader*/LeaderInfo*) are hot exactly during failover storms, when
+# every queued client op resends at once.
 
 
 class _CommandsBatchCodec(MessageCodec):
@@ -1092,7 +1059,6 @@ for _codec in (Phase2bCodec(), Phase2aCodec(), ChosenCodec(),
                Phase2bRangeCodec(), Phase2bVotesCodec(),
                ClientRequestArrayCodec(), Phase2aRunCodec(),
                ChosenRunCodec(), ClientReplyArrayCodec(),
-               MaxSlotRequestCodec(), MaxSlotReplyCodec(),
                ReadRequestCodec(), SequentialReadRequestCodec(),
                EventualReadRequestCodec(), ReadReplyBatchCodec(),
                ClientReplyBatchCodec(), ReadRequestBatchCodec(),

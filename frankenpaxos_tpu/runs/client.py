@@ -3,16 +3,15 @@
 This is the ~130-line mirror the multipaxos and mencius clients carried
 as accepted duplication (flagged in PR 6), extracted verbatim-in-spirit:
 retry-budget bookkeeping, the Rejected backoff/reissue path, and the
-coalesce-writes staging machinery. A protocol's client subclasses both
-mixins and keeps only its own message construction and ``self.send``
-call sites (so the paxflow graphs still attribute every edge to the
-protocol module).
+end-of-pass staging machinery (coalesced writes, batched reads). A
+protocol's client subclasses both mixins and keeps only its own
+message construction and ``self.send`` call sites (so the paxflow
+graphs still attribute every edge to the protocol module).
 
 Pending-operation states are duck-typed: any object with ``id``,
 ``callback``, ``resend`` (a timer), ``attempts``, and -- for operations
 that can draw a Rejected -- ``backoff_pending``. States without
-``backoff_pending`` (e.g. the multipaxos MaxSlot quorum phase, which
-acceptors never reject) are skipped by the Rejected path via the
+``backoff_pending`` are skipped by the Rejected path via the
 ``getattr`` default.
 """
 
@@ -133,30 +132,52 @@ class RetryAdmissionMixin:
 
 
 class StagedWriteMixin:
-    """The coalesce-writes staging machinery.
+    """The end-of-pass staging machinery, for writes and for reads.
 
     Writes staged in one event-loop pass ship as ONE array message (each
     command still gets its own slot -- transport-level coalescing, not
-    slot sharing). On a real event-loop transport the flush is deferred
-    to the END of the pass via ``call_soon_threadsafe`` (write() may be
-    driven from off-loop threads); SimTransport has no loop -- there
-    ``on_drain`` / an explicit ``flush_writes()`` ships them.
+    slot sharing); reads staged in one pass ship as ONE batch, through
+    ``_flush_staged_reads``. On a real event-loop transport the flush is
+    deferred to the END of the pass via ``call_soon_threadsafe``
+    (write() may be driven from off-loop threads); SimTransport has no
+    loop -- there ``on_drain`` / an explicit ``flush_writes()`` ships
+    the writes, and ``on_drain`` the reads a delivery's handlers
+    issued. A read issued there from outside any delivery (a test, a
+    timer) is a pass of its own and ships at once: nobody staging reads
+    has to know about flushing.
 
     Subclass contract: call ``_init_staging()`` in ``__init__`` and
-    implement ``_flush_staged(staged)`` (destination pick + the array
-    ``send`` stay in the protocol module).
+    implement ``_flush_staged(staged)``; a subclass that stages reads
+    also implements ``_flush_staged_reads(staged)`` and sets
+    ``_in_pass`` at the top of its ``receive`` (``on_drain`` ends the
+    pass). Destination pick + the ``send`` stay in the protocol module.
     """
 
     def _init_staging(self) -> None:
         self._staged_writes: list = []
+        self._staged_reads: list = []
         self._flush_scheduled = False
+        self._in_pass = False
+
+    def _schedule_flush(self) -> bool:
+        """Schedule the end-of-pass flush once a pass; False where the
+        transport has no loop to schedule it on."""
+        loop = getattr(self.transport, "loop", None)
+        if loop is None:
+            return False
+        if not self._flush_scheduled:
+            self._flush_scheduled = True
+            loop.call_soon_threadsafe(self._deferred_flush)
+        return True
 
     def _stage_write(self, command) -> None:
         self._staged_writes.append(command)
-        loop = getattr(self.transport, "loop", None)
-        if loop is not None and not self._flush_scheduled:
-            self._flush_scheduled = True
-            loop.call_soon_threadsafe(self._deferred_flush)
+        self._schedule_flush()
+
+    def _stage_read(self, read) -> None:
+        self._staged_reads.append(read)
+        if not self._schedule_flush() and not self._in_pass:
+            self.flush_reads()
 
     def flush_writes(self) -> None:
         """Ship the staged writes as one array via ``_flush_staged``."""
@@ -165,12 +186,26 @@ class StagedWriteMixin:
         staged, self._staged_writes = self._staged_writes, []
         self._flush_staged(staged)
 
+    def flush_reads(self) -> None:
+        """Ship the staged reads as one batch via
+        ``_flush_staged_reads``."""
+        if not self._staged_reads:
+            return
+        staged, self._staged_reads = self._staged_reads, []
+        self._flush_staged_reads(staged)
+
     def _deferred_flush(self) -> None:
         self._flush_scheduled = False
         self.flush_writes()
+        self.flush_reads()
 
     def on_drain(self) -> None:
+        self._in_pass = False
         self.flush_writes()
+        self.flush_reads()
 
     def _flush_staged(self, staged: list) -> None:
+        raise NotImplementedError
+
+    def _flush_staged_reads(self, staged: list) -> None:
         raise NotImplementedError

@@ -32,7 +32,6 @@ CLIENT_LANE_TYPE_NAMES = frozenset({
     "ClientRequest",
     "ClientRequestArray",
     "ClientRequestBatch",
-    "MaxSlotRequest",
     "BatchMaxSlotRequest",
     "ReadRequest",
     "ReadRequestBatch",

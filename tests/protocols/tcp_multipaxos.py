@@ -19,7 +19,7 @@ from frankenpaxos_tpu.obs import RuntimeMetrics
 from frankenpaxos_tpu.runtime import FakeCollectors, FakeLogger, LogLevel
 from frankenpaxos_tpu.runtime.serializer import PickleSerializer
 from frankenpaxos_tpu.runtime.tcp_transport import TcpTransport
-from frankenpaxos_tpu.statemachine import SetRequest
+from frankenpaxos_tpu.statemachine import GetRequest, SetRequest
 
 STAGE_SERIES = "fpx_runtime_drain_stage_seconds"
 
@@ -135,6 +135,37 @@ class TcpMultiPaxos:
         for pseudonym in range(loops):
             loop.call_soon_threadsafe(issue, pseudonym, 0)
         assert done.wait(timeout), f"{left[0]} loops never finished"
+
+    def closed_read_loops(self, loops: int, reads_each: int,
+                          timeout: float = 120.0) -> dict:
+        """``loops`` pseudonyms, each reading key ``k`` linearizably
+        ``reads_each`` times, the next as soon as the last is answered:
+        the reads one pass issues travel as one batch. Returns
+        ``{pseudonym: [the values it read]}`` once all are answered."""
+        left = [loops]
+        done = threading.Event()
+        loop = self.transports["client"].loop
+        command = self._serializer.to_bytes(GetRequest(("k",)))
+        answers: dict = {p: [] for p in range(loops)}
+
+        def issue(pseudonym: int) -> None:
+            if len(answers[pseudonym]) == reads_each:
+                left[0] -= 1
+                if not left[0]:
+                    done.set()
+                return
+            self.client.read(pseudonym, command,
+                             lambda r: answered(pseudonym, r))
+
+        def answered(pseudonym: int, result: bytes) -> None:
+            answers[pseudonym].append(
+                self._serializer.from_bytes(result).key_values[0][1])
+            loop.call_soon(issue, pseudonym)
+
+        for pseudonym in range(loops):
+            loop.call_soon_threadsafe(issue, pseudonym)
+        assert done.wait(timeout), f"{left[0]} loops never finished"
+        return answers
 
     def on_loop(self, label: str, f, timeout: float = 30.0):
         """Run ``f()`` on a process's event loop, behind everything

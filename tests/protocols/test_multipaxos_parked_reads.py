@@ -11,6 +11,7 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     CommandBatch,
     CommandId,
     ReadReply,
+    ReadReplyBatch,
     ReadRequest,
     ReadRequestBatch,
 )
@@ -20,10 +21,14 @@ from tests.protocols.multipaxos_harness import make_multipaxos
 
 
 def replies_to(sim, client) -> list:
+    """The read replies on their way to ``client``, those inside a
+    ReadReplyBatch (reads answered together) unfolded."""
     decode = sim.clients[0].serializer.from_bytes
     found = [decode(m.data) for m in sim.transport.messages
              if m.dst == client]
-    return [m for m in found if isinstance(m, ReadReply)]
+    return [r for m in found
+            for r in (m.batch if isinstance(m, ReadReplyBatch) else (m,))
+            if isinstance(r, ReadReply)]
 
 
 def test_a_parked_read_is_answered_once_after_its_slot_and_released():
@@ -103,8 +108,8 @@ def test_a_read_at_an_executed_slot_is_answered_at_once_and_not_counted():
 
 def test_an_acceptor_counts_and_stages_the_read_paths_question():
     from frankenpaxos_tpu.protocols.multipaxos.messages import (
-        MaxSlotReply,
-        MaxSlotRequest,
+        BatchMaxSlotReply,
+        BatchMaxSlotRequest,
     )
 
     sim = make_multipaxos(f=1, coalesced=False)
@@ -116,15 +121,17 @@ def test_an_acceptor_counts_and_stages_the_read_paths_question():
     sim.transport.runtime_metrics = metrics
     acceptor = sim.acceptors[0]
     client = sim.clients[0].address
-    asked = acceptor.metrics_requests.labels("MaxSlotRequest").get()
-    acceptor.receive(client, MaxSlotRequest(CommandId(client, 3, 0)))
+    asked = acceptor.metrics_requests.labels("BatchMaxSlotRequest").get()
+    acceptor.receive(client, BatchMaxSlotRequest(read_batcher_index=-1,
+                                                 read_batcher_id=3))
     assert acceptor.metrics_requests.labels(
-        "MaxSlotRequest").get() == asked + 1
+        "BatchMaxSlotRequest").get() == asked + 1
     decode = sim.clients[0].serializer.from_bytes
     replies = [m for m in (decode(m.data) for m in sim.transport.messages
                            if m.dst == client)
-               if isinstance(m, MaxSlotReply)]
-    assert [r.slot for r in replies] == [acceptor.max_voted_slot]
+               if isinstance(m, BatchMaxSlotReply)]
+    assert [(r.read_batcher_index, r.read_batcher_id, r.slot)
+            for r in replies] == [(-1, 3, acceptor.max_voted_slot)]
     stages = {stage: found for (_, stage), found
               in metrics.read_stages().items()}
     assert stages["max-slot"][1] == 1 and "vote" not in stages

@@ -49,13 +49,12 @@ HOT_MESSAGES = [
 
 
 def test_read_path_codecs_round_trip():
-    """The read hot path (MaxSlot quorum -> Read*Request -> ReadReplyBatch)
-    and the proxied ClientReplyBatch ride fixed layouts, not pickle."""
+    """The read hot path (a read request -> ReadReplyBatch; its max-slot
+    round is among the batch shapes below) and the proxied
+    ClientReplyBatch ride fixed layouts, not pickle."""
     from frankenpaxos_tpu.protocols.multipaxos.messages import (
         ClientReplyBatch,
         EventualReadRequest,
-        MaxSlotReply,
-        MaxSlotRequest,
         ReadReply,
         ReadReplyBatch,
         ReadRequest,
@@ -66,11 +65,8 @@ def test_read_path_codecs_round_trip():
     sim_cid = CommandId("Client 1", 0, 7)
     command = Command(cid, b"get-k")
     for message in [
-        MaxSlotRequest(command_id=cid),
-        MaxSlotRequest(command_id=sim_cid),
-        MaxSlotReply(command_id=cid, group_index=1, acceptor_index=2,
-                     slot=1 << 40),
         ReadRequest(slot=5, command=command),
+        ReadRequest(slot=5, command=Command(sim_cid, b"get-k")),
         SequentialReadRequest(slot=-1, command=command),
         EventualReadRequest(command=command),
         ReadReplyBatch(batch=(ReadReply(cid, 9, b"r1"),
@@ -672,9 +668,6 @@ def all_codec_samples() -> dict:
         mp.Phase2aRun(start_slot=5, round=2, values=(batch, mp.NOOP)),
         mp.ChosenRun(start_slot=9, values=(mp.NOOP, batch)),
         mp.ClientReplyArray(entries=((0, 1, 5, b"r0"),)),
-        mp.MaxSlotRequest(command_id=cid),
-        mp.MaxSlotReply(command_id=cid, group_index=1,
-                        acceptor_index=2, slot=4),
         mp.ReadRequest(slot=5, command=command),
         mp.SequentialReadRequest(slot=-1, command=command),
         mp.EventualReadRequest(command=command),
