@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """The quickest proof that the served MultiPaxos path runs on the chip.
 
-``python3 chip_smoke.py`` runs four stages one after another, each in a
+``python3 chip_smoke.py`` runs three stages one after another, each in a
 child process, and prints as its last line exactly
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
 the device as JAX reports it; the line before it is a JSON summary of
@@ -11,15 +11,15 @@ any stage raises. This parent never touches JAX: one chip serves one
 process at a time, so each stage's chip owner is reaped before the next
 stage starts.
 
-  A  served_sync       BASELINE.json config 1 deployed through cli.py /
+  B  served            BASELINE.json config 1 deployed through cli.py /
                        launch_roles over real sockets: f=1, 3 acceptors,
                        2 leaders, 2 proxy leaders, 2 replicas, KV store,
-                       quorum_backend=tpu, coalesced run pipeline; 4096
-                       closed write loops from CPU-pinned client_main
-                       processes. The launcher and every role but the
-                       one hosting the proxy leaders stay off the chip.
-  B  served_pipelined  the same with tpu_pipelined=true, so the 2^20-slot
-                       vote board lives on the device behind requests.
+                       quorum_backend=tpu (the 2^20-slot vote board on
+                       the device behind requests), coalesced run
+                       pipeline; 4096 closed write loops from CPU-pinned
+                       client_main processes. The launcher and every
+                       role but the one hosting the proxy leaders stay
+                       off the chip.
   C  kernels           TpuQuorumChecker at window 2^20 (majority and the
                        2x3 grid) across a ring wrap plus a sparse tail,
                        bit-identical to a host oracle; then the donated
@@ -28,12 +28,14 @@ stage starts.
                        sharded pipeline over a 1x4 mesh, with every
                        device holding its quarter.
 
-Stages A and B pass only if every write is acknowledged and read back,
-the drains a kernel decided carry most of the votes (the tracker's own
-counters, read from /metrics), and the chosen (slot, round) set equals
-what a DictQuorumTracker reports for the same votes. To see those votes the
-role processes start through ``chip_smoke.py role``, which is cli.main
-with the tracker class wrapped by a recorder.
+(Stage A was the tracker's synchronous mode and went with it; the
+letters stay as the records use them.) Stage B passes only if every
+write is acknowledged and read back, the tracker's drains launched
+kernels (its own counters, read from /metrics), the board is resident at
+the window with no vote outside it, and the chosen (slot, round) set
+equals what a DictQuorumTracker reports for the same votes. To see those
+votes the role processes start through ``chip_smoke.py role``, which is
+cli.main with the tracker class wrapped by a recorder.
 
 The stage functions take their sizes as arguments; tests/test_chip_smoke.py
 runs them at toy size under JAX_PLATFORMS=cpu.
@@ -55,12 +57,8 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 #: the reference's client scale is up to 20 procs x 200 clients).
 WINDOW = 1 << 20
 FULL = {
-    "served_sync": dict(pipelined=False, client_procs=4,
-                        loops_per_proc=1024, duration_s=4.0,
-                        window=WINDOW),
-    "served_pipelined": dict(pipelined=True, client_procs=4,
-                             loops_per_proc=1024, duration_s=4.0,
-                             window=WINDOW),
+    "served": dict(client_procs=4, loops_per_proc=1024, duration_s=4.0,
+                   window=WINDOW),
     "kernels": dict(window=WINDOW, dense_width=4096, sparse_votes=16384,
                     pipeline_iters=256, pipeline_block=1 << 15),
     "mesh": dict(window=WINDOW, dense_width=4096, pipeline_iters=256,
@@ -70,16 +68,13 @@ STAGE_TIMEOUT_S = 420.0
 
 
 # --------------------------------------------------------------------------
-# Stages A and B: the served path
+# Stage B: the served path
 # --------------------------------------------------------------------------
 
 
-def stage_served(workdir: str, *, pipelined: bool, client_procs: int,
-                 loops_per_proc: int, duration_s: float, window: int,
-                 min_device_slots: int = 0) -> dict:
-    """Deploy config 1 with ``quorum_backend=tpu``, load it, check it.
-    ``min_device_slots`` (0 = the tracker's own choice) exists for the
-    CPU tests, whose toy load is narrower than CPU XLA's threshold."""
+def stage_served(workdir: str, *, client_procs: int, loops_per_proc: int,
+                 duration_s: float, window: int) -> dict:
+    """Deploy config 1 with ``quorum_backend=tpu``, load it, check it."""
     from frankenpaxos_tpu import device
 
     device.pin_cpu()  # the launcher stays off the chip
@@ -100,11 +95,9 @@ def stage_served(workdir: str, *, pipelined: bool, client_procs: int,
     audit_dir = bench.abspath("audit")
     os.makedirs(audit_dir, exist_ok=True)
     input = MultiPaxosInput(
-        f=1, num_replicas=2, quorum_backend="tpu", tpu_pipelined=pipelined,
-        coalesced=True, state_machine="KeyValueStore", prometheus=True)
+        f=1, num_replicas=2, quorum_backend="tpu", coalesced=True,
+        state_machine="KeyValueStore", prometheus=True)
     launch_overrides = {"tpu_window": str(window)}
-    if min_device_slots:
-        launch_overrides["tpu_min_device_slots"] = str(min_device_slots)
     cache_before = _cache_entries()
     t0 = time.time()
     config_path, config = launch_with_retry(
@@ -167,18 +160,16 @@ def stage_served(workdir: str, *, pipelined: bool, client_procs: int,
         oracle_equal &= (len(got) == len(set(got))
                          and set(got) == set(oracle.drain()))
 
-    def metric(name: str, path: str) -> int:
+    def metric(series: str) -> int:
         return int(scrapes[bench.chip_owner].get(
-            f'multipaxos_proxy_leader_tpu_{name}_total{{path="{path}"}}',
-            0))
+            f"multipaxos_proxy_leader_tpu_{series}", 0))
 
     def tracked(field: str) -> int:
         return sum(t[field] for t in trackers)
 
-    counts = {f"{path}_{name}": metric(name, path)
-              for name in ("drains", "votes")
-              for path in ("device", "host")}
-    counts["spilled_votes"] = metric("votes", "spilled")
+    counts = {"device_drains": metric('drains_total{path="device"}'),
+              "device_votes": metric('votes_total{path="device"}'),
+              "device_launches": metric("launches_total")}
     acked = sum(v["writes_acked"] for v in verdicts)
     checks = {
         "one_chip_owner": (bench.chip_owner is not None and pinned
@@ -195,19 +186,19 @@ def stage_served(workdir: str, *, pipelined: bool, client_procs: int,
         # The tracker's counts and what /metrics served agree.
         "metrics_match_tracker": all(
             counts[key] == tracked(key) for key in counts),
-        # The drains a kernel decided exist and carry most votes.
-        "device_drains_carry_most_votes": (
-            counts["device_drains"] > 0
-            and counts["device_votes"] > counts["host_votes"]),
+        # Every drain launched a kernel, and every fed vote went in one.
+        "every_drain_launched": (
+            counts["device_launches"] >= counts["device_drains"] > 0
+            and counts["device_votes"] == sum(
+                end - start for t in trackers
+                for start, end, *_ in t["votes"])),
         "chosen_equals_oracle": oracle_equal and chosen > 0,
+        "board_resident_at_window": all(
+            t["board_shape"] == [3, window] for t in trackers),
+        "no_window_violations": tracked("window_violations") == 0,
     }
-    if pipelined:
-        checks["board_resident_at_window"] = all(
-            t["board_shape"] == [3, window] for t in trackers)
-        checks["no_window_violations"] = (
-            tracked("window_violations") == 0)
     return {
-        "stage": "served_pipelined" if pipelined else "served_sync",
+        "stage": "served",
         "device": owner["device"],
         "chip_owner": bench.chip_owner,
         "processes": len(bench.role_commands) + client_procs,
@@ -315,10 +306,8 @@ def audited_role(audit_dir: str, cli_argv: list) -> None:
                 "board_shape": list(t.checker.board.votes.shape),
                 "window_violations": t.checker.window_violations,
                 "device_drains": t.device_drains,
-                "host_drains": t.host_drains,
                 "device_votes": t.device_votes,
-                "host_votes": t.host_votes,
-                "spilled_votes": t.spilled_votes,
+                "device_launches": t.device_launches,
                 "votes": t.fed,
                 "chosen": t.reported,
             } for t in trackers],
@@ -532,8 +521,7 @@ def stage_mesh(*, window: int, dense_width: int, pipeline_iters: int,
     # The served tracker with its board over the mesh, against the
     # oracle, through 1.25 rings (as __graft_entry__ does at toy size).
     t0 = time.time()
-    tracker = TpuQuorumTracker(config, window=window, pipelined=True,
-                               mesh=mesh)
+    tracker = TpuQuorumTracker(config, window=window, mesh=mesh)
     prewarm_s = time.time() - t0
     oracle = DictQuorumTracker(config)
     equal, chosen = True, 0
@@ -541,8 +529,7 @@ def stage_mesh(*, window: int, dense_width: int, pipeline_iters: int,
         for acceptor in (base % 3, (base + 1) % 3):
             tracker.record_range(base, base + dense_width, 0, 0, acceptor)
             oracle.record_range(base, base + dense_width, 0, 0, acceptor)
-        if tracker.drain():
-            raise RuntimeError("a pipelined drain returned results")
+        tracker.drain()
         got = []
         while (dispatch := tracker.take_dispatch()) is not None:
             got.extend(tracker.collect(dispatch))
@@ -568,7 +555,7 @@ def stage_mesh(*, window: int, dense_width: int, pipeline_iters: int,
         "tracker_prewarm_s": round(prewarm_s, 2),
         "tracker_chosen": chosen,
         "device_drains": tracker.device_drains,
-        "host_drains": tracker.host_drains,
+        "device_launches": tracker.device_launches,
         "runner_first_call_s": round(runner_s, 2),
         "runner_committed": committed,
         "checks": {
@@ -651,7 +638,7 @@ def main() -> None:
     os.makedirs(out_dir, exist_ok=True)
     started = time.time()
     stages = {}
-    for name in ("served_sync", "served_pipelined", "kernels", "mesh"):
+    for name in ("served", "kernels", "mesh"):
         out_path = os.path.join(out_dir, f"{name}.json")
         t0 = time.time()
         # Its own session, so that whatever a stage leaves behind can
@@ -682,9 +669,8 @@ def main() -> None:
                           if k in ("stage_s", "checks", "skipped",
                                    "set_up_s", "compile_cache",
                                    "writes_acked", "keys_read_back",
-                                   "device_drains", "host_drains",
-                                   "device_votes", "host_votes",
-                                   "spilled_votes", "chosen")}
+                                   "device_drains", "device_votes",
+                                   "device_launches", "chosen")}
                    for name, stage in stages.items()},
         "claim": None,
     }))

@@ -10,7 +10,8 @@ quorum backends:
     reference's semantics; CPU-pinned role processes).
   * ``tpu``   -- the proxy leader's Phase2b votes collected on the
     accelerator via TpuQuorumTracker (dense record_block runs + sparse
-    scatter tail), one device call per event-loop drain.
+    scatter tail on the vote board), one device call per event-loop
+    drain as a rule, collected off the loop.
 
 Also runs SimTransport comparisons (no TCP, same actor code) isolating
 the per-drain tracker cost from network effects.
@@ -79,13 +80,16 @@ def sim_transport_cmds_per_sec(quorum_backend: str,
     warm_block[0, 0] = 1
     warm_checker.record_block(0, warm_block)
 
-    from tests.protocols.multipaxos_harness import make_multipaxos
+    from tests.protocols.multipaxos_harness import (
+        deliver_and_flush,
+        make_multipaxos,
+    )
 
     sim = make_multipaxos(f=1, quorum_backend=quorum_backend)
     results = []
-    # Warm up (compiles the device kernels on the tpu backend).
+    # Warm up (the tracker's kernels compile in its constructor).
     sim.clients[0].write(0, b"warmup", results.append)
-    sim.transport.deliver_all_coalesced()
+    deliver_and_flush(sim, coalesced=True)
     assert len(results) == 1
     batches = max(1, num_commands // inflight)
     t0 = time.perf_counter()
@@ -102,13 +106,16 @@ def _drive_waves(sim, inflight: int, waves: int, tag: bytes,
     granularity). Shared by every sim-pipeline benchmark here so the
     driving protocol cannot drift between them. ``flush_writes`` ships
     a coalescing client's staged array (no-op otherwise), standing in
-    for the real event loop's end-of-pass flush."""
+    for the real event loop's end-of-pass flush; the proxy leaders'
+    flush timers stand in for the collector thread."""
+    from tests.protocols.multipaxos_harness import deliver_and_flush
+
     for b in range(waves):
         for p in range(inflight):
             sim.clients[0].write(p, b"%s%d.%d" % (tag, b, p),
                                  results.append)
         sim.clients[0].flush_writes()
-        sim.transport.deliver_all_coalesced()
+        deliver_and_flush(sim, coalesced=True)
 
 
 def sim_ab_pipeline(inflights, reps: int = 6, waves: int = 0,
@@ -135,7 +142,10 @@ def sim_ab_pipeline(inflights, reps: int = 6, waves: int = 0,
     import gc
     import statistics
 
-    from tests.protocols.multipaxos_harness import make_multipaxos
+    from tests.protocols.multipaxos_harness import (
+        deliver_and_flush,
+        make_multipaxos,
+    )
 
     ARMS = {
         "dict": dict(quorum_backend="dict", coalesced=False),
@@ -149,7 +159,7 @@ def sim_ab_pipeline(inflights, reps: int = 6, waves: int = 0,
         results = []
         sim.clients[0].write(0, b"warmup", results.append)
         sim.clients[0].flush_writes()
-        sim.transport.deliver_all_coalesced()
+        deliver_and_flush(sim, coalesced=True)
         _drive_waves(sim, inflight, warm, b"w", results)
         t0 = time.perf_counter()
         _drive_waves(sim, inflight, w, b"x", results)
@@ -233,7 +243,8 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
 
     This isolates the exact component the backends differ in: per-vote
     dict/set updates vs batched recording + one device call per
-    drain."""
+    drain, each collected at once (no overlap: the replay has no next
+    drain's decode to hide the fetch behind)."""
     import sys
 
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -244,15 +255,14 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
         DictQuorumTracker,
         TpuQuorumTracker,
     )
-    from tests.protocols.multipaxos_harness import make_multipaxos
+    from tests.protocols.multipaxos_harness import (
+        drain_and_collect,
+        make_multipaxos,
+    )
 
     config = make_multipaxos(f=1).config
     if quorum_backend == "tpu":
-        # min_device_slots=1: the replay isolates the DEVICE tracker
-        # component (the auto threshold would route narrow replays to
-        # the host tally, measuring the oracle twice).
-        tracker = TpuQuorumTracker(config, window=1 << 14,
-                                   min_device_slots=1)
+        tracker = TpuQuorumTracker(config, window=1 << 14)
     else:
         tracker = DictQuorumTracker(config)
     acceptors = 2 * config.f + 1
@@ -262,7 +272,7 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
     for slot in range(base, base + drain_width):
         for acc in range(acceptors):
             tracker.record(slot, 0, 0, acc)
-    tracker.drain()
+    drain_and_collect(tracker)
     base += drain_width
     chosen = 0
     t0 = time.perf_counter()
@@ -270,7 +280,7 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
         for _ in range(drains):
             for acc in range(acceptors):
                 tracker.record_range(base, base + drain_width, 0, 0, acc)
-            chosen += len(tracker.drain())
+            chosen += len(drain_and_collect(tracker))
             base += drain_width
     else:
         for _ in range(drains):
@@ -278,7 +288,7 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
             for slot in range(base, base + drain_width):
                 for acc in range(acceptors):
                     record(slot, 0, 0, acc)
-            chosen += len(tracker.drain())
+            chosen += len(drain_and_collect(tracker))
             base += drain_width
     elapsed = time.perf_counter() - t0
     assert chosen == drains * drain_width, (chosen, drains, drain_width)
@@ -286,11 +296,11 @@ def tracker_votes_per_sec(quorum_backend: str, drain_width: int,
 
 
 def _overlap_metrics(role_metrics: dict) -> dict:
-    """Aggregate the proxy leaders' pipelined-dispatch instrumentation
-    (scraped /metrics) into the overlap summary the deployed
-    tpu-pipelined point carries: how deep the in-flight dispatch queue
-    runs (0 = every fetch is serialized behind its drain, i.e.
-    pipelining is NOT engaging) and what each device collect costs."""
+    """Aggregate the proxy leaders' dispatch instrumentation (scraped
+    /metrics) into the overlap summary the deployed tpu point carries:
+    how deep the in-flight dispatch queue runs (0 = every fetch is
+    serialized behind its drain, i.e. nothing overlaps) and what each
+    device collect costs."""
     sums = {"dispatches": 0.0, "inflight_sum": 0.0, "inflight_count": 0.0,
             "collect_sum_s": 0.0, "collect_count": 0.0}
     p = "multipaxos_proxy_leader_tpu_"
@@ -358,17 +368,15 @@ def main(argv=None) -> dict:
 
     # Deployed arms. The dict arm is the reference design; dict+run is
     # the drain-granular pipeline on the host tracker; tpu+run is the
-    # sync device tracker (narrow drains go to the host tally);
-    # tpu-pipelined is the board-always mode, instrumented
-    # (prometheus) to measure dispatch overlap. The tpu arms' chip
+    # same pipeline on the device tracker's vote board, instrumented
+    # (prometheus) to measure dispatch overlap. The tpu arm's chip
     # owner refuses to start without a TPU (cli.py), and an arm that
     # fails fails the suite.
     arms = [
         ("dict", dict()),
         ("dict+run", dict(coalesced=True)),
-        ("tpu+run", dict(quorum_backend="tpu", coalesced=True)),
-        ("tpu-pipelined", dict(quorum_backend="tpu", tpu_pipelined=True,
-                               prometheus=True)),
+        ("tpu+run", dict(quorum_backend="tpu", coalesced=True,
+                         prometheus=True)),
     ]
     points = []
     for arm, kwargs in arms:
@@ -383,7 +391,6 @@ def main(argv=None) -> dict:
             point = {
                 "arm": arm,
                 "quorum_backend": backend,
-                "tpu_pipelined": bool(kwargs.get("tpu_pipelined")),
                 "coalesced": bool(kwargs.get("coalesced")),
                 "client_procs": procs,
                 "loops_per_proc": loops,
@@ -393,7 +400,7 @@ def main(argv=None) -> dict:
                 "latency_p99_ms": stats.get("latency.p99_ms"),
                 "num_requests": stats["num_requests"],
             }
-            if kwargs.get("tpu_pipelined"):
+            if backend == "tpu":
                 point["overlap_metrics"] = _overlap_metrics(
                     stats.get("role_metrics") or {})
             points.append(point)
@@ -446,8 +453,7 @@ def main(argv=None) -> dict:
                       "crossover_inflight": crossover}))
 
     # The serial workload (one command per drain), the device path's
-    # worst case: the tracker's threshold routes every such drain to
-    # the host tally.
+    # worst case: a launch and a collect for a couple of votes.
     sim_rows = {
         backend: round(run_in_child(
             f"sim_transport_cmds_per_sec({backend!r}, "
@@ -530,11 +536,11 @@ def main(argv=None) -> dict:
                  "the device-backed tracker; run_over_dict_ratio is the "
                  "dict-tracker ablation of the same run pipeline, "
                  "isolating message-structure wins from vote-tracking "
-                 "wins. The tpu tracker routes adaptively: narrow "
-                 "drains to a host tally, wide drains to ONE stateless "
-                 "quorum check per drain. tracker_votes_per_sec "
-                 "isolates the ProxyLeader vote-collection component "
-                 "with the device path pinned on."),
+                 "wins. The tpu tracker sends every vote to the vote "
+                 "board on the device, one launch a drain as a rule. "
+                 "tracker_votes_per_sec isolates the ProxyLeader "
+                 "vote-collection component, each drain collected at "
+                 "once."),
     }
     if args.out:
         with open(args.out, "w") as f:

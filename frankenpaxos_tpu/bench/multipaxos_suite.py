@@ -40,10 +40,6 @@ class MultiPaxosInput:
     num_clients: int = 2
     duration_s: float = 2.0
     quorum_backend: str = "dict"
-    # Pipelined device drains for the tpu backend (overlap the result
-    # fetch with the next drain's decode; see
-    # ProxyLeaderOptions.tpu_pipelined).
-    tpu_pipelined: bool = False
     # The drain-granular run pipeline (ClientRequestArray -> Phase2aRun
     # -> Phase2bRange -> ChosenRun -> ClientReplyArray): clients
     # coalesce each event-loop pass's writes into one array and every
@@ -148,8 +144,6 @@ def _launch_and_warm(bench: BenchmarkDirectory,
     config_path = bench.write_json("config.json", config_raw)
     config = get_protocol("multipaxos").load_config(config_raw)
     overrides = {"quorum_backend": input.quorum_backend}
-    if input.tpu_pipelined:
-        overrides["tpu_pipelined"] = "true"
     if input.coalesced:
         overrides["coalesce_writes"] = "true"
     if input.num_batchers:

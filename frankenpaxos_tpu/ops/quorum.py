@@ -400,19 +400,6 @@ def _check_batch(present: jax.Array, masks_t: tuple, meta: tuple) -> jax.Array:
     return _predicate_hit(present.T, masks_t, meta)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-@_named("fpx_quorum_check_block")
-def _check_block(block: jax.Array, masks_t: tuple, meta: tuple) -> jax.Array:
-    """``[N, B]`` slot-major vote block -> ``[B]`` bool (stateless).
-
-    The drain-local quorum predicate: one masks @ block matmul and a
-    compare, touching NO board state -- no dynamic slices, no ring
-    bookkeeping, nothing proportional to the window. Measured ~3x
-    cheaper per call than the stateful ``_record_block`` on host XLA
-    and flat in B up to MXU-friendly widths."""
-    return _predicate_hit(block, masks_t, meta)
-
-
 @jax.jit
 def _check_batch_multi(
     present: jax.Array,       # [B, N]
@@ -597,42 +584,6 @@ class TpuQuorumChecker:
             _stage_block(block, padded, start, start_slot, vote_round),
             self._masks_t, self._meta)
         return newly
-
-    def check_block_async(self, block: np.ndarray) -> jax.Array:
-        """Stateless drain-local quorum over a ``[n, B]`` vote block:
-        returns the DEVICE ``[B]`` hit mask (padded to the kernel
-        bucket; slice on the host after fetching).
-
-        A slot whose full write quorum arrives within one event-loop
-        drain (the steady state: the ProxyLeader fans each Phase2a to
-        its whole quorum in one pass and the acks coalesce back into
-        one drain) is decided here without touching the vote board at
-        all -- no ring constraints, any ``start`` slot, cost flat in B.
-        Callers route the non-hit residue through :meth:`record_block`
-        for cross-drain accumulation (SURVEY.md section 7's spill
-        path, lifted on device)."""
-        n, b = block.shape
-        if n != self.num_nodes:
-            raise ValueError(f"block has {n} acceptor rows, spec has "
-                             f"{self.num_nodes}")
-        padded = 64
-        while padded < b:
-            padded *= 2
-        if padded != b:
-            block = np.concatenate(
-                [np.asarray(block, dtype=np.uint8),
-                 np.zeros((n, padded - b), dtype=np.uint8)], axis=1)
-        return _check_block(np.asarray(block, dtype=np.uint8),
-                            self._masks_t, self._meta)
-
-    def check_block(self, block: np.ndarray) -> np.ndarray:
-        """Synchronous :meth:`check_block_async`, sliced to the input
-        width."""
-        b = block.shape[1]
-        # paxlint: disable=TPU203 -- this IS the explicit sync wrapper
-        # (prewarm/tests); drain paths use the _async twin and fetch
-        # off-loop.
-        return np.asarray(self.check_block_async(block))[:b]
 
     def record_block(self, start_slot: int, block: np.ndarray,
                      vote_round: int = 0) -> np.ndarray:

@@ -48,6 +48,11 @@ from frankenpaxos_tpu.runtime import Actor, Collectors, FakeCollectors, Logger
 from frankenpaxos_tpu.runtime.transport import Address, Transport
 
 
+# SimTransport only: how long after a drain the flush timer collects
+# what the tracker dispatched, if no further message came first.
+TPU_FLUSH_PERIOD_S = 0.005
+
+
 @dataclasses.dataclass(frozen=True)
 class ProxyLeaderOptions:
     flush_phase2as_every_n: int = 1
@@ -55,15 +60,6 @@ class ProxyLeaderOptions:
     # "dict" (host oracle) or "tpu" (batched vote board).
     quorum_backend: str = "dict"
     tpu_window: int = 1 << 20
-    # Sync-mode host/device routing threshold (drain width in slots);
-    # 0 = auto-calibrate to the device platform (see TpuQuorumTracker).
-    tpu_min_device_slots: int = 0
-    # Pipelined device drains: dispatch this drain's votes async and
-    # emit the PREVIOUS drain's results, overlapping the result fetch
-    # with the next drain's decode (one drain of extra choose latency).
-    # A flush timer collects the final dispatch during quiescence.
-    tpu_pipelined: bool = False
-    tpu_flush_period_s: float = 0.005
     # Reconfiguration (reconfig/): backend for the epoch-segmented
     # tracker once epoch counting engages ("" follows quorum_backend).
     epoch_backend: str = ""
@@ -88,7 +84,7 @@ class ProxyLeader(Actor):
             "multipaxos_proxy_leader_requests_latency_seconds", labels=("type",))
         self.metrics_requests = collectors.counter(
             "multipaxos_proxy_leader_requests_total", labels=("type",))
-        # Pipelined-mode overlap instrumentation: how many dispatches
+        # Overlap instrumentation of the tpu tracker: how many dispatches
         # are in flight when a new one is queued (depth 0 = no overlap,
         # every fetch is serialized behind its drain) and how long each
         # device collect blocks the worker thread.
@@ -98,19 +94,16 @@ class ProxyLeader(Actor):
             "multipaxos_proxy_leader_tpu_inflight_at_dispatch")
         self.metrics_tpu_collect = collectors.summary(
             "multipaxos_proxy_leader_tpu_collect_seconds")
-        # Where the tpu tracker's work went, in both modes (the
-        # tracker's own counts, published after every drain): a "tpu"
-        # run whose drains all went to the host tally left the device
-        # idle, and only these say so.
+        # The tpu tracker's own counts, published after every drain.
+        # The one child of ``path`` is what benchmark/harness/
+        # readings.py asks for by name.
         tpu_drains = collectors.counter(
             "multipaxos_proxy_leader_tpu_drains_total", labels=("path",))
         tpu_votes = collectors.counter(
             "multipaxos_proxy_leader_tpu_votes_total", labels=("path",))
         # In the order _publish_tpu_counts reads the tracker's counts.
         self.metrics_tpu_work = (
-            tpu_drains.labels("device"), tpu_drains.labels("host"),
-            tpu_votes.labels("device"), tpu_votes.labels("host"),
-            tpu_votes.labels("spilled"),
+            tpu_drains.labels("device"), tpu_votes.labels("device"),
             collectors.counter(
                 "multipaxos_proxy_leader_tpu_launches_total"),
             collectors.counter(
@@ -145,9 +138,7 @@ class ProxyLeader(Actor):
         self._unflushed_phase2as = 0
         if options.quorum_backend == "tpu":
             self.tracker: QuorumTracker = TpuQuorumTracker(
-                config, window=options.tpu_window,
-                pipelined=options.tpu_pipelined,
-                min_device_slots=options.tpu_min_device_slots)
+                config, window=options.tpu_window)
         else:
             self.tracker = DictQuorumTracker(config)
         # Reconfiguration (reconfig/): the epoch store resolves
@@ -168,7 +159,7 @@ class ProxyLeader(Actor):
             self._ensure_epoch_tracker()
         self._flush_timer = None
         self._collector = None
-        if options.quorum_backend == "tpu" and options.tpu_pipelined:
+        if options.quorum_backend == "tpu":
             # Branch on the transport's CAPABILITY (threaded event loop),
             # not on whether its loop happens to exist yet: a TcpTransport
             # actor constructed before start() must still get the
@@ -218,7 +209,7 @@ class ProxyLeader(Actor):
                         self._flush_timer.start()
 
                 self._flush_timer = self.timer(
-                    "tpuDrainFlush", options.tpu_flush_period_s,
+                    "tpuDrainFlush", TPU_FLUSH_PERIOD_S,
                     flush_pending)
 
     def receive(self, src: Address, message) -> None:
@@ -404,7 +395,7 @@ class ProxyLeader(Actor):
         """Engage epoch-segmented vote counting. Pre-switch state in a
         dict tracker migrates (its (group, index) votes map to
         addresses through the epoch-0 config); the TPU tracker's
-        board/spill state cannot be extracted -- quorums straddling
+        board state cannot be extracted -- quorums straddling
         that switch complete through protocol-level resends (warned)."""
         if self._epoch_tracker is not None or self.epochs is None:
             return
@@ -581,11 +572,10 @@ class ProxyLeader(Actor):
                 self._flush_timer.start()
 
     def _publish_tpu_counts(self) -> None:
-        """The tracker's device/host work counts into /metrics, as
-        increments: colocated proxy leaders share one series."""
+        """The tracker's work counts into /metrics, as increments:
+        colocated proxy leaders share one series."""
         t = self.tracker
-        counts = (t.device_drains, t.host_drains, t.device_votes,
-                  t.host_votes, t.spilled_votes, t.device_launches,
+        counts = (t.device_drains, t.device_votes, t.device_launches,
                   t.checker.window_violations)
         if counts == self._tpu_published:
             return

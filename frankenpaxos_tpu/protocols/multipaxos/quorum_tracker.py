@@ -6,8 +6,9 @@ implementations:
 
   * ``DictQuorumTracker`` -- the reference's semantics verbatim: a dict
     keyed (slot, round) accumulating (group, acceptor) votes. The oracle.
-  * ``TpuQuorumTracker`` -- votes buffered per event-loop drain, then one
-    ``TpuQuorumChecker.record_and_check`` scatter + matmul per drain.
+  * ``TpuQuorumTracker`` -- votes buffered per event-loop drain, then
+    dispatched to ``TpuQuorumChecker``'s vote board on the device (one
+    ``record_block`` a drain as a rule) and collected off the loop.
     Acceptor coordinates flatten to columns ``group * group_size + index``.
     In non-flexible mode only a slot's own group is ever messaged, so a
     universe-wide count >= f+1 threshold is exactly the per-group f+1
@@ -98,58 +99,39 @@ class DictQuorumTracker(QuorumTracker):
 
 
 class TpuQuorumTracker(QuorumTracker):
-    """Two operating modes, chosen by ``pipelined``:
+    """Every vote goes to the stateful vote board on the device.
 
-    **Synchronous (default).** Each drain whose dominant-round span is
-    at least ``min_device_slots`` wide is decided by ONE stateless
-    predicate matmul over the drain's ``[n, B]`` vote block
-    (``TpuQuorumChecker.check_block``) -- no board state, no ring
-    bookkeeping, cost flat in B. Votes below quorum after that check
-    (quorums straddling drains) spill into a host tally (a
-    ``DictQuorumTracker``, the oracle itself) -- SURVEY.md section 7's
-    "overflow -> host-side spill path". Drains NARROWER than the
-    threshold skip the device entirely and go straight to the host
-    tally: a fixed device round-trip cannot beat per-vote Python on a
-    handful of slots, exactly the small-batch host fallback every
-    accelerator framework keeps. The result: at trickle widths the
-    tracker matches the dict oracle, and past the threshold the
-    per-drain cost stays flat while the oracle's grows per vote.
+    A drain DISPATCHES its votes asynchronously (``record_block`` for
+    dense runs, the scatter for stragglers), returns [] and enqueues an
+    in-flight record; the caller collects completed dispatches via
+    :meth:`take_dispatch` + :meth:`collect` -- from a worker thread
+    (ProxyLeader posts results back onto the event loop) or a flush
+    timer. This overlaps the device->host fetch of one drain's result
+    with the decode of the next drain's messages, at the cost of one
+    dispatch of added choose latency; the board must see every vote
+    because results are not available within the drain.
 
-    **Pipelined.** Every dense run goes through the stateful on-device
-    vote board (``record_block``): the drain DISPATCHES asynchronously
-    (returning []) and enqueues an in-flight record; the caller
-    collects completed dispatches via :meth:`take_dispatch` +
-    :meth:`collect` -- from a worker thread (ProxyLeader posts results
-    back onto the event loop) or a flush timer. This overlaps the
-    device->host fetch of one drain's result with the decode of the
-    next drain's messages, at the cost of one dispatch of added choose
-    latency; the board must see every vote because results are not
-    available within the drain.
+    The tracker counts its work: ``device_drains`` and the
+    ``device_votes`` they carried, and ``device_launches``, the jitted
+    calls those drains made, dense or sparse: one a drain is the common
+    case, and more says what splits drains (a ring straddle, several
+    rounds, a sparse tail)."""
 
-    Either way a drain may be decided without the device doing any
-    work, so the tracker COUNTS where its work went: ``device_drains``
-    and the ``device_votes`` they carried went through a kernel,
-    ``host_drains`` and their ``host_votes`` straight to the host
-    tally, and ``spilled_votes`` are the votes of device drains that
-    the stateless check left below quorum and handed to the tally.
-    ``device_launches`` counts the jitted calls those device drains
-    made, dense or sparse: one a drain is the common case, and more
-    says what splits drains (a ring straddle, several rounds, a sparse
-    tail)."""
+    # Read by benchmark/harness/role_entry.py::TRACKER_COUNTERS, which
+    # still names the host tally that is gone: they can only read 0
+    # (ROADMAP.md M6 drops them there, then here).
+    host_drains = 0
+    host_votes = 0
+    spilled_votes = 0
 
     def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
-                 pipelined: bool = False, mesh=None,
-                 min_device_slots: int = 0):
+                 mesh=None):
         import collections
 
         self.config = config
-        self.pipelined = pipelined
         self.device_drains = 0
         self.device_launches = 0
         self.device_votes = 0
-        self.host_drains = 0
-        self.host_votes = 0
-        self.spilled_votes = 0
         # In-flight dispatches: (slots, rounds, device per-vote masks).
         # append/popleft are GIL-atomic, so a collector thread may pop
         # while the event loop appends.
@@ -170,12 +152,7 @@ class TpuQuorumTracker(QuorumTracker):
         # (it costs seconds of startup per process).
         from frankenpaxos_tpu.ops.quorum import TpuQuorumChecker
 
-        # Sync mode never records on the vote board (stateless checks +
-        # host spill), so don't allocate a full `window`-wide board
-        # there -- just enough columns for the largest dense bucket.
-        checker_window = window if pipelined else min(window, 4096)
-        self.checker = TpuQuorumChecker(spec, window=checker_window,
-                                        mesh=mesh)
+        self.checker = TpuQuorumChecker(spec, window=window, mesh=mesh)
         self._slots: list[int] = []
         self._cols: list[int] = []
         self._rounds: list[int] = []
@@ -186,25 +163,28 @@ class TpuQuorumTracker(QuorumTracker):
         # O(1) Python per message, arrays straight off the native
         # codec's unpack.
         self._array_votes: list = []
-        # Exactly-once reporting across drains, vectorized. The board's
-        # `chosen` bitmap provides this for board-recorded votes, but
-        # the stateless check_block path never touches the board, so a
-        # duplicate full-quorum drain (resent acks) would re-report. A
-        # host-side dedup ring keyed slot % window (owner slot + round
-        # per column, numpy fancy-indexed in collect()) restores the
-        # dict oracle's contract with O(batch) numpy instead of
-        # per-slot set ops. Like the vote board itself it forgets a
-        # slot once the ring wraps past it -- covered by the same
-        # "window > max slots in flight" invariant.
+        # Exactly-once reporting, vectorized. Each device call answers
+        # with what IT newly chose, and one drain can be several calls
+        # (an older round's scatter before the dense block, a ring
+        # straddle, a sparse tail) whose votes may name one slot twice.
+        # collect() passes every part's hits through this host-side
+        # ring keyed slot % window (owner slot + round per column,
+        # numpy fancy-indexed), so a (slot, round) is reported once
+        # across parts, drains and re-acks, first round first as the
+        # dict oracle does, with O(batch) numpy instead of per-slot set
+        # ops. The board's `chosen` plane guards the same on the
+        # device; whether it alone would do is unproven (ROADMAP.md
+        # D3). Like the vote board itself the ring forgets a slot once
+        # it wraps past it -- covered by the same "window > max slots
+        # in flight" invariant.
         self._dedup_slot = np.full(window, -1, dtype=np.int64)
         self._dedup_round = np.full(window, np.iinfo(np.int64).min,
                                     dtype=np.int64)
-        self._frontier = -1
-        self._host_gc_cap = max(1 << 16, 2 * window)
         # Kernel width buckets. Drains are chunked to these so ONLY the
         # prewarmed widths ever compile -- an unexpected width compiling
-        # mid-run stalls the event loop for seconds. Dense buckets go wide (a contiguous 4k-slot run is one
-        # slice+matmul call); the sparse scatter tail stays narrow.
+        # mid-run stalls the event loop for seconds. Dense buckets go
+        # wide (a contiguous 4k-slot run is one slice+matmul call); the
+        # sparse scatter tail stays narrow.
         self.max_chunk = 256
         self.dense_buckets = tuple(
             b for b in (64, 256, 1024, 4096) if b <= window)
@@ -216,47 +196,20 @@ class TpuQuorumTracker(QuorumTracker):
         # A dominant-round cluster goes dense when it's at least this
         # filled; emptier clusters cost fewer device calls via scatter.
         self.min_fill = 0.25
-        if min_device_slots <= 0:
-            # The host/device routing threshold follows the platform.
-            # On CPU XLA (the tests' control) the call itself is ~150us
-            # but its AMBIENT cost on a small host is the real price
-            # (kernel execution and thread-pool churn timeshare with
-            # the single-threaded actor pipeline; measured ~2-4ms of
-            # surrounding-pipeline slowdown per call on a 1-CPU box),
-            # so the device must only engage when a drain carries
-            # enough votes to beat that: ~1k slots. The TPU value is an
-            # assumption that no chip run has measured yet; moving it
-            # needs a trace of the served path.
-            import jax
-
-            platform = jax.devices()[0].platform
-            min_device_slots = 96 if platform == "tpu" else 1024
-        self.min_device_slots = min_device_slots
-        # Host spill tally for the synchronous mode (narrow drains +
-        # below-quorum residue of stateless checks): the dict oracle
-        # itself, so cross-drain accumulation has one authority with
-        # proven semantics.
-        self._host = DictQuorumTracker(config)
         # Pre-compile every bucket at construction -- before client
         # traffic -- so the first real drains don't stall on XLA
-        # compiles. The board paths (record_block / record_and_check)
-        # only run in pipelined mode; prewarming them in sync mode
-        # would pay startup compiles for kernels that never execute.
-        # Board prewarm votes land at round -1 (below any real round),
-        # and release() clears the touched columns (including the ring
-        # owners the prewarm claimed).
+        # compiles. Prewarm votes land at round -1 (below any real
+        # round), and release() clears the touched columns (including
+        # the ring owners the prewarm claimed).
         for width in self.dense_buckets:
             warm = np.zeros((self.checker.num_nodes, width),
                             dtype=np.uint8)
             warm[0, 0] = 1
-            self.checker.check_block(warm)
-            if pipelined:
-                self.checker.record_block(0, warm, vote_round=-1)
-        if pipelined:
-            for width in (1, self.max_chunk):
-                self.checker.record_and_check([0] * width, [0] * width,
-                                              [-1] * width)
-            self.checker.release(np.arange(self.max_dense))
+            self.checker.record_block(0, warm, vote_round=-1)
+        for width in (1, self.max_chunk):
+            self.checker.record_and_check([0] * width, [0] * width,
+                                          [-1] * width)
+        self.checker.release(np.arange(self.max_dense))
 
     def record(self, slot, round, group_index, acceptor_index) -> None:
         self._slots.append(slot)
@@ -266,9 +219,7 @@ class TpuQuorumTracker(QuorumTracker):
     def record_range(self, slot_start, slot_end, round, group_index,
                      acceptor_index) -> None:
         if slot_end <= slot_start:
-            # Drop empties like record_votes does: an empty range as
-            # ra[0] would seed rnd0/lo from a zero-vote entry and yield
-            # hi = start - 1 in _drain_sync.
+            # Drop empties like record_votes does.
             return
         self._ranges.append((slot_start, slot_end,
                              group_index * self._row_size
@@ -289,300 +240,15 @@ class TpuQuorumTracker(QuorumTracker):
         return bool(self._slots or self._ranges or self._array_votes)
 
     def drain(self) -> list[tuple[int, int]]:
-        """At most a few device calls (usually one, often zero) per
-        event-loop drain; see the class docstring for the two modes."""
+        """Dispatch this drain's votes onto the stateful vote board
+        asynchronously (usually one device call); results are collected
+        later (take_dispatch + collect), so this returns []. Sparse
+        stragglers and off-round votes go through the scatter path;
+        votes in rounds OLDER than the dominant round dispatch BEFORE
+        the dense block so an old-round quorum completing in this drain
+        is reported before the newer round's preemption clears it."""
         if not self.has_votes():
             return []
-        if self.pipelined:
-            return self._drain_pipelined()
-        return self._drain_sync()
-
-    # --- synchronous mode -------------------------------------------------
-
-    def _drain_sync(self) -> list[tuple[int, int]]:
-        """Stateless device check for wide single-round drains; host
-        tally for narrow drains, off-round votes, and the below-quorum
-        residue of device checks.
-
-        Steady-state Phase2b streams cover contiguous slot runs in one
-        round (Leader.scala:331-408 allocates slots contiguously) and a
-        slot's whole write quorum lands in ONE drain (the ProxyLeader
-        fans each Phase2a to its quorum in one pass; the acks coalesce
-        back together), so the common drain is one ``check_block``
-        matmul with an empty residue."""
-        ranges, self._ranges = self._ranges, []
-        av, self._array_votes = self._array_votes, []
-        sl, self._slots = self._slots, []
-        cl, self._cols = self._cols, []
-        rl, self._rounds = self._rounds, []
-
-        # Trickle drains (a serial client, quiescence dribbles): pure
-        # Python straight into the host tally -- no numpy conversions,
-        # no device. This is the regime where ANY fixed overhead is
-        # visible per command. An explicit tiny min_device_slots (the
-        # component benchmarks pin the device path on) lowers this
-        # cutoff too.
-        nvotes = (len(sl) + sum(e - s for s, e, _, _ in ranges)
-                  + sum(s.size for s, _, _ in av))
-        if nvotes < min(48, self.min_device_slots):
-            row = self._row_size
-            frontier = max(sl) if sl else -1
-            for k in range(len(sl)):
-                g, i = divmod(cl[k], row)
-                self._host.record(sl[k], rl[k], g, i)
-            if ranges:
-                frontier = max(frontier,
-                               max(e - 1 for _, e, _, _ in ranges))
-            if av:
-                frontier = max(frontier,
-                               max(int(s.max()) for s, _, _ in av
-                                   if s.size))
-            self._spill_ranges(ranges)
-            self._spill_arrays(av)
-            self._note_frontier(frontier)
-            return self._host_drain_results(nvotes)
-
-        slots = np.asarray(sl, dtype=np.int64)
-        cols = np.asarray(cl, dtype=np.int32)
-        rounds = np.asarray(rl, dtype=np.int32)
-        # Ranges as an [R, 4] array: strided workloads shred ranged
-        # acks into many single-slot runs, so everything below must be
-        # vectorized over R, not Python-per-range.
-        ra = (np.asarray(ranges, dtype=np.int64) if ranges
-              else np.empty((0, 4), dtype=np.int64))
-
-        # Uniform-round test + slot span.
-        uniform = True
-        lo = hi = None
-        if ranges:
-            rnd0 = int(ra[0, 3])
-            uniform = bool((ra[:, 3] == rnd0).all())
-            lo = int(ra[:, 0].min())
-            hi = int(ra[:, 1].max()) - 1
-        elif av:
-            rnd0 = int(av[0][2][0]) if av[0][2].size else 0
-        else:
-            rnd0 = int(rounds[0])
-        for s_arr, _, r_arr in av:
-            if not uniform or not s_arr.size:
-                break
-            if not (r_arr == rnd0).all():
-                uniform = False
-                break
-            alo, ahi = int(s_arr.min()), int(s_arr.max())
-            lo = alo if lo is None else min(lo, alo)
-            hi = ahi if hi is None else max(hi, ahi)
-        if uniform and slots.size:
-            if not (rounds == rnd0).all():
-                uniform = False
-            else:
-                slo = int(slots.min())
-                shi = int(slots.max())
-                lo = slo if lo is None else min(lo, slo)
-                hi = shi if hi is None else max(hi, shi)
-        if not uniform:
-            # Mixed rounds: election churn, preemption -- rare and
-            # thin. Spill everything to the host tally in arrival
-            # order (preserving the oracle's old-round-before-new
-            # reporting liveness).
-            frontier = int(slots.max()) if slots.size else -1
-            if ranges:
-                frontier = max(frontier, int(ra[:, 1].max()) - 1)
-            if av:
-                frontier = max(frontier,
-                               max(int(s.max()) for s, _, _ in av
-                                   if s.size))
-            self._spill_ranges(ranges)
-            self._spill_arrays(av)
-            self._spill_votes(slots, cols, rounds)
-            self._note_frontier(frontier)
-            return self._host_drain_results(nvotes)
-
-        width = hi - lo + 1
-        if width < self.min_device_slots:
-            # Narrow drain: the fixed device round-trip loses to
-            # per-vote Python here -- host tally.
-            self._spill_ranges(ranges)
-            self._spill_arrays(av)
-            self._spill_votes(slots, cols, rounds)
-            self._note_frontier(hi)
-            return self._host_drain_results(nvotes)
-
-        # Wide single-round drain: one stateless check per max_dense
-        # segment of the span (usually exactly one). Only segments
-        # containing votes are materialized, so a pathological sparse
-        # span costs O(active segments), not O(span).
-        out: list[tuple[int, int]] = []
-        seg = self.max_dense
-        # Single-slot runs (the strided-ack shape) fill vectorized;
-        # only genuinely multi-slot runs take the per-range slice loop.
-        single = ra[ra[:, 1] - ra[:, 0] == 1] if ranges else ra
-        multi = ([r for r in ranges if r[1] - r[0] > 1]
-                 if ranges and single.shape[0] != ra.shape[0] else [])
-        active = set()
-        if slots.size:
-            active.update(np.unique((slots - lo) // seg).tolist())
-        if single.shape[0]:
-            active.update(np.unique((single[:, 0] - lo) // seg).tolist())
-        for s, e, _, _ in multi:
-            active.update(range((s - lo) // seg, (e - 1 - lo) // seg + 1))
-        for s_arr, _, _ in av:
-            if s_arr.size:
-                active.update(np.unique((s_arr - lo) // seg).tolist())
-        # Two phases: dispatch every segment's check first, THEN fetch
-        # -- k segments pay one overlap-able round-trip, not k
-        # serialized ones.
-        dispatched = []
-        for seg_idx in sorted(active):
-            seg_start = lo + seg_idx * seg
-            seg_end = min(seg_start + seg, hi + 1)
-            seg_width = seg_end - seg_start
-            bucket = next(b for b in self.dense_buckets
-                          if b >= seg_width)
-            block = np.zeros((self.checker.num_nodes, bucket),
-                             dtype=np.uint8)
-            if single.shape[0]:
-                inseg = ((single[:, 0] >= seg_start)
-                         & (single[:, 0] < seg_end))
-                block[single[inseg, 2],
-                      single[inseg, 0] - seg_start] = 1
-            for s, e, col, _ in multi:
-                cs, ce = max(s, seg_start), min(e, seg_end)
-                if cs < ce:
-                    block[col, cs - seg_start:ce - seg_start] = 1
-            if slots.size:
-                inseg = (slots >= seg_start) & (slots < seg_end)
-                block[cols[inseg], slots[inseg] - seg_start] = 1
-            for s_arr, col, _ in av:
-                inseg = (s_arr >= seg_start) & (s_arr < seg_end)
-                block[col, s_arr[inseg] - seg_start] = 1
-            dispatched.append((seg_start, seg_width, block,
-                               self.checker.check_block_async(block)))
-        self.device_launches += len(dispatched)
-        spilled = 0
-        for seg_start, seg_width, block, mask in dispatched:
-            hit = np.asarray(mask)[:seg_width]
-            touched = block[:, :seg_width].any(axis=0)
-            chosen = np.flatnonzero(hit & touched)
-            if chosen.size:
-                chosen_slots = seg_start + chosen.astype(np.int64)
-                fresh = self._fresh_mask(chosen_slots, rnd0)
-                out.extend(zip(chosen_slots[fresh].tolist(),
-                               (rnd0,) * int(fresh.sum())))
-            resid = touched & ~hit
-            if resid.any():
-                # Below-quorum residue: votes whose quorum straddles
-                # drains. Spill to the host tally (few by
-                # construction), which may complete earlier slots.
-                rcols, rpos = np.nonzero(block[:, :seg_width]
-                                         * resid[None, :])
-                spilled += rcols.size
-                for col, pos in zip(rcols.tolist(), rpos.tolist()):
-                    g, i = divmod(col, self._row_size)
-                    self._host.record(seg_start + pos, rnd0, g, i)
-        self._note_frontier(hi)
-        self.device_drains += 1
-        self.device_votes += nvotes
-        self.spilled_votes += spilled
-        out.extend(self._host_results())
-        return out
-
-    def _spill_votes(self, slots, cols, rounds) -> None:
-        for k in range(slots.size):
-            g, i = divmod(int(cols[k]), self._row_size)
-            self._host.record(int(slots[k]), int(rounds[k]), g, i)
-
-    def _spill_ranges(self, ranges) -> None:
-        for s, e, col, r in ranges:
-            g, i = divmod(col, self._row_size)
-            for slot in range(s, e):
-                self._host.record(slot, r, g, i)
-
-    def _spill_arrays(self, array_votes) -> None:
-        for s_arr, col, r_arr in array_votes:
-            g, i = divmod(col, self._row_size)
-            for slot, r in zip(s_arr.tolist(), r_arr.tolist()):
-                self._host.record(slot, r, g, i)
-
-    def _note_frontier(self, max_slot: int) -> None:
-        """Bound the host tally: the oracle's states dict never evicts,
-        which is fine for the oracle (parity with the reference's
-        per-slot maps) but the spill tally must not grow for the life
-        of the process. Once it exceeds the cap, prune entries the
-        dedup ring has forgotten anyway (slot < frontier - ring size)
-        -- the same windowed-staleness contract as the vote board's
-        self-reclaiming ring."""
-        if max_slot > self._frontier:
-            self._frontier = max_slot
-        if len(self._host.states) > self._host_gc_cap:
-            cutoff = self._frontier - self._dedup_slot.shape[0]
-            self._host.states = {
-                k: v for k, v in self._host.states.items()
-                if k[0] >= cutoff}
-
-    def _host_drain_results(self, nvotes: int) -> list[tuple[int, int]]:
-        """A drain decided by the host tally alone: count it."""
-        self.host_drains += 1
-        self.host_votes += nvotes
-        return self._host_results()
-
-    def _host_results(self) -> list[tuple[int, int]]:
-        """Drain the host tally, marking its completions in the dedup
-        ring so a later stateless re-ack of the same slot is not
-        re-reported."""
-        results = self._host.drain()
-        if not results:
-            return []
-        if len(results) <= 8:  # scalar ring ops beat array setup here
-            n = self._dedup_slot.shape[0]
-            out = []
-            seen: set[int] = set()
-            for slot, rnd in results:
-                if slot in seen:
-                    # Mixed-round churn can complete one slot at two
-                    # rounds in one drain; keep the first (oldest
-                    # round, arrival order) so the ring holds exactly
-                    # one (slot, round) pair per slot.
-                    continue
-                seen.add(slot)
-                i = slot % n
-                if (self._dedup_slot[i] != slot
-                        or self._dedup_round[i] != rnd):
-                    self._dedup_slot[i] = slot
-                    self._dedup_round[i] = rnd
-                    out.append((slot, rnd))
-            return out
-        slots = np.asarray([s for s, _ in results], dtype=np.int64)
-        rounds = np.asarray([r for _, r in results], dtype=np.int64)
-        # _fresh_mask requires unique slots (its last-wins fancy-indexed
-        # ring write forgets one pair otherwise, re-reporting a later
-        # duplicate re-ack): dedup to one entry per slot, keeping the
-        # first = oldest-round arrival, as the dict oracle reports.
-        # The DROPPED (slot, newer-round) pair is never reported -- a
-        # later re-ack completing it would be its FIRST report, which
-        # the per-(slot, round) exactly-once contract permits (the
-        # ring can only remember one round per slot).
-        uniq, first = np.unique(slots, return_index=True)
-        if uniq.size != slots.size:
-            first.sort()
-            slots = slots[first]
-            rounds = rounds[first]
-            results = [results[i] for i in first.tolist()]
-        fresh = self._fresh_mask(slots, rounds)
-        if fresh.all():
-            return results
-        return [kv for kv, f in zip(results, fresh.tolist()) if f]
-
-    # --- pipelined mode ---------------------------------------------------
-
-    def _drain_pipelined(self) -> list[tuple[int, int]]:
-        """Dispatch this drain's votes onto the stateful vote board
-        asynchronously; results are collected later (take_dispatch +
-        collect). Sparse stragglers and off-round votes go through the
-        scatter path; votes in rounds OLDER than the dominant round
-        dispatch BEFORE the dense block so an old-round quorum
-        completing in this drain is reported before the newer round's
-        preemption clears it."""
         parts: list[tuple] = []
         slots = np.asarray(self._slots, dtype=np.int64)
         cols = np.asarray(self._cols, dtype=np.int32)

@@ -155,8 +155,6 @@ def make_multipaxos(
     grid_shape: tuple[int, int] | None = None,
     batch_size: int = 1,
     quorum_backend: str = "dict",
-    tpu_pipelined: bool = False,
-    tpu_min_device_slots: int = 0,
     coalesced: "bool | str" = False,
     phase1_backend: str = "host",
     state_machine_factory=AppendLog,
@@ -250,8 +248,6 @@ def make_multipaxos(
                     ProxyLeaderOptions(
                         quorum_backend=quorum_backend,
                         tpu_window=1 << 12,
-                        tpu_pipelined=tpu_pipelined,
-                        tpu_min_device_slots=tpu_min_device_slots,
                         epoch_quorums=epoch_quorums),
                     seed=seed + 10 + i)
         for i, a in enumerate(config.proxy_leader_addresses)]
@@ -304,3 +300,28 @@ def executed_prefix(replica: Replica) -> list:
 
 def state_machine_of(sim: MultiPaxosSim, i: int) -> StateMachine:
     return sim.replicas[i].state_machine
+
+
+def drain_and_collect(tracker) -> list:
+    """One drain of ``tracker`` with everything it decided: the device
+    tracker's drain only dispatches, so its dispatches are taken and
+    collected until none is left; a DictQuorumTracker just drains."""
+    out = list(tracker.drain())
+    take = getattr(tracker, "take_dispatch", None)
+    while take is not None and (dispatch := take()) is not None:
+        out.extend(tracker.collect(dispatch))
+    return out
+
+
+def deliver_and_flush(sim: MultiPaxosSim, coalesced: bool = False) -> None:
+    """Deliver until quiet, firing the proxy leaders' ``tpuDrainFlush``
+    timers (what collects a ``quorum_backend="tpu"`` tracker's
+    dispatches over a SimTransport) until none is armed."""
+    deliver = (sim.transport.deliver_all_coalesced if coalesced
+               else sim.transport.deliver_all)
+    deliver()
+    while flushes := [t for t in sim.transport.running_timers()
+                      if t.name == "tpuDrainFlush"]:
+        for timer in flushes:
+            sim.transport.trigger_timer(timer.id)
+        deliver()

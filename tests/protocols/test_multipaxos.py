@@ -11,7 +11,12 @@ import pytest
 from frankenpaxos_tpu.runtime import PickleSerializer
 from frankenpaxos_tpu.sim import SimulatedSystem, Simulator
 from frankenpaxos_tpu.statemachine import GetRequest, KeyValueStore, SetRequest
-from tests.protocols.multipaxos_harness import executed_prefix, make_multipaxos
+from tests.protocols.multipaxos_harness import (
+    deliver_and_flush,
+    drain_and_collect,
+    executed_prefix,
+    make_multipaxos,
+)
 
 SER = PickleSerializer()
 
@@ -19,8 +24,13 @@ SER = PickleSerializer()
 def run_write(sim, client_index, pseudonym, payload):
     got = []
     sim.clients[client_index].write(pseudonym, payload, got.append)
-    sim.transport.deliver_all()
+    deliver_and_flush(sim)
     return got
+
+
+def board_launches(sim) -> list[int]:
+    """Kernel launches of each proxy leader's device tracker."""
+    return [p.tracker.device_launches for p in sim.proxy_leaders]
 
 
 class TestMultiPaxosIntegration:
@@ -98,12 +108,14 @@ class TestMultiPaxosIntegration:
             assert run_write(sim, 0, 0, b"cmd%d" % i) == [b"%d" % i]
         logs = [executed_prefix(r) for r in sim.replicas]
         assert logs[0] == logs[1] and len(logs[0]) == 5
+        assert sum(board_launches(sim)) >= 5
 
     def test_tpu_backend_flexible_grid(self):
         sim = make_multipaxos(f=1, flexible=True, grid_shape=(2, 3),
                               quorum_backend="tpu")
         for i in range(4):
             assert run_write(sim, 0, 0, b"cmd%d" % i) == [b"%d" % i]
+        assert sum(board_launches(sim)) >= 4
 
     def test_tpu_phase1_recovery_preserves_log(self):
         """Failover with phase1_backend=tpu: the new leader's batched
@@ -419,7 +431,7 @@ class TestCoalescedRunPipeline:
         for p in range(lo, hi):
             sim.clients[0].write(p, b"v%d" % p, got.append)
         sim.clients[0].flush_writes()
-        sim.transport.deliver_all_coalesced()
+        deliver_and_flush(sim, coalesced=True)
 
     @pytest.mark.parametrize("backend", ["dict", "tpu"])
     def test_matches_per_message_pipeline(self, backend):
@@ -440,6 +452,8 @@ class TestCoalescedRunPipeline:
             assert executed_prefix(sim.replicas[0]) \
                 == executed_prefix(sim.replicas[1])
             logs[coalesced] = executed_prefix(sim.replicas[0])
+            if backend == "tpu":
+                assert sum(board_launches(sim)) > 0
         assert len(logs[False]) == len(logs[True]) == 200
         assert logs[False] == logs[True]
 
@@ -769,8 +783,15 @@ class TestAcceptorSameStartTruncation:
 
 def test_simulation_with_tpu_backend():
     simulated = MultiPaxosSimulated(f=1, quorum_backend="tpu")
-    failure = Simulator(simulated, run_length=60, num_runs=3).run(seed=0)
+    systems = []
+    new_system = simulated.new_system
+    simulated.new_system = lambda seed: (
+        systems.append(new_system(seed)) or systems[-1])
+    # Long enough runs that votes reach the proxy leaders: at 60 steps
+    # no tracker of either backend ever saw one.
+    failure = Simulator(simulated, run_length=300, num_runs=3).run(seed=0)
     assert failure is None, str(failure)
+    assert sum(sum(board_launches(sim)) for sim in systems) > 0
 
 
 def test_quorum_tracker_dense_and_sparse_paths_match_dict():
@@ -784,37 +805,33 @@ def test_quorum_tracker_dense_and_sparse_paths_match_dict():
 
     sim = make_multipaxos(f=1)
     config = sim.config
-    # min_device_slots=1 forces wide-enough drains onto the stateless
-    # device path; 1024 routes everything through the host tally --
-    # both must match the oracle exactly.
-    for min_dev in (1, 1024):
-        for seed in range(4):
-            rng = random.Random(100 + seed)
-            dict_tracker = DictQuorumTracker(config)
-            tpu_tracker = TpuQuorumTracker(config, window=1 << 12,
-                                           min_device_slots=min_dev)
-            cursor = 0
-            for _ in range(15):
-                votes = []
-                if rng.random() < 0.6 or cursor == 0:
-                    # Contiguous frontier run: the dense block shape.
-                    run_len = rng.randrange(1, 40)
-                    for slot in range(cursor, cursor + run_len):
-                        for acc in rng.sample(range(3),
-                                              rng.randrange(1, 4)):
-                            votes.append((slot, acc))
-                    cursor += run_len
-                else:
-                    # Scattered stragglers over already-seen slots.
-                    for _ in range(rng.randrange(1, 16)):
-                        votes.append((rng.randrange(cursor),
-                                      rng.randrange(3)))
-                rng.shuffle(votes)
-                for slot, acc in votes:
-                    dict_tracker.record(slot, 0, 0, acc)
-                    tpu_tracker.record(slot, 0, 0, acc)
-                assert sorted(dict_tracker.drain()) == \
-                    sorted(tpu_tracker.drain()), (min_dev, seed, cursor)
+    for seed in range(4):
+        rng = random.Random(100 + seed)
+        dict_tracker = DictQuorumTracker(config)
+        tpu_tracker = TpuQuorumTracker(config, window=1 << 12)
+        cursor = 0
+        for _ in range(15):
+            votes = []
+            if rng.random() < 0.6 or cursor == 0:
+                # Contiguous frontier run: the dense block shape.
+                run_len = rng.randrange(1, 40)
+                for slot in range(cursor, cursor + run_len):
+                    for acc in rng.sample(range(3),
+                                          rng.randrange(1, 4)):
+                        votes.append((slot, acc))
+                cursor += run_len
+            else:
+                # Scattered stragglers over already-seen slots.
+                for _ in range(rng.randrange(1, 16)):
+                    votes.append((rng.randrange(cursor),
+                                  rng.randrange(3)))
+            rng.shuffle(votes)
+            for slot, acc in votes:
+                dict_tracker.record(slot, 0, 0, acc)
+                tpu_tracker.record(slot, 0, 0, acc)
+            assert sorted(drain_and_collect(dict_tracker)) == \
+                sorted(drain_and_collect(tpu_tracker)), (seed, cursor)
+        assert tpu_tracker.device_launches >= 15
 
 
 def test_quorum_tracker_ring_wrap_self_reclaims():
@@ -831,18 +848,7 @@ def test_quorum_tracker_ring_wrap_self_reclaims():
     sim = make_multipaxos(f=1)
     window = 256
     dict_tracker = DictQuorumTracker(sim.config)
-    # The board only carries cross-drain state in PIPELINED mode now
-    # (sync mode decides statelessly + spills to the host tally), so
-    # the ring-wrap property is exercised through pipelined dispatches.
-    tpu_tracker = TpuQuorumTracker(sim.config, window=window,
-                                   pipelined=True)
-
-    def tpu_drain():
-        assert tpu_tracker.drain() == []
-        got = []
-        while (d := tpu_tracker.take_dispatch()) is not None:
-            got.extend(tpu_tracker.collect(d))
-        return got
+    tpu_tracker = TpuQuorumTracker(sim.config, window=window)
 
     # Drive 8 windows of slots through in dense runs of 32.
     for base in range(0, 8 * window, 32):
@@ -850,18 +856,20 @@ def test_quorum_tracker_ring_wrap_self_reclaims():
             for t in (dict_tracker, tpu_tracker):
                 t.record(slot, 0, 0, 0)
                 t.record(slot, 0, 0, 1)
-        assert sorted(dict_tracker.drain()) == sorted(tpu_drain())
+        assert sorted(dict_tracker.drain()) \
+            == sorted(drain_and_collect(tpu_tracker))
     # Sparse wrap: a straggler vote for a long-dead slot must be dropped
     # (its column has moved on), not clear the column's current state.
     half1 = window // 2
     tpu_tracker.record(half1, 0, 0, 0)  # ancient slot, wrapped 7 times
-    assert tpu_drain() == []
+    assert drain_and_collect(tpu_tracker) == []
     live = 8 * window + 5
     for t in (dict_tracker, tpu_tracker):
         t.record(live, 0, 0, 0)
         t.record(live, 0, 0, 2)
-    assert sorted(dict_tracker.drain()) == sorted(tpu_drain()) \
-        == [(live, 0)]
+    assert sorted(dict_tracker.drain()) \
+        == sorted(drain_and_collect(tpu_tracker)) == [(live, 0)]
+    assert tpu_tracker.device_launches > 0
 
 
 def test_quorum_tracker_mixed_round_drain_reports_old_quorum():
@@ -881,36 +889,38 @@ def test_quorum_tracker_mixed_round_drain_reports_old_quorum():
         t = tracker_cls(sim.config)
         # Round 0: slot 5 has one of two votes.
         t.record(5, 0, 0, 0)
-        assert t.drain() == []
+        assert drain_and_collect(t) == []
         # One drain: slot 5's completing round-0 vote arrives first,
         # then a wave of round-1 votes (the dominant round) including
         # slot 5. Arrival-order semantics: (5, 0) reached quorum.
         t.record(5, 0, 0, 1)
         for slot in range(4, 8):
             t.record(slot, 1, 0, 0)
-        out = t.drain()
+        out = drain_and_collect(t)
         assert (5, 0) in out, (tracker_cls, out)
+    # The last one built is the device tracker: the first drain is one
+    # launch, the second one for each round.
+    assert t.device_launches == 3
 
 
 def test_quorum_tracker_duplicate_slot_two_rounds_one_drain():
-    """Advisor-found: a mixed-round host drain completing ONE slot at
-    TWO rounds fed ``_fresh_mask`` duplicate slots, whose last-wins
-    fancy-indexed ring write forgot one (slot, round) pair -- a later
-    device re-ack of the forgotten pair was then re-reported,
-    violating exactly-once. The host drain now dedups to one entry per
-    slot (the first = oldest round, arrival order, as the oracle
-    reports). The dropped newer-round pair is simply never reported in
-    that drain; a later re-ack completing it would be that pair's
-    FIRST report, which the per-(slot, round) contract permits."""
+    """Advisor-found: one drain completing ONE slot at TWO rounds must
+    report the slot once in that drain -- the first = oldest round,
+    arrival order, as the oracle reports -- and the dedup ring must
+    hold exactly one (slot, round) pair for it: a later wide re-ack
+    then re-reports no slot that was reported already. The dropped
+    newer-round pair is simply never reported in that drain; a later
+    re-ack completing it would be that pair's FIRST report, which the
+    per-(slot, round) contract permits."""
     from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
         TpuQuorumTracker,
     )
 
     sim = make_multipaxos(f=1)
-    t = TpuQuorumTracker(sim.config, window=1 << 10, min_device_slots=1)
-    # One mixed-round drain (mixed rounds always spill to the host
-    # tally): slot 5 completes at round 0 AND round 1, plus 10 more
-    # round-0 slots so the host drain takes the vectorized (>8) path.
+    t = TpuQuorumTracker(sim.config, window=1 << 10)
+    # One mixed-round drain: slot 5 completes at round 0 AND round 1,
+    # plus 10 more round-0 slots (the dominant round's dense block;
+    # round 1 goes after it through the scatter).
     t.record(5, 0, 0, 0)
     t.record(5, 0, 0, 1)
     t.record(5, 1, 0, 0)
@@ -918,25 +928,26 @@ def test_quorum_tracker_duplicate_slot_two_rounds_one_drain():
     for slot in range(10, 20):
         t.record(slot, 0, 0, 0)
         t.record(slot, 0, 0, 1)
-    out = t.drain()
+    out = drain_and_collect(t)
     assert [s for s, _ in out].count(5) == 1 and (5, 0) in out, out
-    # A wide dense round-0 re-ack containing slot 5 (the stateless
-    # device path, checked against the dedup ring) must not re-report
+    assert {s for s, _ in out} == {5, *range(10, 20)}, out
+    assert t.device_launches == 2
+    # A wide dense round-0 re-ack containing slot 5 must not re-report
     # any already-reported slot.
     for slot in range(0, 200):
         t.record(slot, 0, 0, 0)
         t.record(slot, 0, 0, 2)
-    out2 = t.drain()
+    out2 = drain_and_collect(t)
     reported = {s for s, _ in out2}
     assert 5 not in reported, out2
     assert reported.isdisjoint(range(10, 20)), out2
     assert set(range(0, 5)).issubset(reported)
+    assert t.device_launches == 3
 
 
 def test_quorum_tracker_empty_range_ignored():
     """An empty Phase2bRange (slot_end <= slot_start) is dropped at the
-    door like empty packed votes: as ra[0] it would seed the drain's
-    round/lo from a zero-vote entry and skew hi to start - 1."""
+    door like empty packed votes: it is no vote, and no drain."""
     from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
         TpuQuorumTracker,
     )
@@ -944,11 +955,13 @@ def test_quorum_tracker_empty_range_ignored():
     sim = make_multipaxos(f=1)
     t = TpuQuorumTracker(sim.config, window=1 << 10)
     t.record_range(7, 7, 0, 0, 0)
-    assert t.drain() == []
+    assert not t.has_votes()
+    assert drain_and_collect(t) == [] and t.device_drains == 0
     t.record_range(7, 3, 5, 0, 0)  # inverted: also dropped
     t.record_range(3, 5, 0, 0, 0)
     t.record_range(3, 5, 0, 0, 1)
-    assert sorted(t.drain()) == [(3, 0), (4, 0)]
+    assert sorted(drain_and_collect(t)) == [(3, 0), (4, 0)]
+    assert (t.device_launches, t.device_votes) == (1, 4)
 
 
 def test_quorum_tracker_ranged_votes_match_dict():
@@ -986,8 +999,9 @@ def test_quorum_tracker_ranged_votes_match_dict():
                     slot, acc = rng.randrange(cursor), rng.randrange(3)
                     for t in trackers:
                         t.record(slot, 0, 0, acc)
-            got = [sorted(t.drain()) for t in trackers]
+            got = [sorted(drain_and_collect(t)) for t in trackers]
             assert got[0] == got[1], (seed, cursor)
+        assert trackers[1].device_launches >= 12
 
 
 def test_acceptor_emits_phase2b_ranges_per_drain():
@@ -1026,9 +1040,9 @@ def test_sim_transport_coalesced_waves_match_serial():
     for batch in range(3):
         for p in range(8):
             sim.clients[0].write(p, b"b%d.%d" % (batch, p), got.append)
-        sim.transport.deliver_all_coalesced()
+        deliver_and_flush(sim, coalesced=True)
     assert len(got) == 24
-    from tests.protocols.multipaxos_harness import executed_prefix
+    assert sum(board_launches(sim)) > 0
     logs = [executed_prefix(r) for r in sim.replicas]
     assert logs[0] == logs[1]
     assert len(logs[0]) >= 24
@@ -1050,45 +1064,66 @@ def test_quorum_tracker_gap_slot_keeps_old_round_votes():
     # Drain 1: slot 10 gets 1 of 2 round-0 votes.
     for t in trackers:
         t.record(10, 0, 0, 0)
-    assert [t.drain() for t in trackers] == [[], []]
+    assert [drain_and_collect(t) for t in trackers] == [[], []]
     # Drain 2: round-1 votes for slots 8 and 12 only (slot 10 is a gap
     # inside the dense run and must be untouched).
     for t in trackers:
         t.record(8, 1, 0, 0)
         t.record(12, 1, 0, 1)
-    assert [t.drain() for t in trackers] == [[], []]
+    assert [drain_and_collect(t) for t in trackers] == [[], []]
     # Drain 3: slot 10's second round-0 vote completes its quorum.
     for t in trackers:
         t.record(10, 0, 0, 1)
-    dict_out, tpu_out = [t.drain() for t in trackers]
+    dict_out, tpu_out = [drain_and_collect(t) for t in trackers]
     assert dict_out == tpu_out == [(10, 0)]
+    assert trackers[1].device_launches == 3
 
 
 def test_pipelined_tpu_backend_matches():
-    """Pipelined device drains (dispatch async, collect one drain later,
-    flush timer covers quiescence) still commit every write and keep
-    replica logs identical to the reference semantics."""
-    sim = make_multipaxos(f=1, quorum_backend="tpu", tpu_pipelined=True)
+    """A drain only dispatches: with every message delivered a write's
+    quorum is still in flight on the device, the flush timer collects
+    it (quiescence), and then every write commits with replica logs
+    identical to the reference semantics."""
+    sim = make_multipaxos(f=1, quorum_backend="tpu")
     got = []
     for i in range(5):
         sim.clients[0].write(0, b"cmd%d" % i, got.append)
-        for _ in range(10):
-            sim.transport.deliver_all()
-            if got and got[-1] == b"%d" % i:
-                break
-            # Quiescence: the in-flight device dispatch is collected by
-            # the proxy leader's flush timer.
-            for timer in sim.transport.running_timers():
-                if timer.name == "tpuDrainFlush":
-                    sim.transport.trigger_timer(timer.id)
+        sim.transport.deliver_all()
+        assert len(got) == i
+        assert any(p.tracker.has_pending() for p in sim.proxy_leaders)
+        deliver_and_flush(sim)
         assert got[-1] == b"%d" % i, (i, got)
     logs = [executed_prefix(r) for r in sim.replicas]
     assert logs[0] == logs[1] and len(logs[0]) == 5
+    assert sum(board_launches(sim)) >= 5
+
+
+def test_tpu_backend_alone_commits_on_the_board():
+    """``quorum_backend="tpu"`` and no other option is the board: every
+    write commits, and every proxy leader's tracker launched kernels
+    for the votes it was sent (nothing falls back to a host tally)."""
+    sim = make_multipaxos(f=1, quorum_backend="tpu", coalesced=True)
+    got = []
+    # The leader moves on to its next proxy leader every 256 slots
+    # (LeaderOptions.proxy_leader_chunk): 320 reach both.
+    for wave in range(10):
+        for p in range(32):
+            sim.clients[0].write(p, b"w%d.%d" % (wave, p), got.append)
+        sim.clients[0].flush_writes()
+        deliver_and_flush(sim, coalesced=True)
+    assert len(got) == 320
+    logs = [executed_prefix(r) for r in sim.replicas]
+    assert logs[0] == logs[1] and len(logs[0]) >= 320
+    for proxy_leader in sim.proxy_leaders:
+        t = proxy_leader.tracker
+        assert t.device_launches > 0 and t.device_votes > 0
+        assert (t.host_drains, t.host_votes, t.spilled_votes) == (0, 0, 0)
+        assert not t.has_pending()
 
 
 def test_pipelined_tracker_matches_dict_across_drains():
-    """The pipelined tracker reports exactly the dict oracle's choices,
-    shifted by at most one drain."""
+    """The tracker reports exactly the dict oracle's choices, however
+    many dispatches are in flight before the first is collected."""
     from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
         DictQuorumTracker,
         TpuQuorumTracker,
@@ -1098,9 +1133,8 @@ def test_pipelined_tracker_matches_dict_across_drains():
     for seed in range(3):
         rng = random.Random(200 + seed)
         dict_tracker = DictQuorumTracker(sim.config)
-        tpu_tracker = TpuQuorumTracker(sim.config, window=1 << 12,
-                                       pipelined=True)
-        dict_out, tpu_out = [], []
+        tpu_tracker = TpuQuorumTracker(sim.config, window=1 << 12)
+        dict_out = []
         cursor = 0
         for _ in range(12):
             votes = []
@@ -1113,37 +1147,17 @@ def test_pipelined_tracker_matches_dict_across_drains():
                 dict_tracker.record(slot, 0, 0, acc)
                 tpu_tracker.record(slot, 0, 0, acc)
             dict_out += dict_tracker.drain()
-            assert tpu_tracker.drain() == []  # pipelined: dispatch only
+            assert tpu_tracker.drain() == []  # dispatch only
         # Collect every in-flight dispatch (what the proxy leader's
         # collector thread / flush timer does).
         assert tpu_tracker.has_pending()
-        while (dispatch := tpu_tracker.take_dispatch()) is not None:
-            tpu_out += tpu_tracker.collect(dispatch)
+        tpu_out = drain_and_collect(tpu_tracker)
         assert sorted(dict_out) == sorted(tpu_out), seed
-
-
-def test_quorum_tracker_host_spill_is_bounded():
-    """Review r4: the sync-mode host spill tally must not grow for the
-    life of the process -- entries older than the dedup ring's memory
-    are pruned once the tally exceeds its cap."""
-    from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
-        TpuQuorumTracker,
-    )
-
-    sim = make_multipaxos(f=1)
-    tracker = TpuQuorumTracker(sim.config, window=256)
-    tracker._host_gc_cap = 512  # shrink the cap so the test is fast
-    # Leave every slot one vote short of quorum so everything stays in
-    # the host tally (trickle drains -> host path).
-    for base in range(0, 4096, 16):
-        for slot in range(base, base + 16):
-            tracker.record(slot, 0, 0, 0)
-        assert tracker.drain() == []
-    assert len(tracker._host.states) <= 512 + 256
+        assert tpu_tracker.device_launches >= 12
 
 
 def test_quorum_tracker_straddling_board_split_uses_prewarmed_widths():
-    """Review r4: a pipelined dense run straddling the ring end must
+    """Review r4: a dense run straddling the ring end must
     decompose into prewarmed bucket widths (+ scatter remainder), not
     compile odd widths mid-run -- and still report the right slots."""
     from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
@@ -1154,19 +1168,16 @@ def test_quorum_tracker_straddling_board_split_uses_prewarmed_widths():
     sim = make_multipaxos(f=1)
     window = 256
     dict_tracker = DictQuorumTracker(sim.config)
-    tpu_tracker = TpuQuorumTracker(sim.config, window=window,
-                                   pipelined=True)
+    tpu_tracker = TpuQuorumTracker(sim.config, window=window)
     # A 100-wide run ending past the ring end (starts at window-30).
     start = window - 30
     for t in (dict_tracker, tpu_tracker):
         for slot in range(start, start + 100):
             t.record(slot, 0, 0, 0)
             t.record(slot, 0, 0, 1)
-    assert tpu_tracker.drain() == []  # pipelined: dispatched async
-    got = []
-    while (d := tpu_tracker.take_dispatch()) is not None:
-        got.extend(tpu_tracker.collect(d))
+    got = drain_and_collect(tpu_tracker)
     assert sorted(got) == sorted(dict_tracker.drain())
+    assert tpu_tracker.device_launches > 1  # split at the ring end
 
 
 def test_acceptor_packs_fragmented_drains():
@@ -1199,8 +1210,9 @@ def test_acceptor_packs_fragmented_drains():
 
 
 def test_quorum_tracker_record_votes_matches_dict():
-    """Packed array votes (record_votes) agree with the oracle across
-    both tpu-tracker modes and the dict default expansion."""
+    """Packed array votes (record_votes) agree with the oracle: the
+    board's vectorized expansion beside the dict's default per-slot
+    one."""
     import numpy as np
 
     from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
@@ -1209,11 +1221,10 @@ def test_quorum_tracker_record_votes_matches_dict():
     )
 
     sim = make_multipaxos(f=1)
-    rng = random.Random(7)
-    for min_dev in (1, 1024):
+    for seed in (7, 8):
+        rng = random.Random(seed)
         dict_tracker = DictQuorumTracker(sim.config)
-        tpu_tracker = TpuQuorumTracker(sim.config, window=1 << 12,
-                                       min_device_slots=min_dev)
+        tpu_tracker = TpuQuorumTracker(sim.config, window=1 << 12)
         cursor = 0
         for _ in range(10):
             run_len = rng.randrange(8, 60)
@@ -1228,5 +1239,50 @@ def test_quorum_tracker_record_votes_matches_dict():
                 dict_tracker.record_votes(slots, rounds, 0, acc)
                 tpu_tracker.record_votes(slots, rounds, 0, acc)
             cursor += run_len
-            assert sorted(dict_tracker.drain()) == \
-                sorted(tpu_tracker.drain()), (min_dev, cursor)
+            assert sorted(drain_and_collect(dict_tracker)) == \
+                sorted(drain_and_collect(tpu_tracker)), (seed, cursor)
+        assert tpu_tracker.device_launches >= 10
+
+
+def test_quorum_tracker_trickle_drains_match_dict_on_the_board():
+    """Drains of one and two votes -- a serial client's, where nothing
+    amortises a launch -- go to the board like any other and report
+    what the oracle reports, over a few hundred drains and more than
+    one turn of the ring, quorums straddling drains included."""
+    from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+        DictQuorumTracker,
+        TpuQuorumTracker,
+    )
+
+    sim = make_multipaxos(f=1)
+    window = 64
+    rng = random.Random(33)
+    trackers = [DictQuorumTracker(sim.config),
+                TpuQuorumTracker(sim.config, window=window)]
+    reported = 0
+    drains = 0
+    for slot in range(3 * window):
+        # The slot's two votes in one drain, or one a drain; now and
+        # then a third vote for an older slot rides along (a re-ack:
+        # it reports nothing).
+        first, second = rng.sample(range(3), 2)
+        together = rng.random() < 0.4
+        for acceptors in ([first, second] if together
+                          else [first], [second]):
+            for t in trackers:
+                for acceptor in acceptors:
+                    t.record(slot, 0, 0, acceptor)
+            if not together and slot and rng.random() < 0.2:
+                for t in trackers:
+                    t.record(slot - 1, 0, 0, first)
+            got = [sorted(drain_and_collect(t)) for t in trackers]
+            assert got[0] == got[1], (slot, got)
+            reported += len(got[1])
+            drains += 1
+            if together:
+                break
+    assert reported == 3 * window and drains > 250
+    board = trackers[1]
+    assert board.device_drains == drains
+    assert board.device_launches >= drains
+    assert board.checker.window_violations == 0

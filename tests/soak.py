@@ -208,35 +208,25 @@ CONFIGS: list[tuple] = [
     ("craq/chain5", CraqChain5Simulated),
     # Device-backed configs at FULL scale (500x250 like every other
     # row): the TPU quorum tracker / dependency kernels under the
-    # randomized interleaving exploration. min_device_slots=1 pins the
-    # device path ON (sim drains are narrow; the auto threshold would
-    # route them all to the host tally and the device kernels would
-    # never run under interleaving). The module-level platform pin
+    # randomized interleaving exploration. The multipaxos tracker's
+    # drains dispatch to the vote board and its flush timer collects
+    # them: a real sim timer, so the exploration fires it at arbitrary
+    # points relative to deliveries. The module-level platform pin
     # keeps every device call on local CPU XLA.
     ("multipaxos/f1-tpu-backend",
-     lambda: MultiPaxosSimulated(f=1, quorum_backend="tpu",
-                                 tpu_min_device_slots=1)),
+     lambda: MultiPaxosSimulated(f=1, quorum_backend="tpu")),
     ("multipaxos/f1-grid-tpu-backend",
      lambda: MultiPaxosSimulated(f=1, flexible=True, grid_shape=(2, 2),
-                                 quorum_backend="tpu",
-                                 tpu_min_device_slots=1)),
+                                 quorum_backend="tpu")),
     ("epaxos/f1-tpu-deps",
      lambda: EPaxosSimulated(dep_backend="tpu")),
-    # Pipelined device drains (async dispatch + flush-timer collection,
-    # quorum_tracker._drain_pipelined) under sim interleaving: the
-    # flush timer is a real sim timer, so the exploration fires it at
-    # arbitrary points relative to deliveries.
-    ("multipaxos/f1-tpu-pipelined",
-     lambda: MultiPaxosSimulated(f=1, quorum_backend="tpu",
-                                 tpu_pipelined=True)),
     # The drain-granular run pipeline (ClientRequestArray -> Phase2aRun
     # -> ChosenRun -> ClientReplyArray), host + device trackers + grid.
     ("multipaxos/f1-coalesced",
      lambda: MultiPaxosSimulated(f=1, coalesced=True)),
     ("multipaxos/f1-coalesced-tpu",
      lambda: MultiPaxosSimulated(f=1, coalesced=True,
-                                 quorum_backend="tpu",
-                                 tpu_min_device_slots=1)),
+                                 quorum_backend="tpu")),
     ("multipaxos/f1-coalesced-grid",
      lambda: MultiPaxosSimulated(f=1, coalesced=True, flexible=True,
                                  grid_shape=(2, 2))),

@@ -450,7 +450,7 @@ def test_tpu203_blocking_fetch_of_async_dispatch(tmp_path):
     import numpy as np
 
     def fetch(checker, block):
-        mask = checker.check_block_async(block)
+        mask = checker.record_block_async(0, block)
         return np.asarray(mask)
     """}))
     assert any(f.rule == "TPU203" for f in findings)

@@ -9,7 +9,6 @@ import subprocess
 import sys
 
 import chip_smoke
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -22,17 +21,14 @@ def _assert_checks(result: dict) -> None:
     assert result["device"]["platform"] == "cpu"
 
 
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_stage_served_at_toy_size(tmp_path, pipelined):
+def test_stage_served_at_toy_size(tmp_path):
     result = chip_smoke.stage_served(
-        str(tmp_path), pipelined=pipelined, client_procs=2,
-        loops_per_proc=32, duration_s=1.0, window=4096,
-        # CPU XLA's own threshold (1024 slots) is wider than this load.
-        min_device_slots=0 if pipelined else 1)
+        str(tmp_path), client_procs=2, loops_per_proc=32, duration_s=1.0,
+        window=4096)
     _assert_checks(result)
     assert result["chip_owner"] == "proxy_leader_0_1"
     assert result["writes_acked"] >= result["closed_loops"]
-    assert result["device_drains"] > 0
+    assert result["device_launches"] >= result["device_drains"] > 0
 
     # cli.py's per-process decision, as the roles logged it: only the
     # chip owner claimed a device, and it said the pin made it a CPU.

@@ -1,6 +1,7 @@
 """One process per chip: who claims the TPU, who is pinned to the CPU,
 where compiled executables go, and what a launch may look like."""
 
+import dataclasses
 import hashlib
 import os
 
@@ -155,26 +156,60 @@ def test_tracker_counts_where_its_work_went():
     )
 
     _, config = _multipaxos()
-    sync = TpuQuorumTracker(config, window=256, min_device_slots=64)
-    # Narrow drain: straight to the host tally.
+    tracker = TpuQuorumTracker(config, window=1 << 10)
+    # A narrow drain is a device drain like any other: one launch.
     for acceptor in (0, 1):
-        sync.record_range(0, 8, 0, 0, acceptor)
-    assert len(sync.drain()) == 8
-    assert (sync.host_drains, sync.host_votes) == (1, 16)
-    assert (sync.device_drains, sync.device_votes) == (0, 0)
-    # Wide drain: one kernel decides the slots with both votes; the 36
-    # slots with one vote are spilled to the tally.
-    sync.record_range(100, 200, 0, 0, 0)
-    sync.record_range(100, 164, 0, 0, 1)
-    assert len(sync.drain()) == 64
-    assert (sync.device_drains, sync.device_votes) == (1, 164)
-    assert sync.spilled_votes == 36
-    assert (sync.host_drains, sync.host_votes) == (1, 16)
+        tracker.record_range(0, 8, 0, 0, acceptor)
+    assert tracker.drain() == []
+    assert len(tracker.collect(tracker.take_dispatch())) == 8
+    assert (tracker.device_drains, tracker.device_votes) == (1, 16)
+    assert tracker.device_launches == 1
+    # A wide one with 36 slots a vote short: the board keeps them, and
+    # their second votes complete them in a later drain.
+    tracker.record_range(100, 200, 0, 0, 0)
+    tracker.record_range(100, 164, 0, 0, 1)
+    assert tracker.drain() == []
+    assert len(tracker.collect(tracker.take_dispatch())) == 64
+    assert (tracker.device_drains, tracker.device_votes) == (2, 180)
+    tracker.record_range(164, 200, 0, 0, 2)
+    assert tracker.drain() == []
+    assert len(tracker.collect(tracker.take_dispatch())) == 36
+    assert (tracker.device_drains, tracker.device_votes) == (3, 216)
+    assert tracker.device_launches == 3
+    # What benchmark/harness/role_entry.py still reads: no host tally.
+    assert (tracker.host_drains, tracker.host_votes,
+            tracker.spilled_votes) == (0, 0, 0)
 
-    pipelined = TpuQuorumTracker(config, window=256, pipelined=True)
-    for acceptor in (0, 1):
-        pipelined.record_range(0, 8, 0, 0, acceptor)
-    assert pipelined.drain() == []
-    assert len(pipelined.collect(pipelined.take_dispatch())) == 8
-    assert (pipelined.device_drains, pipelined.device_votes) == (1, 16)
-    assert (pipelined.host_drains, pipelined.spilled_votes) == (0, 0)
+
+def test_overrides_of_the_tracker_mode_that_went_are_ignored_and_listed():
+    """``benchmark/configs/*.json`` still pass ``tpu_pipelined``, and an
+    old deployment may pass ``tpu_min_device_slots``: no options class
+    declares either now, so the proxy leader is built on the board
+    without them and the role's start-up lists them as unused
+    (``cli.py`` logs ``unmatched_overrides()`` and goes on)."""
+    from frankenpaxos_tpu.deploy import DeployCtx
+    from frankenpaxos_tpu.protocols.multipaxos import ProxyLeaderOptions
+    from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+        TpuQuorumTracker,
+    )
+    from frankenpaxos_tpu.runtime import FakeLogger, SimTransport
+
+    protocol, config = _multipaxos()
+    logger = FakeLogger()
+    ctx = DeployCtx(
+        config=config, transport=SimTransport(logger), logger=logger,
+        overrides={"quorum_backend": "tpu", "tpu_window": "256",
+                   "tpu_pipelined": "true", "tpu_min_device_slots": "1",
+                   "coalesce_writes": "true"})
+    role = protocol.roles["proxy_leader"]
+    proxy_leader = role.make(ctx, role.addresses(config)[0], 0)
+    assert ctx.unmatched_overrides() == [
+        "coalesce_writes", "tpu_min_device_slots", "tpu_pipelined"]
+    assert {"quorum_backend", "tpu_window"} <= ctx.consumed
+    assert not {"tpu_pipelined", "tpu_min_device_slots",
+                "tpu_flush_period_s"} & {
+        f.name for f in dataclasses.fields(ProxyLeaderOptions)}
+    assert type(proxy_leader.tracker) is TpuQuorumTracker
+    assert proxy_leader.tracker.checker.window == 256
+    # Built for a SimTransport: the flush timer, which collects.
+    assert proxy_leader._flush_timer is not None

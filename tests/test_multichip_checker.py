@@ -18,6 +18,7 @@ from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
     DictQuorumTracker,
     TpuQuorumTracker,
 )
+from tests.protocols.multipaxos_harness import drain_and_collect
 
 
 @pytest.fixture(autouse=True)
@@ -67,8 +68,20 @@ def replay(tracker, drains) -> list:
     for drain in drains:
         for slot, round, group, acceptor in drain:
             tracker.record(slot, round, group, acceptor)
-        out.append(sorted(tracker.drain()))
+        out.append(sorted(drain_and_collect(tracker)))
     return out
+
+
+def assert_board_sharded_over(tracker, mesh) -> None:
+    """The tracker's vote board is laid out over every device of
+    ``mesh``, each holding its share of the slot axis, and the tracker
+    launched kernels on it."""
+    votes = tracker.checker.board.votes
+    assert votes.sharding.device_set == set(mesh.devices.flat)
+    window = votes.shape[1]
+    assert {shard.data.shape for shard in votes.addressable_shards} \
+        == {(votes.shape[0], window // mesh.size)}
+    assert tracker.device_launches > 0
 
 
 def test_sharded_checker_matches_unsharded_on_real_stream(mesh_factory):
@@ -77,12 +90,16 @@ def test_sharded_checker_matches_unsharded_on_real_stream(mesh_factory):
     the unsharded board and the dict oracle."""
     config, drains = record_real_vote_stream()
     oracle = replay(DictQuorumTracker(config), drains)
-    unsharded = replay(TpuQuorumTracker(config, window=1 << 10), drains)
-    sharded = replay(
-        TpuQuorumTracker(config, window=1 << 10, mesh=mesh_factory(2, 4)), drains)
+    unsharded_tracker = TpuQuorumTracker(config, window=1 << 10)
+    unsharded = replay(unsharded_tracker, drains)
+    mesh = mesh_factory(2, 4)
+    sharded_tracker = TpuQuorumTracker(config, window=1 << 10, mesh=mesh)
+    sharded = replay(sharded_tracker, drains)
     assert unsharded == oracle
     assert sharded == oracle
     assert sum(len(d) for d in oracle) > 0
+    assert unsharded_tracker.device_launches >= len(drains)
+    assert_board_sharded_over(sharded_tracker, mesh)
 
 
 def test_sharded_checker_ring_wrap_on_mesh(mesh_factory):
@@ -91,7 +108,8 @@ def test_sharded_checker_ring_wrap_on_mesh(mesh_factory):
     config, _ = record_real_vote_stream(num_batches=1, inflight=1)
     window = 256
     oracle = DictQuorumTracker(config)
-    sharded = TpuQuorumTracker(config, window=window, mesh=mesh_factory(1, 8))
+    mesh = mesh_factory(1, 8)
+    sharded = TpuQuorumTracker(config, window=window, mesh=mesh)
     rng = random.Random(7)
     for base in range(0, 4 * window, 64):
         votes = []
@@ -102,4 +120,6 @@ def test_sharded_checker_ring_wrap_on_mesh(mesh_factory):
         for slot, acc in votes:
             oracle.record(slot, 0, 0, acc)
             sharded.record(slot, 0, 0, acc)
-        assert sorted(oracle.drain()) == sorted(sharded.drain()), base
+        assert sorted(oracle.drain()) \
+            == sorted(drain_and_collect(sharded)), base
+    assert_board_sharded_over(sharded, mesh)

@@ -763,15 +763,14 @@ class TestStageAccounting:
         assert events.count("fpx.loop-wait") == 1
 
 
-SERVED = {"quorum_backend": "tpu", "tpu_pipelined": "true",
-          "tpu_window": "4096", "coalesce_writes": "true"}
+SERVED = {"quorum_backend": "tpu", "tpu_window": "4096",
+          "coalesce_writes": "true"}
 
 
 @pytest.fixture(scope="class")
 def served():
     """The served MultiPaxos path at toy size, in-process over
-    TcpTransport: tpu tracker (CPU XLA here), pipelined, coalesced
-    writes."""
+    TcpTransport: tpu tracker (CPU XLA here), coalesced writes."""
     from tests.protocols.tcp_multipaxos import TcpMultiPaxos
 
     deployment = TcpMultiPaxos.launch(SERVED)
@@ -815,8 +814,7 @@ class TestServedPathStages:
         # once, waited once, collected once, on one clock pair with
         # the collect summary.
         drains = owner["multipaxos_proxy_leader_tpu_drains_total"]
-        had_votes = (drains.labels("device").get()
-                     + drains.labels("host").get())
+        had_votes = drains.labels("device").get()
         dispatched = owner[
             "multipaxos_proxy_leader_tpu_dispatches_total"].get()
         collect = owner["multipaxos_proxy_leader_tpu_collect_seconds"]
@@ -855,6 +853,30 @@ class TestServedPathStages:
         for label in (served.OWNER, "replica_0", "acceptor_0", "client",
                       "leader_0"):
             assert served.stage_counts(label)["flush"] > 0, label
+
+    def test_the_tracker_publishes_what_it_counts_and_one_path(
+            self, served):
+        """``_publish_tpu_counts``: the colocated trackers' drains, votes
+        and launches, summed, are what ``/metrics`` serves, every drain
+        launched, and ``device`` is the only child of ``path`` (no host
+        tally is left to publish one)."""
+        served.closed_loops(8, 5)
+        served.settle()
+        owner = served.collectors[served.OWNER].metrics
+        drains = owner["multipaxos_proxy_leader_tpu_drains_total"]
+        votes = owner["multipaxos_proxy_leader_tpu_votes_total"]
+        launches = owner["multipaxos_proxy_leader_tpu_launches_total"]
+        published, counted = served.on_loop(served.OWNER, lambda: (
+            (drains.labels("device").get(), votes.labels("device").get(),
+             launches.get()),
+            tuple(sum(getattr(proxy.tracker, name)
+                      for proxy in served.actors[served.OWNER])
+                  for name in ("device_drains", "device_votes",
+                               "device_launches"))))
+        assert published == counted
+        assert counted[2] >= counted[0] > 0 and counted[1] >= counted[0]
+        assert set(drains._children) == set(votes._children) == {
+            ("device",)}
 
     def test_the_handler_stage_and_the_role_summary_share_a_clock(
             self, served):

@@ -13,6 +13,7 @@ from frankenpaxos_tpu.ops.quorum import (
     TpuQuorumChecker,
 )
 from frankenpaxos_tpu.quorums import Grid, SimpleMajority, UnanimousWrites
+from tests.protocols.multipaxos_harness import drain_and_collect
 
 
 def test_check_batch_matches_oracle():
@@ -240,8 +241,8 @@ def test_window_violation_intra_batch_and_rejected_block():
 
 
 # --- fused grid kernel ------------------------------------------------------
-# _spec_statics tags grid specs so every kernel (check_block,
-# record_block, record_and_check, check_batch) swaps the generic mask
+# _spec_statics tags grid specs so every kernel (record_block,
+# record_and_check, check_batch) swaps the generic mask
 # matmul for the boolean reshape col-OR/row-AND (write) / col-AND/row-OR
 # (read) reduction. Bit-identity to the quorums/systems.py host oracle
 # is the contract.
@@ -288,7 +289,7 @@ def test_fused_grid_check_block_matches_oracle(qs):
         for width in (1, 7, 64, 100):
             block = (rng.random((spec.num_nodes, width)) < 0.5
                      ).astype(np.uint8)
-            got = checker.check_block(block)
+            got = checker.check_batch(block.T)
             np.testing.assert_array_equal(got, spec.evaluate(block.T),
                                           err_msg=f"{qs} {spec.combine}")
 
@@ -382,9 +383,8 @@ def _tracker_config(grid: bool):
 
 
 class _TrackerAndOracle:
-    """A pipelined ``TpuQuorumTracker`` fed vote for vote beside the dict
-    oracle; a drain collects at once, and says how many jitted calls it
-    made."""
+    """A ``TpuQuorumTracker`` fed vote for vote beside the dict oracle;
+    a drain collects at once, and says how many jitted calls it made."""
 
     def __init__(self, grid: bool, window: int = TRACKER_WINDOW):
         from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
@@ -393,8 +393,7 @@ class _TrackerAndOracle:
         )
 
         config = _tracker_config(grid)
-        self.tracker = TpuQuorumTracker(config, window=window,
-                                        pipelined=True)
+        self.tracker = TpuQuorumTracker(config, window=window)
         self.oracle = DictQuorumTracker(config)
         # A write quorum: f+1 of the one group, or one of each grid row.
         self.quorum = ((0, 0), (1, 0)) if grid else ((0, 0), (0, 1))
@@ -409,10 +408,7 @@ class _TrackerAndOracle:
         """Drain both, hold the tracker to the oracle's chosen list, and
         return the number of launches the drain made."""
         before = (self.tracker.device_drains, self.tracker.device_launches)
-        assert self.tracker.drain() == []
-        got = []
-        while (dispatch := self.tracker.take_dispatch()) is not None:
-            got.extend(self.tracker.collect(dispatch))
+        got = drain_and_collect(self.tracker)
         assert sorted(got) == sorted(self.oracle.drain())
         assert self.tracker.device_drains == before[0] + 1
         return self.tracker.device_launches - before[1]
@@ -465,9 +461,12 @@ def test_tracker_compiles_only_its_named_programs_and_none_after_prewarm(
             assert both.drain() == 1
     finally:
         logger.removeHandler(handler)
-    assert "fpx_quorum_record_block" in prewarm, prewarm
-    assert [name for name in prewarm
-            if not name.startswith("fpx_quorum_")] == []
+    # The board, four widths of record_block, two of the scatter, the
+    # release: eight, and the stateless check that went is not one.
+    assert sorted(prewarm) == sorted(
+        ["fpx_quorum_make_board"] + 4 * ["fpx_quorum_record_block"]
+        + 2 * ["fpx_quorum_record_votes"] + ["fpx_quorum_release"]), prewarm
+    assert "fpx_quorum_check_block" not in prewarm
     assert handler.names == []
 
 
