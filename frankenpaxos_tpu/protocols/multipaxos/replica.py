@@ -7,6 +7,14 @@ execution per client), chosen-watermark gossip every N entries with
 responsibility round-robin'd across replicas (Replica.scala:421-447), a
 randomized hole-recovery timer (Replica.scala:238-260), and deferred
 reads parked until their slot executes (Replica.scala:203-211,455-530).
+
+The log holds only what cannot be executed yet. A ``ChosenRun`` is
+walked once as plain rows (``LazyValueArray.rows``: no value object is
+made). One that reaches the executed watermark is executed straight
+from them and never enters the log; the rows of one above a hole are
+parked in the log, a slot each, and leave it once they have been
+executed. Nothing reads an executed entry: a slot under the watermark
+is a duplicate without a look at the log.
 """
 
 from __future__ import annotations
@@ -28,10 +36,10 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     ClientReplyArray,
     ClientReplyBatch,
     Command,
-    CommandBatch,
+    CommandId,
     EventualReadRequest,
     EventualReadRequestBatch,
-    Noop,
+    NOOP,
     ReadReply,
     ReadReplyBatch,
     ReadRequest,
@@ -47,8 +55,11 @@ from frankenpaxos_tpu.protocols.multipaxos.wire import (
     _take_bytes,
     decode_value_array,
     encode_value_array,
+    LazyValueArray,
+    row_value,
+    value_row,
 )
-from frankenpaxos_tpu.runs.records import log_chosen_values, wal_log_chosen_run
+from frankenpaxos_tpu.runs.records import log_chosen_values
 from frankenpaxos_tpu.runtime import Actor, Collectors, FakeCollectors, Logger
 from frankenpaxos_tpu.runtime.transport import Address, Transport
 from frankenpaxos_tpu.statemachine import StateMachine
@@ -107,6 +118,17 @@ class Replica(Actor, DurableRole):
         # executed_reads it gives reads a request message.
         self.metrics_read_messages = collectors.counter(
             "multipaxos_replica_read_messages_total")
+        # Which way a ChosenRun with something new went: executed from
+        # its columns ("direct") or parked in the log ("logged").
+        runs = collectors.counter("multipaxos_replica_runs_total",
+                                  labels=("path",))
+        self.metrics_runs_direct = runs.labels("direct")
+        self.metrics_runs_logged = runs.labels("logged")
+        # Entries the log holds after a run: what waits above a hole.
+        # Chosen entries above the executed watermark, and the log
+        # holds no other (the tests count the log beside it).
+        self.metrics_log_entries = collectors.gauge(
+            "multipaxos_replica_log_entries")
         self.index = list(config.replica_addresses).index(address)
         self.log: BufferMap = BufferMap(options.log_grow_size)
         # slot -> [(when it was parked, its commands)], one entry a
@@ -202,25 +224,29 @@ class Replica(Actor, DurableRole):
                 self.client_table = {}
                 self._restore_snapshot(record.payload)
             elif isinstance(record, WalChosenRun):
-                self._log_chosen(
-                    record.start_slot,
-                    decode_value_array(record.values))
+                # Re-execute the recovered records as they were taken
+                # (deterministic: same entries, same order). Replies
+                # are DISCARDED -- every reply the pre-crash replica
+                # sent was covered by a synced record, and unacked
+                # clients resend (the client table keeps re-execution
+                # exactly-once).
+                self._take_run(record.start_slot,
+                               decode_value_array(record.values), {},
+                               durable=False)
             else:
                 self.logger.fatal(
                     f"unexpected replica WAL record {record!r}")
-        # Re-execute the recovered contiguous prefix (deterministic:
-        # same entries, same order). Replies are DISCARDED -- every
-        # reply the pre-crash replica sent was covered by a synced
-        # record, and unacked clients resend (the client table keeps
-        # re-execution exactly-once).
-        self._execute_log()
 
-    def _log_chosen(self, start_slot: int, values) -> int:
-        """Put a contiguous run of chosen values into the log
-        (runs/records.py); returns how many were new. Shared by the
-        live handlers and WAL replay."""
+    def _park(self, start_slot: int, rows: list) -> int:
+        """Park the rows of a contiguous run of chosen values in the
+        log (runs/records.py), a slot each, until the slots before
+        them have executed; returns how many were new."""
+        # The direct path moves the watermark without a look at the
+        # log: catch the log up first, or a put sizes its buffer by
+        # the distance.
+        self.log.garbage_collect(self.executed_watermark)
         new, _ = log_chosen_values(self.log, self.executed_watermark,
-                                   start_slot, 1, values)
+                                   start_slot, 1, rows)
         self.num_chosen += new
         return new
 
@@ -230,10 +256,10 @@ class Replica(Actor, DurableRole):
         disk. Chosen-but-unexecuted entries above the watermark (holes
         pending) are re-logged after the snapshot marker."""
         records = []
-        for slot, value in self.log.items(start=self.executed_watermark):
+        for slot, row in self.log.items(start=self.executed_watermark):
             records.append(WalChosenRun(
                 start_slot=slot, stride=1,
-                values=encode_value_array((value,))))
+                values=encode_value_array((row_value(row),))))
         self.wal.compact(WalSnapshot(payload=self._snapshot_payload()),
                          records)
         self.log.garbage_collect(self.executed_watermark)
@@ -253,15 +279,15 @@ class Replica(Actor, DurableRole):
                 % self.options.send_chosen_watermark_every_n_entries
                 and self.executed_watermark % self.config.num_replicas
                 == self.index):
-            self._send_chosen_watermark()
+            self._send_chosen_watermark(self.executed_watermark)
         self._wm_dirty = False
         # GROUP COMMIT (DurableRole): one fsync covers every chosen
         # entry this drain logged; only then do the replies it
         # produced go out.
         self._wal_drain()
 
-    def _send_chosen_watermark(self) -> None:
-        watermark = ChosenWatermark(slot=self.executed_watermark)
+    def _send_chosen_watermark(self, slot: int) -> None:
+        watermark = ChosenWatermark(slot=slot)
         proxy = self._proxy_replica_address()
         if proxy is not None:
             self._wal_send(proxy, watermark)
@@ -288,51 +314,78 @@ class Replica(Actor, DurableRole):
                 self.send(leader, recover)
         self.recover_timer.start()
 
-    def _execute_command(self, slot: int, command: Command,
-                         replies: list[ClientReply]) -> None:
-        """Execute with exactly-once + reply-once-per-slot-owner semantics
-        (Replica.scala:300-344)."""
-        cid = command.command_id
-        key = (cid.client_address, cid.client_pseudonym)
-        cached = self.client_table.get(key)
-        if cached is not None:
-            largest_id, cached_result = cached
-            if cid.client_id < largest_id:
-                return
-            if cid.client_id == largest_id:
-                replies.append(ClientReply(cid, slot, cached_result))
-                return
-        result = self.state_machine.run(command.command)
-        if not self.options.unsafe_dont_use_client_table:
-            self.client_table[key] = (cid.client_id, result)
-        if slot % self.config.num_replicas == self.index:
-            replies.append(ClientReply(cid, slot, result))
-        self.metrics_executed.inc()
+    def _execute_rows(self, rows, replies: dict) -> None:
+        """Execute ``rows``, a slot each from the executed watermark
+        up: ``NOOP``, or the slot's commands as ``(client address,
+        pseudonym, client id, payload)`` (``LazyValueArray.rows``).
+        The one place a write is executed (Replica.scala:300-344,
+        394-453): exactly once through the client table, in slot
+        order, one ``state_machine.run`` a command. Reply entries are
+        appended to ``replies[client address]`` as ``ClientReplyArray``
+        carries them; reads parked at a slot are answered right after
+        it. What is the same for every slot is done once a call."""
+        table = self.client_table
+        keep = not self.options.unsafe_dont_use_client_table
+        run = self.state_machine.run
+        num_replicas = self.config.num_replicas
+        index = self.index
+        reads_parked = self._deferred_read_count > 0
+        first = slot = self.executed_watermark
+        executed = 0
+        for batch in rows:
+            if batch is not NOOP:
+                mine = slot % num_replicas == index
+                for address, pseudonym, client_id, payload in batch:
+                    key = (address, pseudonym)
+                    cached = table.get(key)
+                    if cached is None or client_id > cached[0]:
+                        result = run(payload)
+                        executed += 1
+                        if keep:
+                            table[key] = (client_id, result)
+                        if not mine:
+                            continue
+                    elif client_id == cached[0]:
+                        # A resend of the client's newest command:
+                        # answered from the table, by every replica.
+                        result = cached[1]
+                    else:
+                        continue
+                    entries = replies.get(address)
+                    if entries is None:
+                        entries = replies[address] = []
+                    entries.append((pseudonym, client_id, slot, result))
+            slot += 1
+            self.executed_watermark = slot
+            if reads_parked:
+                parked = self.deferred_reads.pop(slot - 1)
+                if parked is not None:
+                    self._process_deferred_reads(parked)
+        if slot == first:
+            return
+        self._wm_dirty = True
+        self.metrics_executed.inc(executed)
+        # Every boundary the rows crossed is announced, by the replica
+        # whose turn it is (Replica.scala:421-447).
+        every_n = self.options.send_chosen_watermark_every_n_entries
+        for boundary in range((first // every_n + 1) * every_n, slot + 1,
+                              every_n):
+            if (boundary // every_n) % num_replicas == index:
+                self._send_chosen_watermark(boundary)
 
-    def _execute_log(self) -> list[ClientReply]:
-        """Execute the contiguous chosen prefix (Replica.scala:394-453)."""
-        replies: list[ClientReply] = []
-        while True:
-            value = self.log.get(self.executed_watermark)
-            if value is None:
-                return replies
-            slot = self.executed_watermark
-            if isinstance(value, CommandBatch):
-                for command in value.commands:
-                    self._execute_command(slot, command, replies)
-            else:
-                assert isinstance(value, Noop)
-            parked = self.deferred_reads.pop(slot)
-            if parked is not None:
-                self._process_deferred_reads(parked)
-            self.executed_watermark += 1
-            self._wm_dirty = True
-
-            every_n = self.options.send_chosen_watermark_every_n_entries
-            if (self.executed_watermark % every_n == 0
-                    and (self.executed_watermark // every_n)
-                    % self.config.num_replicas == self.index):
-                self._send_chosen_watermark()
+    def _execute_log(self, replies: dict) -> None:
+        """Execute the contiguous chosen prefix the log holds, and
+        drop it from the log: nothing reads an executed entry."""
+        log = self.log
+        slot = self.executed_watermark
+        if log.largest_key < slot:
+            return  # nothing is parked
+        rows = []
+        while (row := log.get(slot)) is not None:
+            rows.append(row)
+            slot += 1
+        self._execute_rows(rows, replies)
+        log.garbage_collect(self.executed_watermark)
 
     def _execute_read(self, command: Command) -> ReadReply:
         result = self.state_machine.run(command.command)
@@ -349,7 +402,7 @@ class Replica(Actor, DurableRole):
             self.send(proxy, ReadReplyBatch(batch=tuple(replies)))
         else:
             # A batch is answered as a batch: one message a client
-            # address (as _reply_to_run groups a run's write replies).
+            # address (as _send_replies groups a run's write replies).
             by_client: dict = {}
             for reply in replies:
                 by_client.setdefault(reply.command_id.client_address,
@@ -510,67 +563,124 @@ class Replica(Actor, DurableRole):
             if admission is not None:
                 admission.set_inflight(self._deferred_read_count)
 
-    def _wal_log_chosen_run(self, start_slot: int, values,
-                            all_new: bool) -> None:
-        """Append the run's NEW entries to the WAL (runs/records.py):
-        all-new runs log the inbound lazy value array as ONE raw copy;
-        a partially-duplicate run falls back to per-new-slot records
-        (rare: a resend or post-failover overlap)."""
-        wal_log_chosen_run(self.wal, self.log.get, start_slot, 1, values,
-                           all_new=all_new, encode=encode_value_array)
+    def _wal_log_chosen_run(self, start_slot: int, values) -> None:
+        """Append a run to the WAL as it came: for the lazy value
+        array a run arrives in, ONE raw copy. Whole, the slots it
+        repeats among them (a resend, an overlap after a failover):
+        replay skips what is executed or parked as the live pass
+        does."""
+        self.wal.append(WalChosenRun(start_slot=start_slot, stride=1,
+                                     values=encode_value_array(values)))
 
     def _handle_chosen(self, src: Address, chosen: Chosen) -> None:
         """(Replica.scala:572-628). One command a message, so it opens
-        no stage of its own: its time is the transport's ``handler``."""
-        if self._log_chosen(chosen.slot, (chosen.value,)) == 0:
+        no stage of its own: its time is the transport's ``handler``.
+        Always through the log (recovery and the uncoalesced path)."""
+        if self._park(chosen.slot, [value_row(chosen.value)]) == 0:
             return  # duplicate Chosen
         if self.wal is not None:
-            self._wal_log_chosen_run(chosen.slot, (chosen.value,),
-                                     all_new=True)
-        replies = self._execute_log()
-        if replies:
-            proxy = self._proxy_replica_address()
-            if proxy is not None:
-                self._wal_send(proxy,
-                               ClientReplyBatch(batch=tuple(replies)))
-            else:
-                for reply in replies:
-                    self._wal_send(reply.command_id.client_address, reply)
+            self._wal_log_chosen_run(chosen.slot, (chosen.value,))
+        replies: dict = {}
+        self._execute_log(replies)
+        self._note_log_entries()
+        self._send_replies(replies, arrays=False)
         self._restart_recover_timer()
 
     def _handle_chosen_run(self, src: Address, run: ChosenRun) -> None:
-        """A contiguous drain of chosen values in one message: log the
-        whole run, execute once, and ship each client ONE reply array
-        for the drain instead of one ClientReply per command. Stages
-        ``log``, ``execute`` and ``reply``, one scope each a run."""
-        with self.trace_stage("log"):
-            new = self._log_chosen(run.start_slot, run.values)
-            if new == 0:
-                return
-            if self.wal is not None:
-                self._wal_log_chosen_run(
-                    run.start_slot, run.values,
-                    all_new=(new == len(run.values)))
-        with self.trace_stage("execute"):
-            replies = self._execute_log()
+        """A contiguous drain of chosen values in one message: take
+        the whole run, execute once, and ship each client ONE reply
+        array for the drain instead of one ClientReply per command.
+        Stages ``log``, ``execute`` and ``reply``, one scope each a
+        run."""
+        replies: dict = {}
+        if not self._take_run(run.start_slot, run.values, replies,
+                              durable=self.wal is not None):
+            return
         if replies:
             with self.trace_stage("reply"):
-                self._reply_to_run(replies)
+                self._send_replies(replies, arrays=True)
         self._restart_recover_timer()
 
-    def _reply_to_run(self, replies: list) -> None:
-        proxy = self._proxy_replica_address()
-        if proxy is not None:
-            self._wal_send(proxy, ClientReplyBatch(batch=tuple(replies)))
+    def _take_run(self, start_slot: int, values, replies: dict,
+                  durable: bool) -> bool:
+        """Execute what a run of chosen values lets execute; False for
+        a run that held nothing new. Stage ``log``: the one walk over
+        the encoded array (no value object is made), the WAL's raw
+        copy, and whatever enters the log. Which way the rows go is
+        decided by where the run lies: one that reaches the executed
+        watermark, with nothing parked in the slots it brings, is
+        executed from them at once ("direct"); one above a hole, or
+        one that meets parked entries, is parked a slot each
+        ("logged"). Stage ``execute``: the pass, and then whatever
+        the log holds in order, which leaves the log. Shared by the
+        live handler and WAL replay (``durable=False``: the records
+        are in the WAL already)."""
+        watermark = self.executed_watermark
+        end = start_slot + len(values)
+        if end <= watermark:
+            return False  # a resend of what has been executed
+        with self.trace_stage("log"):
+            if not isinstance(values, LazyValueArray):
+                values = decode_value_array(encode_value_array(values))
+            # All of it before any of it is executed: a corrupt array
+            # raises here, and nothing has changed.
+            rows = list(values.rows())
+            direct = (start_slot <= watermark
+                      and not self._parked_in(watermark, end))
+            if direct:
+                del rows[:watermark - start_slot]
+                self.num_chosen += end - watermark
+            elif self._park(start_slot, rows) == 0:
+                return False
+            if durable:
+                self._wal_log_chosen_run(start_slot, values)
+        (self.metrics_runs_direct if direct
+         else self.metrics_runs_logged).inc()
+        with self.trace_stage("execute"):
+            if direct:
+                self._execute_rows(rows, replies)
+            # What was parked above a hole that is now filled, if any.
+            self._execute_log(replies)
+        self._note_log_entries()
+        return True
+
+    def _parked_in(self, start: int, end: int) -> bool:
+        """Whether the log holds an entry in ``[start, end)``; one
+        comparison while nothing is parked at all."""
+        log = self.log
+        return log.largest_key >= start and any(
+            log.get(slot) is not None
+            for slot in range(start, min(end, log.largest_key + 1)))
+
+    def _note_log_entries(self) -> None:
+        # Chosen and not executed is what the log holds, since an
+        # executed entry leaves it: reckoned, because a count of the
+        # log a run would cost the more the longer a hole stays open.
+        self.metrics_log_entries.set(
+            self.num_chosen - self.executed_watermark)
+
+    def _send_replies(self, replies: dict, arrays: bool) -> None:
+        """Send what ``_execute_rows`` gathered: to a proxy replica as
+        one ``ClientReplyBatch``, else to each client address its
+        entries, as one ``ClientReplyArray`` (a run) or a
+        ``ClientReply`` each (a ``Chosen``)."""
+        if not replies:
             return
-        by_client: dict = {}
-        for r in replies:
-            cid = r.command_id
-            by_client.setdefault(cid.client_address, []).append(
-                (cid.client_pseudonym, cid.client_id, r.slot, r.result))
-        for address, entries in by_client.items():
-            self._wal_send(address,
-                           ClientReplyArray(entries=tuple(entries)))
+        proxy = self._proxy_replica_address()
+        if proxy is None and arrays:
+            for address, entries in replies.items():
+                self._wal_send(address,
+                               ClientReplyArray(entries=tuple(entries)))
+            return
+        batch = [ClientReply(CommandId(address, pseudonym, client_id),
+                             slot, result)
+                 for address, entries in replies.items()
+                 for pseudonym, client_id, slot, result in entries]
+        if proxy is not None:
+            self._wal_send(proxy, ClientReplyBatch(batch=tuple(batch)))
+        else:
+            for reply in batch:
+                self._wal_send(reply.command_id.client_address, reply)
 
     def _restart_recover_timer(self) -> None:
         # Recover timer runs only while there are unexecuted chosen slots

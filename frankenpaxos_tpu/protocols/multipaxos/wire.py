@@ -343,6 +343,8 @@ class Phase2bVotesCodec(MessageCodec):
 # re-encoding a lazy array is a raw bytes copy.
 
 _CMD_ENTRY = struct.Struct("<iqq")  # address index, pseudonym, client id
+# The same entry and the length of the payload behind it, in one unpack.
+_CMD_ROW = struct.Struct("<iqqi")
 
 
 class LazyValueArray:
@@ -376,6 +378,41 @@ class LazyValueArray:
     def __len__(self) -> int:
         return self.n
 
+    def rows(self):
+        """Walk the segment once and yield, a slot, its batch as plain
+        values: a list of ``(client address, pseudonym, client id,
+        payload)``, one a command, or ``NOOP``. Nothing is cached and
+        no ``Command`` is made: this is how a replica reads a run it
+        executes at once. A corrupt segment raises ``ValueError`` as
+        ``_decode`` does, and so does one that ends before or after
+        its last value."""
+        raw = self.raw
+        try:
+            addresses, at = _take_address_table(raw, 0)
+            unpack_row = _CMD_ROW.unpack_from
+            for _ in range(self.n):
+                if raw[at] == 0:
+                    at += 1
+                    yield NOOP
+                    continue
+                (k,) = _I32.unpack_from(raw, at + 1)
+                at += 5
+                batch = []
+                for _ in range(k):
+                    idx, pseudonym, id, size = unpack_row(raw, at)
+                    at += 24
+                    batch.append((addresses[idx], pseudonym, id,
+                                  raw[at:at + size]))
+                    at += size
+                yield batch
+        except (struct.error, IndexError, KeyError,
+                UnicodeDecodeError, OverflowError, MemoryError) as e:
+            raise ValueError(
+                f"corrupt value array (n={self.n}): {e}") from e
+        if at != len(raw):
+            raise ValueError(f"corrupt value array (n={self.n}): values "
+                             f"end at byte {at} of {len(raw)}")
+
     def __iter__(self):
         return iter(self._decode())
 
@@ -391,6 +428,23 @@ class LazyValueArray:
 
     def __repr__(self) -> str:
         return f"LazyValueArray(n={self.n})"
+
+
+def value_row(value):
+    """One decoded value as ``LazyValueArray.rows`` would yield it."""
+    if isinstance(value, Noop):
+        return NOOP
+    return [(c.command_id.client_address, c.command_id.client_pseudonym,
+             c.command_id.client_id, c.command) for c in value.commands]
+
+
+def row_value(row):
+    """The value a row stands for: ``value_row``'s inverse."""
+    if row is NOOP:
+        return NOOP
+    return CommandBatch(tuple(
+        Command(CommandId(address, pseudonym, id), payload)
+        for address, pseudonym, id, payload in row))
 
 
 def _put_value_array(out: bytearray, values) -> None:
@@ -450,13 +504,19 @@ def _take_value_array(buf: bytes, at: int) -> tuple:
     return LazyValueArray(buf[at:at + nbytes], n), at + nbytes
 
 
-def _parse_value_array(buf: bytes, at: int, n: int) -> tuple:
+def _take_address_table(buf: bytes, at: int) -> tuple:
+    """-> (the array's deduplicated client addresses, next offset)."""
     (t,) = _I32.unpack_from(buf, at)
     at += 4
     addresses = []
     for _ in range(t):
         address, at = _take_address(buf, at)
         addresses.append(address)
+    return addresses, at
+
+
+def _parse_value_array(buf: bytes, at: int, n: int) -> tuple:
+    addresses, at = _take_address_table(buf, at)
     values = []
     for _ in range(n):
         kind = buf[at]

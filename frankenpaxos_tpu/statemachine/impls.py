@@ -178,9 +178,17 @@ class KeyValueStore(TypedStateMachine[KeyValueStoreInput, object]):
 
     def __init__(self):
         self.kvs: dict[str, str] = {}
+        # Every set is answered with the same bytes: encoded once.
+        self._set_reply_bytes = self.output_serializer.to_bytes(SetReply())
 
     def __repr__(self):
         return f"KeyValueStore({self.kvs!r})"
+
+    def run(self, input: bytes) -> bytes:
+        output = self.typed_run(self.input_serializer.from_bytes(input))
+        if isinstance(output, SetReply):
+            return self._set_reply_bytes
+        return self.output_serializer.to_bytes(output)
 
     def get(self) -> dict[str, str]:
         return dict(self.kvs)

@@ -27,6 +27,12 @@ from frankenpaxos_tpu.protocols.multipaxos import (
     Replica,
     ReplicaOptions,
 )
+from frankenpaxos_tpu.protocols.multipaxos.messages import (
+    Chosen,
+    ChosenRun,
+    CommandBatch,
+)
+from frankenpaxos_tpu.protocols.multipaxos.wire import LazyValueArray
 from frankenpaxos_tpu.runtime import FakeLogger, LogLevel, SimTransport
 from frankenpaxos_tpu.statemachine import AppendLog, StateMachine
 
@@ -139,6 +145,10 @@ def crash_restart_replica(sim: "MultiPaxosSim", i: int) -> None:
         old.address, sim.transport, sim.transport.logger,
         sim.state_machine_factory(), sim.config, old.options,
         seed=sim.seed + 20 + i, wal=_sim_wal(sim, old.address))
+    # What the address was handed outlives the crash; what its state
+    # machine ran starts again with the recovery, which is not seen.
+    record_execution(sim.replicas[i], ExecutionRecord(
+        chosen=old.execution_record.chosen, complete=False))
 
 
 def make_multipaxos(
@@ -259,6 +269,8 @@ def make_multipaxos(
                 ReplicaOptions(send_chosen_watermark_every_n_entries=10),
                 seed=seed + 20 + i, wal=wal_for(a))
         for i, a in enumerate(config.replica_addresses)]
+    for replica in replicas:
+        record_execution(replica, ExecutionRecord())
     proxy_replicas = [
         ProxyReplica(a, transport, logger, config)
         for a in config.proxy_replica_addresses]
@@ -292,10 +304,101 @@ def make_multipaxos(
                          seed=seed)
 
 
+@dataclasses.dataclass
+class ExecutionRecord:
+    """What one replica was handed and what its state machine ran,
+    kept outside the replica: its log holds only what it cannot
+    execute yet, so the tests' view of an executed slot is theirs."""
+
+    # slot -> the value the address was first handed for it.
+    chosen: dict = dataclasses.field(default_factory=dict)
+    # (slot, first value, later value) of a slot handed two values.
+    conflicts: list = dataclasses.field(default_factory=list)
+    # (slot, payload) a write the state machine ran, in order.
+    ran: list = dataclasses.field(default_factory=list)
+    # False once ``ran`` misses a part (a recovery from the WAL).
+    complete: bool = True
+    reading: bool = False
+
+    def hand(self, start_slot: int, values) -> None:
+        for slot, value in enumerate(values, start_slot):
+            first = self.chosen.setdefault(slot, value)
+            if first != value:
+                self.conflicts.append((slot, first, value))
+
+
+def record_execution(replica: Replica, record: ExecutionRecord) -> None:
+    """Put ``record`` round ``replica``: every Chosen / ChosenRun it
+    is delivered (decoded by the recorder's own copy of the array, so
+    the replica's stays as it came), and every write its state machine
+    runs with the slot it ran in (``executed_watermark`` is the slot
+    under execution). Reads run the state machine too and are left
+    out."""
+    receive = replica.receive
+    execute_read = replica._execute_read
+    run = replica.state_machine.run
+
+    def receiving(src, message):
+        if isinstance(message, Chosen):
+            record.hand(message.slot, (message.value,))
+        elif isinstance(message, ChosenRun):
+            values = message.values
+            if isinstance(values, LazyValueArray):
+                values = LazyValueArray(values.raw, values.n)
+            record.hand(message.start_slot, values)
+        receive(src, message)
+
+    def reading(command):
+        record.reading = True
+        try:
+            return execute_read(command)
+        finally:
+            record.reading = False
+
+    def running(input):
+        if not record.reading:
+            record.ran.append((replica.executed_watermark, input))
+        return run(input)
+
+    replica.receive = receiving
+    replica._execute_read = reading
+    replica.state_machine.run = running
+    replica.execution_record = record
+
+
+def exactly_once(values, use_client_table: bool = True) -> list:
+    """The (slot, payload)s a replica has to run for ``values``, a
+    value a slot from 0: every command in slot order, but one whose
+    client has had a command with its id or a later one executed."""
+    largest: dict = {}
+    ran = []
+    for slot, value in enumerate(values):
+        if not isinstance(value, CommandBatch):
+            continue
+        for command in value.commands:
+            cid = command.command_id
+            key = (cid.client_address, cid.client_pseudonym)
+            if key in largest and cid.client_id <= largest[key]:
+                continue
+            if use_client_table:
+                largest[key] = cid.client_id
+            ran.append((slot, command.command))
+    return ran
+
+
 def executed_prefix(replica: Replica) -> list:
-    """The replica's executed log prefix as a list of values."""
-    return [replica.log.get(slot)
-            for slot in range(replica.executed_watermark)]
+    """The values of the slots the replica has executed, as it was
+    handed them; checked on the way: no slot was handed two values,
+    and the state machine ran exactly what these values hold, in slot
+    order, each command once."""
+    record = replica.execution_record
+    assert not record.conflicts, record.conflicts
+    values = [record.chosen.get(slot)
+              for slot in range(replica.executed_watermark)]
+    if record.complete:
+        assert record.ran == exactly_once(
+            values, not replica.options.unsafe_dont_use_client_table)
+    return values
 
 
 def state_machine_of(sim: MultiPaxosSim, i: int) -> StateMachine:

@@ -146,6 +146,7 @@ class TestCrashRestartIntegration:
         replica = sim.replicas[0]
         assert replica.wal.metrics.compactions >= 1
         assert replica.log.watermark > 0  # watermark GC reached disk
+        assert not list(replica.log.items())  # nothing executed stays
 
         sm_before = replica.state_machine.get()
         crash_restart_replica(sim, 0)
@@ -336,7 +337,14 @@ class MultiPaxosWalSimulated(SimulatedSystem):
         # waiting for executions to diverge.
         logs: dict = {}
         for i, r in enumerate(sim.replicas):
-            for slot, value in r.log.items():
+            # Every value the address was ever handed, across its
+            # restarts (the harness's record: a replica's own log
+            # holds only what it has not executed yet).
+            record = r.execution_record
+            if record.conflicts:
+                return (f"replica {i} was handed two values for a "
+                        f"slot: {record.conflicts!r}")
+            for slot, value in record.chosen.items():
                 prev = logs.get(slot)
                 if prev is not None and prev[1] != value:
                     return (f"slot {slot} chosen twice: replica "
