@@ -243,6 +243,58 @@ class _StageThread:
             self.annotation = annotate if annotate.is_enabled() else None
 
 
+class _WalSeries:
+    """A write-ahead log's own counts (wal.WalMetrics) as series. The
+    log counts in plain ints as it works; ``publish`` is called once a
+    drain, after the group commit, and adds what each count grew by:
+    no series is touched per record."""
+
+    def __init__(self, collectors, role: str):
+        self._bytes = collectors.counter(
+            "fpx_runtime_wal_synced_bytes_total",
+            help="Bytes made durable by WAL group commits and "
+                 "compactions",
+            labels=("role",)).labels(role)
+        self._records = collectors.counter(
+            "fpx_runtime_wal_synced_records_total",
+            help="Records made durable by WAL group commits and "
+                 "compactions",
+            labels=("role",)).labels(role)
+        self._compactions = collectors.counter(
+            "fpx_runtime_wal_compactions_total",
+            help="WAL compactions (the live state re-logged, older "
+                 "segments deleted)",
+            labels=("role",)).labels(role)
+        self._recovered = collectors.counter(
+            "fpx_runtime_wal_recovered_records_total",
+            help="Records replayed from the WAL when the role started",
+            labels=("role",)).labels(role)
+        self._compaction_s = collectors.summary(
+            "fpx_runtime_wal_compaction_seconds",
+            help="Duration of one WAL compaction, on the event loop",
+            labels=("role",)).labels(role)
+        self._seen = (0, 0, 0)
+
+    def publish(self, counts) -> None:
+        """``counts``: the log's WalMetrics, as of now."""
+        now = (counts.bytes_synced, counts.records_synced,
+               counts.compactions)
+        seen = self._seen
+        if now == seen:
+            return
+        self._seen = now
+        self._bytes.inc(now[0] - seen[0])
+        self._records.inc(now[1] - seen[1])
+        if now[2] != seen[2]:
+            self._compactions.inc(now[2] - seen[2])
+
+    def compacted(self, dur_s: float) -> None:
+        self._compaction_s.observe(dur_s)
+
+    def recovered(self, records: int) -> None:
+        self._recovered.inc(records)
+
+
 class RuntimeMetrics:
     """The drain-granular runtime metrics every role exports when the
     metrics endpoint is on (with or without tracing): per-stage self
@@ -264,6 +316,7 @@ class RuntimeMetrics:
         annotations (_Stage). No other process imports JAX for it."""
         self.role = role
         self.clock = clock
+        self._collectors = collectors
         annotate = None
         if device_clock:
             from jax.profiler import TraceAnnotation as annotate
@@ -568,6 +621,14 @@ class RuntimeMetrics:
 
     def observe_batch(self, depth: int) -> None:
         self._depth_gauge.set(depth)
+
+    # --- paxlog (wal/) --------------------------------------------------
+    def wal_series(self) -> "_WalSeries":
+        """The series of one role's write-ahead log, made when a role
+        that has a log asks (DurableRole): a role without one exports
+        none of them. Colocated roles share the families and each
+        keeps its own last reading."""
+        return _WalSeries(self._collectors, self.role)
 
     # --- paxload admission/backpressure (serve/) ------------------------
     def admission_admitted(self, n: int = 1) -> None:

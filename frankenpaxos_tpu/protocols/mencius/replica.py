@@ -92,7 +92,7 @@ class MenciusReplica(Actor, DurableRole):
         self._wal_init(wal)
         self.recover_timer = None
         if wal is not None:
-            self._recover_from_wal()
+            self._wal_recover()
         if not unsafe_dont_recover:
             self.recover_timer = self.timer(
                 "recover",

@@ -1251,7 +1251,7 @@ class MenciusAcceptor(Actor, DurableRole):
         # on_drain's single fsync releases it (DurableRole).
         self._wal_init(wal)
         if wal is not None:
-            self._recover_from_wal()
+            self._wal_recover()
 
     # --- durability -------------------------------------------------------
     def _recover_from_wal(self) -> None:

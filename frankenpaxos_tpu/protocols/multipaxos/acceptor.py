@@ -117,7 +117,7 @@ class Acceptor(Actor, DurableRole):
         # the reference's in-memory behavior.
         self._wal_init(wal)
         if wal is not None:
-            self._recover_from_wal()
+            self._wal_recover()
 
     # --- durability -------------------------------------------------------
     def _recover_from_wal(self) -> None:

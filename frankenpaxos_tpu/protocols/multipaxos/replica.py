@@ -165,7 +165,7 @@ class Replica(Actor, DurableRole):
             transport.note_admission(address, self)
         self.recover_timer = None
         if wal is not None:
-            self._recover_from_wal()
+            self._wal_recover()
         if not options.unsafe_dont_recover:
             self.recover_timer = self.timer(
                 "recover",

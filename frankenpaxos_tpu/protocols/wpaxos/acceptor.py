@@ -66,7 +66,7 @@ class WPaxosAcceptor(Actor, DurableRole):
                                        config.initial_home)
         self._wal_init(wal)
         if wal is not None:
-            self._recover_from_wal()
+            self._wal_recover()
 
     # --- durability ---------------------------------------------------------
     def _recover_from_wal(self) -> None:

@@ -815,7 +815,7 @@ class TcpTransport(Transport):
     async def _connect(self, conn: _Conn, dst: Address) -> None:
         host, port = dst
         try:
-            _, writer = await asyncio.open_connection(host, port)
+            reader, writer = await asyncio.open_connection(host, port)
         except OSError as e:
             self.logger.warn(f"connect to {dst} failed: {e}; "
                              f"dropping {len(conn.pending)} pending")
@@ -825,7 +825,30 @@ class TcpTransport(Transport):
             return
         conn.writer = writer
         conn.connecting = False
+        self.loop.create_task(self._watch_peer(conn, reader, writer))
         self._flush_conn(conn)
+
+    async def _watch_peer(self, conn: _Conn, reader: asyncio.StreamReader,
+                          writer: asyncio.StreamWriter) -> None:
+        """Drop an outbound connection the moment its peer closes it.
+        A peer sends nothing on a connection it accepted (it answers
+        on one of its own), so a read here returns only at the
+        connection's end: the peer exited, or was killed and the
+        kernel closed its sockets. Without this the loss is found at
+        the next WRITE, which is the write that is lost; when every
+        peer of a kind restarts at once (a storage tier recovering
+        from its logs) no survivor covers it, and the first Phase2a
+        to each restarted acceptor vanished into its dead socket,
+        leaving holes that only a leader change filled. Dropped here,
+        the next send finds no writer and connects anew (_write)."""
+        try:
+            while await reader.read(65536):
+                pass
+        except (OSError, asyncio.IncompleteReadError):
+            pass
+        if conn.writer is writer:
+            conn.writer = None
+        writer.close()
 
     def _flush_conn(self, conn: _Conn) -> None:
         if conn.writer is None or not conn.pending:

@@ -74,7 +74,7 @@ def runtime_row_panels(y: int = 0) -> list:
         9003, "WAL fsync latency p99 / mean",
         "histogram_quantile(0.99, sum by (le) "
         "(rate(fpx_runtime_wal_fsync_seconds_bucket[5s])))",
-        "p99", "s", x=16, y=y + 1, w=8)
+        "p99", "s", x=12, y=y + 1, w=6)
     # The fsync panel charts the p99 AND the mean on one graph.
     fsync["targets"].append({
         "expr": ("sum(rate(fpx_runtime_wal_fsync_seconds_sum[5s])) / "
@@ -82,6 +82,25 @@ def runtime_row_panels(y: int = 0) -> list:
         "legendFormat": "mean",
         "refId": "B",
     })
+    # The log's own counts (wal.WalMetrics through obs._WalSeries):
+    # only roles that have a WAL export them.
+    wal_counts = _panel(
+        9026, "WAL: synced bytes/s, records/s, compactions",
+        "sum by (role) (rate(fpx_runtime_wal_synced_bytes_total[5s]))",
+        "bytes {{role}}", "Bps", x=18, y=y + 1, w=6,
+        extra=[
+            ("sum by (role) "
+             "(rate(fpx_runtime_wal_synced_records_total[5s]))",
+             "records {{role}}"),
+            ("sum by (role) "
+             "(rate(fpx_runtime_wal_compactions_total[5s]))",
+             "compactions {{role}}"),
+            ("sum by (role) "
+             "(rate(fpx_runtime_wal_compaction_seconds_sum[5s]))",
+             "compacting s/s {{role}}"),
+            ("fpx_runtime_wal_recovered_records_total",
+             "recovered at start {{role}}"),
+        ])
     admitted = _panel(
         9004, "Admission: admitted vs rejected",
         "sum by (role) "
@@ -153,12 +172,13 @@ def runtime_row_panels(y: int = 0) -> list:
             9001, "Drain-stage time share",
             "sum by (stage) "
             "(rate(fpx_runtime_drain_stage_seconds_sum[5s]))",
-            "{{stage}}", "s", x=0, y=y + 1, w=8),
+            "{{stage}}", "s", x=0, y=y + 1, w=6),
         _panel(
             9002, "Inbound queue depth (msgs/drain)",
             "fpx_runtime_inbound_queue_depth",
-            "{{role}}", "short", x=8, y=y + 1, w=8),
+            "{{role}}", "short", x=6, y=y + 1, w=6),
         fsync,
+        wal_counts,
         admitted,
         reasons,
         depth,

@@ -916,3 +916,91 @@ class TestServedPathStages:
         finally:
             gc.callbacks.remove(metrics._on_gc)
         assert served.stage_counts("replica_0")["gc"] > before
+
+
+class TestWalSeries:
+    """A write-ahead log's own counts and its two stages beside
+    wal-fsync: on /metrics of a role that has a log, and absent from one
+    that has none (obs._WalSeries, wal.DurableRole)."""
+
+    SERIES = ("fpx_runtime_wal_synced_bytes_total",
+              "fpx_runtime_wal_synced_records_total",
+              "fpx_runtime_wal_compactions_total",
+              "fpx_runtime_wal_compaction_seconds",
+              "fpx_runtime_wal_recovered_records_total")
+    STAGES = "fpx_runtime_drain_stage_seconds"
+
+    def served(self, wal: bool, writes: int = 1):
+        from tests.protocols.multipaxos_harness import make_multipaxos
+
+        sim = make_multipaxos(f=1, coalesced=True, wal=wal)
+        collectors = FakeCollectors()
+        sim.transport.runtime_metrics = RuntimeMetrics(collectors, "sim")
+        results: list = []
+        for n in range(writes):
+            sim.clients[0].write(0, b"cmd %d" % n, results.append)
+            sim.clients[0].flush_writes()
+            sim.transport.deliver_all_coalesced()
+        assert len(results) == writes
+        return sim, collectors
+
+    def test_a_role_with_a_log_exports_its_counts(self):
+        sim, collectors = self.served(wal=True)
+        synced = sum(role.wal.metrics.bytes_synced
+                     for role in (*sim.acceptors, *sim.replicas))
+        records = sum(role.wal.metrics.records_synced
+                      for role in (*sim.acceptors, *sim.replicas))
+        assert synced > 0 and records > 0
+        read = {name: collectors.metrics[name].labels("sim")
+                for name in self.SERIES}
+        assert read[self.SERIES[0]].get() == synced
+        assert read[self.SERIES[1]].get() == records
+        assert read[self.SERIES[2]].get() == 0
+        stages = collectors.metrics[self.STAGES]
+        # Every drain of a role with a log passes the check for a due
+        # compaction; none recovered anything.
+        assert stages.labels("sim", "wal-compact").get_count() > 0
+        assert stages.labels("sim", "wal-recover").get_count() == 0
+
+    def test_a_role_without_a_log_exports_none_of_them(self):
+        _, collectors = self.served(wal=False)
+        assert not set(self.SERIES) & set(collectors.metrics)
+        stages = collectors.metrics[self.STAGES].read()
+        assert stages and not {
+            ("sim", "wal-compact"), ("sim", "wal-recover"),
+            ("sim", "wal-fsync")} & set(stages)
+
+    def test_a_compaction_is_counted_and_timed(self):
+        sim, collectors = self.served(wal=True)
+        roles = (*sim.acceptors, *sim.replicas)
+        for role in roles:
+            role.wal.compact_every_bytes = 1
+        results: list = []
+        sim.clients[0].write(0, b"one more", results.append)
+        sim.clients[0].flush_writes()
+        sim.transport.deliver_all_coalesced()
+        done = sum(role.wal.metrics.compactions for role in roles)
+        assert results and done > 0
+        assert collectors.metrics[self.SERIES[2]].labels("sim").get() == done
+        assert collectors.metrics[self.SERIES[3]].labels(
+            "sim").get_count() == done
+        # What a compaction wrote is in the synced bytes too.
+        assert collectors.metrics[self.SERIES[0]].labels("sim").get() == sum(
+            role.wal.metrics.bytes_synced for role in roles)
+
+    def test_recovery_is_a_stage_and_a_count(self):
+        from tests.protocols.multipaxos_harness import (
+            crash_restart_acceptor,
+            crash_restart_replica,
+        )
+
+        sim, collectors = self.served(wal=True, writes=3)
+        crash_restart_acceptor(sim, 0)
+        crash_restart_replica(sim, 0)
+        replayed = (sim.acceptors[0].wal.metrics.recovered_records
+                    + sim.replicas[0].wal.metrics.recovered_records)
+        assert replayed > 0
+        assert collectors.metrics[self.SERIES[4]].labels(
+            "sim").get() == replayed
+        stages = collectors.metrics[self.STAGES]
+        assert stages.labels("sim", "wal-recover").get_count() == 2

@@ -352,3 +352,34 @@ def test_the_loops_selector_times_every_wait_as_loop_wait():
         assert clock.now == 8.0
     finally:
         selector.close()
+
+
+def test_a_peer_that_closed_is_dropped_before_the_next_send(transports):
+    """An outbound connection whose peer went away is dropped when the
+    peer closes it, not at the next write, so that the first message to
+    the peer's next life is not the one that finds the loss (when a whole
+    tier restarts at once no survivor covers that message)."""
+    server_addr = ("127.0.0.1", free_port())
+    client_addr = ("127.0.0.1", free_port())
+    client_t = transports(client_addr)
+    logger = FakeLogger()
+    client = EchoClient(client_addr, client_t, logger, server_addr)
+    got: list = []
+
+    def life(word: str) -> TcpTransport:
+        server_t = TcpTransport(server_addr, FakeLogger())
+        server_t.start()
+        EchoServer(server_addr, server_t, logger)
+        client_t.loop.call_soon_threadsafe(client.echo, word, got.append)
+        assert wait_for(lambda: word in got), got
+        return server_t
+
+    first = life("to the first life")
+    conn = client_t._conn_for(client_addr, server_addr)
+    assert conn.writer is not None
+    first.stop()
+    # Nothing is sent meanwhile: the close alone drops the connection.
+    assert wait_for(lambda: conn.writer is None)
+    life("to the second life").stop()
+    assert got == ["to the first life", "to the second life"]
+    assert not any("write failed" in m for _, m in logger.records)
