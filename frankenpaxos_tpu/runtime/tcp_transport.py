@@ -804,7 +804,18 @@ class TcpTransport(Transport):
 
     def _flush_pass(self) -> None:
         self._flush_scheduled = False
+        self.flush_sends()
+
+    def flush_sends(self) -> None:
+        """Write now what this loop pass has sent so far (on the
+        loop's thread). The end-of-pass flush calls it; a durable role
+        calls it before a compaction, so that the acks its drain
+        released are not held in ``pending`` through the rewrite
+        (wal/role.py). A pass's later sends ride the flush that is
+        already scheduled."""
         queue, self._flush_queue = self._flush_queue, []
+        if not queue:
+            return
         self._flush_dirty.clear()
         # Stage ``flush``: coalesce + encode (plan_flush) and writev of
         # everything this loop pass sent, one scope a pass.

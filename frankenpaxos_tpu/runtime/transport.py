@@ -101,6 +101,13 @@ class Transport(abc.ABC):
             self.send_no_flush(src, dst, data)
         self.flush(src, dst)
 
+    def flush_sends(self) -> None:
+        """Put on the wire now whatever ``send`` has accepted and not
+        yet written. Default: nothing to do, a ``send`` hands over at
+        once (SimTransport); TcpTransport, which writes once at the
+        end of a loop pass, overrides it. Called by a role about to
+        block its loop (a WAL compaction, wal/role.py)."""
+
     @abc.abstractmethod
     def timer(self, address: Address, name: str, delay_s: float,
               f: Callable[[], None]) -> Timer:

@@ -21,9 +21,28 @@ SEGMENTS & COMPACTION: records append to ``seg-<n>.wal``; when the live
 segment exceeds ``segment_bytes`` the next sync rotates to a fresh one.
 ``compact(records)`` writes a WalSnapshot marker + the re-logged live
 state as the first records of a NEW segment (one fsync), then deletes
-every older segment -- roles trigger it from the same watermark GC
-that bounds their in-memory state, so the log on disk stays O(live
-state), not O(history).
+every older segment, so the log on disk holds the role's live state
+and what was appended since, not its history.
+
+WHEN A COMPACTION IS DUE (``wants_compaction``): when the log has
+DOUBLED -- the bytes synced since the last compaction have reached
+what that compaction wrote (marker + re-logged state; after
+``recover()``, what recovery read) -- and never before
+``compact_every_bytes`` of growth. A role whose compaction keeps
+almost nothing (a replica: one snapshot) therefore compacts every
+``compact_every_bytes``; a role whose compaction keeps everything (an
+acceptor forgets no vote, so its live state IS its history) compacts
+at 1x, 2x, 4x, 8x ... ``compact_every_bytes``. A fixed threshold made
+that second role rewrite its whole history every
+``compact_every_bytes``: bytes rewritten grew with the SQUARE of the
+log. Doubling gives two bounds over any history: the bytes all
+compactions wrote stay under TWICE the bytes appended (each
+compaction writes at most what the last one kept plus the growth
+since, and the growth is at least what the last one kept), and between
+compactions the disk holds at most twice what the last compaction kept
+plus ``compact_every_bytes`` (plus the one drain that crossed the
+line; while a compaction runs the old segments lie beside the new one
+until its fsync has returned).
 """
 
 from __future__ import annotations
@@ -169,6 +188,10 @@ class Wal:
         self._buf = bytearray()
         self._buf_records = 0
         self._bytes_since_compact = 0
+        # What the last compaction wrote (after recover(): what
+        # recovery read): the size the log must grow by again before
+        # the next one is due.
+        self._bytes_last_compact = 0
         segments = storage.segments()
         if segments:
             self._seg_index = int(segments[-1][4:-4])
@@ -209,7 +232,11 @@ class Wal:
         self._segment = f"seg-{self._seg_index:08d}.wal"
 
     def wants_compaction(self) -> bool:
-        return self._bytes_since_compact >= self.compact_every_bytes
+        """Due when the log has doubled since the last compaction (or
+        recovery), and never before ``compact_every_bytes`` of growth
+        (module docstring: WHEN A COMPACTION IS DUE)."""
+        return self._bytes_since_compact >= max(
+            self.compact_every_bytes, self._bytes_last_compact)
 
     def compact(self, snapshot: WalSnapshot, records: Iterable) -> None:
         """Snapshot + reclaim: write ``snapshot`` followed by the
@@ -235,6 +262,7 @@ class Wal:
             self.metrics.segments_deleted += 1
         self.metrics.compactions += 1
         self._bytes_since_compact = 0
+        self._bytes_last_compact = len(buf)
 
     # --- recovery ---------------------------------------------------------
     def recover(self, logger=None) -> list:
@@ -245,6 +273,7 @@ class Wal:
         records never land after truncated garbage."""
         records: list = []
         truncated = False
+        recovered_bytes = 0
         for name in self.storage.segments():
             if truncated:
                 # A torn frame in a NON-last segment cannot happen
@@ -270,6 +299,7 @@ class Wal:
                 except ValueError:
                     break
                 at = start + length
+            recovered_bytes += at
             if at < len(data):
                 # Torn tail (an interrupted group commit): physically
                 # truncate it so recovery is IDEMPOTENT -- a later
@@ -285,6 +315,7 @@ class Wal:
         if records or truncated:
             self._rotate()
         self.metrics.recovered_records = len(records)
+        self._bytes_last_compact = recovered_bytes
         return records
 
     def close(self) -> None:

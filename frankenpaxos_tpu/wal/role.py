@@ -7,6 +7,25 @@ actor. That ordering is the WAL's entire safety argument (a crash can
 never lose acked state), so it lives here exactly once instead of
 drifting across four role classes; only ``_wal_compact`` (what live
 state a compaction re-logs) and recovery genuinely differ per role.
+
+A drain is sync -> release -> compact. WHY THE ACKS MAY LEAVE BEFORE A
+DUE COMPACTION, and do: every held message depends only on records
+the drain's ``wal.sync()`` has just made durable in the OLD segments.
+``Wal.compact`` syncs what is staged, writes the new segment and
+fsyncs it, and only THEN deletes the old ones; recovery replays the
+old segments first and resets at the new segment's ``WalSnapshot``.
+So at whatever storage call a compaction dies, recovery finds either
+the old segments alone, or the old segments followed by the whole new
+one, or the new one alone, and each holds every record a released ack
+depended on. (That rests, as it did when the compaction came first,
+on the new segment reaching the disk whole or not at all before its
+fsync: a torn prefix of it would reset recovery at a snapshot
+whose re-logged state is cut short, for the acks of every EARLIER
+drain under either order. docs/DURABILITY.md,
+"Segment rotation and watermark GC".) Holding the acks through the
+rewrite bought no safety and cost the rewrite's whole length in
+latency, for this drain's acks and, in a thrifty quorum, for every
+slot that needs this role.
 """
 
 from __future__ import annotations
@@ -16,7 +35,7 @@ import time
 
 class DurableRole:
     """Mixin over Actor: wal staging, deferred sends, and the drain's
-    sync -> compact -> release sequence."""
+    sync -> release -> compact sequence."""
 
     def _wal_init(self, wal) -> None:
         self.wal = wal
@@ -59,24 +78,34 @@ class DurableRole:
 
     def _wal_drain(self) -> None:
         """The on_drain tail for durable roles: ONE fsync covers every
-        record this drain appended, compaction runs on the same
-        boundary, and only then do the held acks go out. The two
-        paxtrace drain stages wal-fsync and send-release are exactly
-        the latency a command spends waiting on the group commit (the
-        dominant cloud-Paxos cost PAPERS.md's experience report
-        attributes poorly without tracing). Stage wal-compact opens
-        every drain, as wal-fsync does round a sync that may have
-        nothing to write: the check for a due compaction and, when
-        one is due, the compaction, which runs here on the loop and
-        holds this drain's acks back for as long as it takes."""
+        record this drain appended, then the held acks go out, and
+        only then does a due compaction run (the module docstring has
+        why that order is safe). The two paxtrace drain stages
+        wal-fsync and send-release are exactly the latency a command
+        spends waiting on the group commit (the dominant cloud-Paxos
+        cost PAPERS.md's experience report attributes poorly without
+        tracing). Stage wal-compact opens every drain, as wal-fsync
+        does round a sync that may have nothing to write: the check
+        for a due compaction and, when one is due, the compaction,
+        which runs here on the loop and stops the role's answers for
+        as long as it takes. What the drain released is therefore
+        pushed to the wire first (``Transport.flush_sends``): a
+        transport that writes at the end of a loop pass would
+        otherwise hold it in its buffers through the rewrite."""
         wal = self.wal
         if wal is None:
             return
         with self.trace_stage("wal-fsync"):
             wal.sync()
+        if self._wal_sends:
+            sends, self._wal_sends = self._wal_sends, []
+            with self.trace_stage("send-release"):
+                for dst, message in sends:
+                    self.send(dst, message)
         compacted_s = None
         with self.trace_stage("wal-compact"):
             if wal.wants_compaction():
+                self.transport.flush_sends()
                 t0 = time.perf_counter()
                 self._wal_compact()
                 compacted_s = time.perf_counter() - t0
@@ -85,11 +114,6 @@ class DurableRole:
             series.publish(wal.metrics)
             if compacted_s is not None:
                 series.compacted(compacted_s)
-        if self._wal_sends:
-            sends, self._wal_sends = self._wal_sends, []
-            with self.trace_stage("send-release"):
-                for dst, message in sends:
-                    self.send(dst, message)
 
     def _wal_compact(self) -> None:  # pragma: no cover - roles override
         raise NotImplementedError

@@ -173,6 +173,185 @@ class TestCrashRestartIntegration:
         assert sim.acceptors[0].round == rounds[0]  # promise survived
 
 
+# --- a drain that compacts: what leaves first, and a crash at each call ------
+
+
+class _ObservedStorage:
+    """A MemStorage whose every call that changes the disk is noted,
+    with the disk as a crash right after the call would leave it
+    (MemStorage's crash model: what was appended survives, whole)."""
+
+    def __init__(self, inner, events):
+        self.inner = inner
+        self.events = events
+
+    def _note(self, call, name):
+        self.events.append((call, name, {
+            n: bytes(data) for n, data in self.inner.files.items()}))
+
+    def append(self, name, data):
+        self.inner.append(name, data)
+        self._note("append", name)
+
+    def sync(self, name):
+        self.inner.sync(name)
+        self._note("sync", name)
+
+    def delete(self, name):
+        self.inner.delete(name)
+        self._note("delete", name)
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def _observe(role):
+    """Note, in one list and in order, what ``role`` sends and what it
+    does to its storage."""
+    events = []
+    role.wal.storage = _ObservedStorage(role.wal.storage, events)
+    send = role.send
+
+    def observed_send(dst, message, serializer=None):
+        events.append(("send", message))
+        send(dst, message, serializer)
+
+    role.send = observed_send
+    return events
+
+
+def _restart_from(sim, kind, disk):
+    """kill -9 role 0 of ``kind`` and restart it over ``disk``."""
+    from frankenpaxos_tpu.wal import MemStorage
+
+    role = (sim.acceptors if kind == "acceptor" else sim.replicas)[0]
+    storage = MemStorage()
+    storage.files = {n: bytearray(data) for n, data in disk.items()}
+    sim.wal_storages[role.address] = storage
+    if kind == "acceptor":
+        crash_restart_acceptor(sim, 0)
+        return sim.acceptors[0]
+    crash_restart_replica(sim, 0)
+    return sim.replicas[0]
+
+
+def _drain_until_compaction(kind):
+    """Drive role 0 of ``kind`` alone, a run a drain, until a drain
+    compacts. Returns the sim, that drain's events, and what had been
+    acknowledged when each of them happened: an acceptor's acked slots,
+    a replica's answered commands (their indices in its AppendLog)."""
+    from frankenpaxos_tpu.protocols.multipaxos.messages import (
+        ChosenRun,
+        ClientReply,
+        ClientReplyArray,
+        Command,
+        CommandBatch,
+        CommandId,
+        NOOP,
+        Phase2aRun,
+        Phase2bRange,
+    )
+
+    sim = make_multipaxos(f=1, wal=True)
+    role = (sim.acceptors if kind == "acceptor" else sim.replicas)[0]
+    events = _observe(role)
+    acked: set = set()
+    width = 8
+    for drain in range(400):
+        del events[:]
+        start = drain * width
+        if kind == "acceptor":
+            role.receive("proxy-leader-0", Phase2aRun(
+                start_slot=start, round=0, values=(NOOP,) * width))
+        else:
+            role.receive("proxy-leader-0", ChosenRun(
+                start_slot=start, values=tuple(
+                    CommandBatch((Command(
+                        CommandId("client-0", slot % 4, slot),
+                        b"payload-%05d" % slot),))
+                    for slot in range(start, start + width))))
+        role.on_drain()
+        acked_at = []
+        for event in events:
+            if event[0] == "send":
+                message = event[1]
+                if isinstance(message, Phase2bRange):
+                    acked |= set(range(message.slot_start_inclusive,
+                                       message.slot_end_exclusive))
+                elif isinstance(message, ClientReplyArray):
+                    acked |= {int(e[3]) for e in message.entries}
+                elif isinstance(message, ClientReply):
+                    acked.add(int(message.result))
+            acked_at.append(frozenset(acked))
+        if role.wal.metrics.compactions:
+            return sim, events, acked_at
+    raise AssertionError(f"{kind} never compacted")
+
+
+def _compaction_calls(events):
+    """Indices into ``events`` of a compacting drain's storage calls:
+    the group commit's append and sync, then the compaction's append
+    of the new segment, its sync, and a delete an old segment."""
+    calls = [i for i, e in enumerate(events) if e[0] != "send"]
+    kinds = [events[i][0] for i in calls]
+    assert kinds[:4] == ["append", "sync", "append", "sync"], kinds
+    assert kinds[4:] and set(kinds[4:]) == {"delete"}, kinds
+    assert events[calls[2]][1] > events[calls[0]][1]  # a NEW segment
+    return calls
+
+
+ACKS = {"acceptor": "Phase2bRange", "replica": "ClientReplyArray"}
+
+
+@pytest.mark.parametrize("kind", sorted(ACKS))
+def test_a_compacting_drain_releases_its_acks_before_the_rewrite(kind):
+    """sync -> release -> compact (wal/role.py): the drain's acks,
+    which its group commit has made durable, are sent before the
+    compaction's first write, not after its last delete."""
+    _, events, _ = _drain_until_compaction(kind)
+    calls = _compaction_calls(events)
+    sends = [i for i, e in enumerate(events) if e[0] == "send"]
+    acks = [i for i in sends
+            if type(events[i][1]).__name__ == ACKS[kind]]
+    assert acks, events
+    assert all(calls[1] < i < calls[2] for i in sends), \
+        [e[:2] for e in events]
+
+
+@pytest.mark.parametrize("crash_after", [
+    "new-segment-append", "new-segment-sync", "first-delete",
+    "middle-delete", "last-delete"])
+@pytest.mark.parametrize("kind", sorted(ACKS))
+def test_crash_inside_a_compaction_loses_no_acked_state(kind, crash_after):
+    """The acks left BEFORE the compaction, so a crash after any of
+    its storage calls must recover all they depended on: the old
+    segments are whole until the new one is synced, and recovery
+    resets at the new one's WalSnapshot."""
+    sim, events, acked_at = _drain_until_compaction(kind)
+    calls = _compaction_calls(events)
+    deletes = calls[4:]
+    assert len(deletes) >= 3  # rotation made several old segments
+    at = {"new-segment-append": calls[2],
+          "new-segment-sync": calls[3],
+          "first-delete": deletes[0],
+          "middle-delete": deletes[len(deletes) // 2],
+          "last-delete": deletes[-1]}[crash_after]
+    acked = acked_at[at]
+    assert acked and acked == acked_at[-1]  # this drain's acks too
+    before = (sim.replicas[0].state_machine.get()
+              if kind == "replica" else None)
+    role = _restart_from(sim, kind, events[at][2])
+    if kind == "acceptor":
+        voted = {info.slot for info in role._voted_info(0)}
+        assert acked <= voted, sorted(acked - voted)
+        assert role.round == 0
+    else:
+        executed = role.state_machine.get()
+        assert max(acked) < len(executed)
+        assert executed == before[:len(executed)]
+        assert role.executed_watermark == len(executed)
+
+
 # --- the chaos simulated system --------------------------------------------
 
 

@@ -383,3 +383,58 @@ def test_a_peer_that_closed_is_dropped_before_the_next_send(transports):
     life("to the second life").stop()
     assert got == ["to the first life", "to the second life"]
     assert not any("write failed" in m for _, m in logger.records)
+
+
+def test_a_durable_role_writes_its_acks_before_it_compacts(transports):
+    """A send waits in ``pending`` for the flush at the end of its loop
+    pass, and a WAL compaction blocks the loop inside that pass: a
+    durable role therefore pushes the acks its drain released to the
+    wire (``flush_sends``) before it starts one, and its peer has them
+    while the rewrite runs, not after it."""
+    from frankenpaxos_tpu.protocols.echo import EchoReply
+    from frankenpaxos_tpu.runtime import Actor
+    from frankenpaxos_tpu.wal import (
+        DurableRole,
+        MemStorage,
+        Wal,
+        WalPromise,
+        WalSnapshot,
+    )
+
+    got: list = []
+
+    class DurableEcho(Actor, DurableRole):
+        def __init__(self, address, transport, logger):
+            super().__init__(address, transport, logger)
+            # One 17-byte record a drain: the second drain compacts.
+            self._wal_init(Wal(MemStorage(), compact_every_bytes=30))
+            self.peer_had_the_ack = None
+
+        def receive(self, src, message):
+            self.wal.append(WalPromise(round=1))
+            self._wal_send(src, EchoReply(msg=message.msg))
+
+        def on_drain(self):
+            self._wal_drain()
+
+        def _wal_compact(self):
+            # On the loop's thread, which answers nothing meanwhile;
+            # the peer's loop is another thread.
+            self.peer_had_the_ack = wait_for(lambda: len(got) == 2, 2.0)
+            self.wal.compact(WalSnapshot(payload=b""), [])
+
+    server_addr = ("127.0.0.1", free_port())
+    client_addr = ("127.0.0.1", free_port())
+    server_t = transports(server_addr)
+    client_t = transports(client_addr)
+    logger = FakeLogger()
+    server = DurableEcho(server_addr, server_t, logger)
+    client = EchoClient(client_addr, client_t, logger, server_addr)
+    # The first answer opens the server's connection to the client.
+    client_t.loop.call_soon_threadsafe(client.echo, "first", got.append)
+    assert wait_for(lambda: got == ["first"])
+    assert server.wal.metrics.compactions == 0
+    client_t.loop.call_soon_threadsafe(client.echo, "second", got.append)
+    assert wait_for(lambda: server.wal.metrics.compactions == 1)
+    assert server.peer_had_the_ack is True
+    assert got == ["first", "second"]

@@ -217,6 +217,98 @@ def test_wants_compaction_threshold():
     assert not wal.wants_compaction()
 
 
+@pytest.mark.parametrize("kept, rewrite_bound", [
+    (0.0, 1), (0.5, 1), (1.0, 2),
+], ids=["nothing-kept", "half-kept", "everything-kept"])
+def test_compaction_is_due_when_the_log_has_doubled(kept, rewrite_bound):
+    """A compaction is due once the log has grown by what the last one
+    wrote, and never before ``compact_every_bytes``. Over a long
+    append-only history of a role whose compactions keep the newest
+    ``kept`` share of what it has: the first comes at
+    ``compact_every_bytes``; all compactions together write at most
+    ``rewrite_bound`` times the bytes appended (under twice whatever
+    is kept, and no more than once unless nearly all is); the disk
+    never holds more than twice what the last one kept plus
+    ``compact_every_bytes`` (plus the drain that crossed the line);
+    and after ``recover()`` the next one waits for the recovered bytes
+    again. A fixed threshold fails the first bound for
+    everything-kept: it rewrites the whole history every
+    ``compact_every_bytes``."""
+    every = 4096
+    storage = MemStorage()
+    wal = Wal(storage, segment_bytes=1024, compact_every_bytes=every)
+    live: list = []  # what a compaction of this role would re-log
+    appended = 0  # bytes the role's own records took
+    drain_bytes = 0  # the widest group commit
+    rewritten = 0  # bytes the compactions wrote
+    last_wrote = 0
+    due_at = []  # (bytes appended since the last compaction, its size)
+
+    def disk():
+        return sum(len(data) for data in storage.files.values())
+
+    def drain(slot):
+        before = wal.metrics.bytes_synced
+        for i in range(8):
+            record = WalVote(slot=slot + i, round=0, value=b"v" * 24)
+            wal.append(record)
+            live.append(record)
+        wal.sync()
+        return wal.metrics.bytes_synced - before
+
+    since = 0
+    for n in range(600):
+        wrote = drain(8 * n)
+        appended += wrote
+        since += wrote
+        drain_bytes = max(drain_bytes, wrote)
+        assert disk() <= 2 * last_wrote + every + drain_bytes
+        if not wal.wants_compaction():
+            continue
+        due_at.append((since, last_wrote))
+        del live[:len(live) - int(len(live) * kept)]
+        before = wal.metrics.bytes_synced
+        wal.compact(WalSnapshot(payload=b""), list(live))
+        last_wrote = wal.metrics.bytes_synced - before
+        rewritten += last_wrote
+        since = 0
+        assert disk() == last_wrote  # every older segment is gone
+        assert not wal.wants_compaction()
+        assert rewritten <= rewrite_bound * appended
+
+    # The first comes at compact_every_bytes, each later one once the
+    # log has grown by what the one before it wrote, and not a drain
+    # later than that.
+    assert due_at[0][1] == 0
+    for since, wrote in due_at:
+        assert max(every, wrote) <= since < max(every, wrote) + drain_bytes
+    assert wal.metrics.compactions == len(due_at)
+    if kept == 0.0:
+        # One marker a time: every compact_every_bytes (rounded up
+        # to whole drains), as under the fixed threshold.
+        assert all(wrote < 64 for _, wrote in due_at)
+        assert len({since for since, _ in due_at}) == 1
+        assert len(due_at) == appended // due_at[0][0]
+    if kept == 1.0:
+        # At 1x, 2x, 4x ... compact_every_bytes: six in ~250 KB, where
+        # a fixed threshold made sixty.
+        assert len(due_at) == 6
+        sizes = [wrote for _, wrote in due_at[1:]]
+        assert all(b >= 2 * a - 64 for a, b in zip(sizes, sizes[1:]))
+
+    # A restart: the recovered log is what the next compaction would
+    # rewrite, so it waits for that much growth again.
+    recovered = disk()
+    wal2 = Wal(storage, segment_bytes=1024, compact_every_bytes=every)
+    assert len(wal2.recover()) > 0
+    assert not wal2.wants_compaction()
+    wal, since = wal2, 0
+    while not wal.wants_compaction():
+        since += drain(10 ** 6 + since)
+    assert max(every, recovered) <= since \
+        < max(every, recovered) + drain_bytes
+
+
 # --- paxchaos: FsyncStallStorage over REAL FileStorage on disk ---------------
 
 
