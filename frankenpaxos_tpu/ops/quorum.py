@@ -718,6 +718,12 @@ class TpuQuorumChecker:
                                        self._meta))
 
 
+#: Planes an epoch stack holds at least, and the rows its board's
+#: acceptor axis is allocated by (see ``_rebuild_universe``).
+_MIN_PLANES = 128
+_ROW_TILE = 8
+
+
 class EpochSegmentedChecker:
     """Quorum checking where each SLOT selects its epoch's predicate.
 
@@ -766,7 +772,7 @@ class EpochSegmentedChecker:
         self._starts = [int(b) for b in boundaries]
         self.universe: tuple = ()
         self._rebuild_universe()
-        self.board = make_vote_board(window, len(self.universe))
+        self.board = make_vote_board(window, self._rows)
         if mesh is not None:
             self.board = _shard_board(self.board, mesh, window)
 
@@ -783,10 +789,31 @@ class EpochSegmentedChecker:
         # everything below boundaries[0]). int32 like the board's slot
         # state: x64 is off in jitted kernels, and no ring outlives
         # 2^31 slots between GCs.
+        # K and N are shapes of every jitted call, so both are padded:
+        # the stack to a capacity that doubles, the acceptor axis (the
+        # board's rows and the masks' columns) to whole tiles of
+        # ``_ROW_TILE``. A cluster reconfigured once a second would
+        # otherwise compile the scatter again, for every batch width,
+        # at every reconfiguration and for every acceptor new to the
+        # universe, on its event loop (seconds each on a TPU). A
+        # padding plane starts at the largest int32, which no slot
+        # reaches, so searchsorted never selects it; a padding row is
+        # in no mask and no vote names it.
+        capacity = _MIN_PLANES
+        while capacity < len(specs):
+            capacity *= 2
+        spare = capacity - len(specs)
+        self._rows = -(-len(self.universe) // _ROW_TILE) * _ROW_TILE
+        masks, thresholds, combine_any = pad_specs(specs)
         (self._masks, self._thresholds, self._combine_any,
          self._boundaries) = _place_planes(
-            (*pad_specs(specs),
-             np.asarray(self._starts[1:], dtype=np.int32)), self.mesh)
+            (np.pad(masks, ((0, spare), (0, 0),
+                            (0, self._rows - len(self.universe)))),
+             np.pad(thresholds, ((0, spare), (0, 0))),
+             np.pad(combine_any, (0, spare)),
+             np.pad(np.asarray(self._starts[1:], dtype=np.int32),
+                    (0, spare), constant_values=np.iinfo(np.int32).max)),
+            self.mesh)
         self._boundaries_np = np.asarray(self._starts[1:],
                                          dtype=np.int64)
 
@@ -803,16 +830,33 @@ class EpochSegmentedChecker:
                 f"{self._starts[-1]}")
         self._own_specs.append(spec)
         self._starts.append(int(start_slot))
-        old_universe = self.universe
+        old_universe, old_rows = self.universe, self._rows
         self._rebuild_universe()
-        if self.universe != old_universe:
-            cmap = epoch_column_map(old_universe, self.universe)
-            self.board = VoteBoard(
-                votes=_reshape_columns(self.board.votes, cmap),
-                rounds=self.board.rounds,
-                chosen=self.board.chosen,
-                owner=self.board.owner,
-            )
+        if (self.universe[:len(old_universe)] != old_universe
+                or self._rows != old_rows):
+            self._take_votes(self.board, old_universe)
+
+    def _take_votes(self, board: VoteBoard, universe) -> None:
+        """This checker's board from ``board``, whose rows are
+        ``universe``'s: the epoch reshape gather onto this universe
+        and its padding rows; the slot-axis state as it stands."""
+        cmap = np.full(self._rows, -1, dtype=np.int32)
+        cmap[:len(self.universe)] = epoch_column_map(universe,
+                                                    self.universe)
+        self.board = VoteBoard(
+            votes=_reshape_columns(board.votes, cmap),
+            rounds=board.rounds, chosen=board.chosen, owner=board.owner)
+
+    def adopt(self, checker: "TpuQuorumChecker") -> None:
+        """Continue on ``checker``'s live board: its acceptor axis is
+        gathered onto this union universe (the epoch reshape gather of
+        :meth:`TpuQuorumChecker.reshape`), its slot-axis state is taken
+        as it stands, so a slot mid-collection there completes here.
+        ``checker`` must not be used afterwards."""
+        if checker.window != self.window:
+            raise ValueError(f"cannot adopt a {checker.window}-slot board "
+                             f"into a {self.window}-slot one")
+        self._take_votes(checker.board, checker.spec.universe)
 
     def config_indices(self, slots: np.ndarray) -> np.ndarray:
         """Which epoch plane governs each slot."""
@@ -826,8 +870,11 @@ class EpochSegmentedChecker:
         under each row's slot's epoch -- one fused kernel across the
         handover boundary."""
         config_idx = self.config_indices(slots)
+        present = np.asarray(present, dtype=np.uint8)
+        present = np.pad(present, ((0, 0),
+                                   (0, self._rows - present.shape[1])))
         return np.asarray(_check_batch_multi(
-            np.asarray(present, dtype=np.uint8),
+            present,
             np.asarray(config_idx, dtype=np.int32),
             self._masks, self._thresholds, self._combine_any))
 

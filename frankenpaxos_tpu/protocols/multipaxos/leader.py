@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import random
+import time
 from typing import Optional
 
 from frankenpaxos_tpu.election.basic import (
@@ -163,6 +164,7 @@ class _EpochChange:
     acks: set
     resend: object  # Timer
     pending: list   # buffered CommandBatchOrNoop values
+    started: float  # time.perf_counter() when the commit first left
     activated: bool = False
     # True when RE-driving an adopted epoch (post-failover, or a
     # Phase2 leader learning one from a peer broadcast): same gate --
@@ -190,6 +192,13 @@ class Leader(Actor):
             "multipaxos_leader_requests_latency_seconds", labels=("type",))
         self.metrics_requests = collectors.counter(
             "multipaxos_leader_requests_total", labels=("type",))
+        # Reconfiguration (reconfig/): epochs this leader activated,
+        # and the proposals it held back while each waited for its
+        # predecessor's quorum of acks.
+        self.metrics_epoch_changes = collectors.counter(
+            "multipaxos_leader_epoch_changes_total")
+        self.metrics_epoch_buffered = collectors.counter(
+            "multipaxos_leader_epoch_buffered_proposals_total")
         self.index = list(config.leader_addresses).index(address)
         self.grid = config.quorum_grid() if config.flexible else None
         self._row_size = len(config.acceptor_addresses[0])
@@ -1073,7 +1082,8 @@ class Leader(Actor):
         timer.start()
         self._epoch_change = _EpochChange(
             config=config, commit=commit, targets=set(targets),
-            acks=set(), resend=timer, pending=[], recommit=recommit)
+            acks=set(), resend=timer, pending=[],
+            started=time.perf_counter(), recommit=recommit)
         for dst in targets:
             self.send(dst, commit)
 
@@ -1159,6 +1169,15 @@ class Leader(Actor):
                     self._abort_epoch_change()
                     return
                 change.activated = True
+                # Stage ``epoch-handover``: from the commit's first
+                # send to here, the wait in which proposals buffer.
+                metrics = self.transport.runtime_metrics
+                if metrics is not None:
+                    metrics.observe_stage(
+                        "epoch-handover",
+                        time.perf_counter() - change.started)
+                self.metrics_epoch_changes.inc()
+                self.metrics_epoch_buffered.inc(len(change.pending))
                 # Post-activation the resends only need to reach the
                 # parties that ROUTE by the epoch (proxies) and the
                 # new members; stop chasing old-epoch/peer-leader

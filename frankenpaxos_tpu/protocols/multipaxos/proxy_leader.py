@@ -109,6 +109,24 @@ class ProxyLeader(Actor):
             collectors.counter(
                 "multipaxos_proxy_leader_tpu_window_violations_total"))
         self._tpu_published = (0,) * len(self.metrics_tpu_work)
+        # The epoch tracker's counts (reconfig/), published after each
+        # of its drains: in the order _publish_epoch_counts reads them.
+        self.metrics_epoch_work = (
+            collectors.counter(
+                "multipaxos_proxy_leader_epoch_votes_total"),
+            collectors.counter(
+                "multipaxos_proxy_leader_epoch_launches_total"))
+        self._epoch_published = (0,) * len(self.metrics_epoch_work)
+        self.metrics_epoch_planes = collectors.gauge(
+            "multipaxos_proxy_leader_epoch_planes")
+        self.metrics_epoch_stashed = collectors.counter(
+            "multipaxos_proxy_leader_epoch_stashed_runs_total")
+        # Votes the single-epoch tracker held for slots still
+        # collecting when epoch counting engaged, and that the epoch
+        # tracker could not take over: their quorums complete only
+        # through protocol-level resends.
+        self.metrics_epoch_stranded = collectors.counter(
+            "multipaxos_proxy_leader_epoch_switch_stranded_votes_total")
         self.grid = config.quorum_grid() if config.flexible else None
         self._row_size = len(config.acceptor_addresses[0])
         # paxingest (ingest/): control batch frames of vote acks land
@@ -155,8 +173,6 @@ class ProxyLeader(Actor):
         # EpochPhase2aRuns for epochs this proxy has not seen the
         # commit for yet: epoch -> [run]; replayed when it arrives.
         self._stashed_epoch_runs: dict[int, list] = {}
-        if options.epoch_quorums and self.epochs is not None:
-            self._ensure_epoch_tracker()
         self._flush_timer = None
         self._collector = None
         if options.quorum_backend == "tpu":
@@ -211,6 +227,8 @@ class ProxyLeader(Actor):
                 self._flush_timer = self.timer(
                     "tpuDrainFlush", TPU_FLUSH_PERIOD_S,
                     flush_pending)
+        if options.epoch_quorums and self.epochs is not None:
+            self._ensure_epoch_tracker()
 
     def receive(self, src: Address, message) -> None:
         # timed(label) handler latency summaries (Leader.scala:281-293).
@@ -357,6 +375,7 @@ class ProxyLeader(Actor):
         if config is None:
             self._stashed_epoch_runs.setdefault(run.epoch,
                                                 []).append(run)
+            self.metrics_epoch_stashed.inc()
             return
         self._ensure_epoch_tracker()
         if not self._admit_run(run.start_slot, run.round, run.values):
@@ -387,23 +406,27 @@ class ProxyLeader(Actor):
             return  # lower-round or non-contiguous: no ack
         self._ensure_epoch_tracker()
         self._epoch_tracker.note_epochs()
+        self.metrics_epoch_planes.set(self._epoch_tracker.planes)
         self.send(src, EpochAck(epoch=commit.epoch, round=commit.round))
         for run in self._stashed_epoch_runs.pop(commit.epoch, []):
             self._handle_epoch_phase2a_run(src, run)
 
     def _ensure_epoch_tracker(self) -> None:
-        """Engage epoch-segmented vote counting. Pre-switch state in a
-        dict tracker migrates (its (group, index) votes map to
-        addresses through the epoch-0 config); the TPU tracker's
-        board state cannot be extracted -- quorums straddling
-        that switch complete through protocol-level resends (warned)."""
+        """Engage epoch-segmented vote counting. Votes the
+        single-epoch tracker holds for slots still collecting move
+        over: a dict tracker's (group, index) votes map to addresses
+        through the epoch-0 config, and a device board is adopted
+        whole by a device epoch tracker (one gather onto its
+        universe). Only a device board facing a dict epoch tracker
+        stays behind; what it strands is counted."""
         if self._epoch_tracker is not None or self.epochs is None:
             return
         backend = self.options.epoch_backend or (
             "tpu" if self.options.quorum_backend == "tpu" else "dict")
         self._epoch_tracker = EpochQuorumTracker(
             self.epochs, backend=backend,
-            window=min(self.options.tpu_window, 1 << 14))
+            window=self.options.tpu_window)
+        self.metrics_epoch_planes.set(self._epoch_tracker.planes)
         if isinstance(self.tracker, DictQuorumTracker):
             for (slot, rnd), votes in self.tracker.states.items():
                 if not votes:
@@ -415,11 +438,21 @@ class ProxyLeader(Actor):
                     addr = self.config.acceptor_addresses[g][i]
                     self._epoch_tracker.record(slot, rnd, addr)
             self.tracker.states = {}
-        elif self.options.quorum_backend == "tpu" \
-                and not self.options.epoch_quorums:
-            self.logger.warn(
-                "tpu quorum tracker state not migrated to the epoch "
-                "tracker; in-flight quorums complete via resends")
+            return
+        # The device board: what was buffered for it goes first, so
+        # that the board holds every vote up to this instant.
+        if self.tracker.has_votes():
+            self.tracker.drain()
+        self._hand_over_dispatches()
+        if backend == "tpu":
+            self._epoch_tracker.adopt_board(self.tracker.checker)
+            return
+        # paxlint: disable=TPU203 -- one fetch, at the one switch
+        board = self.tracker.checker.board
+        stranded = int(np.asarray(board.votes)[
+            :, ~np.asarray(board.chosen)].sum())
+        if stranded:
+            self.metrics_epoch_stranded.inc(stranded)
 
     def _run_for(self, slot: int, round: int):
         """The pending run covering (slot, round), else None."""
@@ -538,8 +571,11 @@ class ProxyLeader(Actor):
             self._hand_over_dispatches()
         if self._epoch_tracker is not None \
                 and self._epoch_tracker.has_votes():
-            with self.trace_stage("drain"):
+            # Stage ``epoch-drain``: the epoch tracker's whole check,
+            # its device round trips included (it fetches on the loop).
+            with self.trace_stage("epoch-drain"):
                 chosen = self._epoch_tracker.drain()
+            self._publish_epoch_counts()
             self._emit_chosen(chosen)
 
     def _hand_over_dispatches(self) -> None:
@@ -583,6 +619,16 @@ class ProxyLeader(Actor):
                                      self._tpu_published):
             series.inc(now - then)
         self._tpu_published = counts
+
+    def _publish_epoch_counts(self) -> None:
+        """The epoch tracker's work counts into /metrics, as
+        increments (as :meth:`_publish_tpu_counts`)."""
+        t = self._epoch_tracker
+        counts = (t.votes, t.launches)
+        for series, now, then in zip(self.metrics_epoch_work, counts,
+                                     self._epoch_published):
+            series.inc(now - then)
+        self._epoch_published = counts
 
     def _collect_and_post(self, dispatch, stages) -> None:
         """Runs on the collector thread: block on the device fetch, then

@@ -45,14 +45,21 @@ class EpochQuorumTracker:
         self._cols: list = []
         self._rounds: list = []
         self._chunk = 256
+        # Work counts (ProxyLeader publishes them): votes handed to
+        # drain() and the jitted calls they made, one a chunk.
+        self.votes = 0
+        self.launches = 0
         if backend == "tpu":
             from frankenpaxos_tpu.ops.quorum import EpochSegmentedChecker
 
             specs, starts = store.specs_and_boundaries()
             self._checker = EpochSegmentedChecker(specs, starts,
                                                   window=window)
-            # Prewarm the scatter buckets before client traffic.
-            self._checker.record_and_check([0], [0], [-1])
+            # Prewarm every scatter bucket a drain's chunks can pad to,
+            # before this tracker's first votes.
+            for width in (64, 128, self._chunk):
+                self._checker.record_and_check(
+                    [0] * width, [0] * width, [-1] * width)
             self._checker.release([0])
 
     def note_epochs(self) -> None:
@@ -86,6 +93,20 @@ class EpochQuorumTracker:
                 # proposals, which protocol-level resends re-drive.
                 self._slots, self._cols, self._rounds = [], [], []
         self._known = known
+
+    @property
+    def planes(self) -> int:
+        """K: the epochs whose predicate planes a check reads."""
+        return len(self._known)
+
+    def adopt_board(self, checker) -> None:
+        """Take over the live single-epoch board of ``checker`` (a
+        ``TpuQuorumChecker`` over the epoch-0 members in config order,
+        which are this store's first universe ids): votes it holds
+        for slots still collecting keep counting here. The caller has
+        dispatched every vote it meant for ``checker`` and never uses
+        it again (its buffers are donated to this tracker's calls)."""
+        self._checker.adopt(checker)
 
     # --- recording (per message, O(1) Python) ------------------------------
     def record(self, slot: int, round: int, voter) -> None:
@@ -161,9 +182,11 @@ class EpochQuorumTracker:
         cols = np.asarray(self._cols, dtype=np.int32)
         rounds = np.asarray(self._rounds, dtype=np.int32)
         self._slots, self._cols, self._rounds = [], [], []
+        self.votes += slots.size
         out: list = []
         seen: set = set()
         for at in range(0, slots.size, self._chunk):
+            self.launches += 1
             sl = slots[at:at + self._chunk]
             newly = self._checker.record_and_check(
                 sl, cols[at:at + self._chunk],
