@@ -303,28 +303,14 @@ def _stage_block(block: np.ndarray, width: int, start: int,
     return staged
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2, 3))
-@_named("fpx_quorum_record_block")
-def _record_block(
-    board: VoteBoard,
-    staged: jax.Array,       # uint8, from _stage_block: [N, B] vote
-                             # arrivals for these slots, then the ring
-                             # offset of the block, the slot number of
-                             # that column and the votes' round
-    masks_t: tuple,
-    meta: tuple,
-) -> tuple[VoteBoard, jax.Array]:
-    """Dense path: votes for a contiguous slot block, one round.
-
-    The steady-state Phase2b stream (Leader.scala:331-408 allocates slots
-    contiguously; ProxyLeader collects in slot order) maps here: no
-    scatter, only slicing. Returns the ``[B]`` newly-chosen mask.
-
-    Columns with no vote in ``block`` (gap slots inside the run, or
-    bucket padding) are left untouched -- in particular their rounds are
-    NOT bumped, so an older-round slot mid-run keeps collecting its own
-    round's votes (matching the per-(slot, round) dict semantics).
-    """
+def _apply_block_votes(board: VoteBoard, staged: jax.Array, predicate):
+    """Shared traced body of the dense kernels: ring self-reclaim + round
+    preemption + vote recording by slices, then ``predicate(cols,
+    owner) -> [B] bool`` over the block's columns and the slot each now
+    holds (the single-spec and the epoch-segmented kernel each hand in
+    their own) and the chosen plane. ``staged`` is
+    :func:`_stage_block`'s buffer. Returns the new board and the ``[B]``
+    newly-chosen mask."""
     n, block_size = board.votes.shape[0], staged.shape[1]
     block = staged[:n]
     start, true_start, vote_round = jax.lax.bitcast_convert_type(
@@ -367,7 +353,11 @@ def _record_block(
                                               (start,))
 
     with jax.named_scope("check"):
-        hit = _predicate_hit(cols, masks_t, meta)
+        # The slot a column HOLDS, not the one the block would put
+        # there: a column this block does not touch (or whose vote was
+        # stale) is judged as its owner's, so it reads as it did when
+        # its last vote was recorded.
+        hit = predicate(cols, new_owner)
         old_chosen = jax.lax.dynamic_slice(board.chosen, (start,),
                                            (block_size,))
         old_chosen = jnp.where(claim, False, old_chosen)
@@ -376,6 +366,84 @@ def _record_block(
                                               hit | old_chosen, (start,))
     return VoteBoard(votes=votes, rounds=rounds, chosen=chosen,
                      owner=owner), newly
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2, 3))
+@_named("fpx_quorum_record_block")
+def _record_block(
+    board: VoteBoard,
+    staged: jax.Array,       # uint8, from _stage_block: [N, B] vote
+                             # arrivals for these slots, then the ring
+                             # offset of the block, the slot number of
+                             # that column and the votes' round
+    masks_t: tuple,
+    meta: tuple,
+) -> tuple[VoteBoard, jax.Array]:
+    """Dense path: votes for a contiguous slot block, one round.
+
+    The steady-state Phase2b stream (Leader.scala:331-408 allocates slots
+    contiguously; ProxyLeader collects in slot order) maps here: no
+    scatter, only slicing. Returns the ``[B]`` newly-chosen mask.
+
+    Columns with no vote in ``block`` (gap slots inside the run, or
+    bucket padding) are left untouched -- in particular their rounds are
+    NOT bumped, so an older-round slot mid-run keeps collecting its own
+    round's votes (matching the per-(slot, round) dict semantics).
+    """
+    return _apply_block_votes(
+        board, staged,
+        lambda cols, owner: _predicate_hit(cols, masks_t, meta))
+
+
+def _epoch_block_hit(cols: jax.Array, slot_ids: jax.Array,
+                     boundaries: jax.Array, masks: jax.Array,
+                     thresholds: jax.Array,
+                     combine_any: jax.Array) -> jax.Array:
+    """``[B]`` bool from a ``[N, B]`` vote block whose column ``b``
+    holds slot ``slot_ids[b]``, each column judged under its slot's
+    epoch.
+
+    No per-column indexing (``masks[config_idx]`` is a B-row gather, and
+    indexed access costs this chip about a microsecond an index): EVERY
+    plane's predicate is evaluated for every column, one ``[K*G, N] x
+    [N, B]`` product with the columns along the lanes, and the plane that
+    governs a column is picked by comparing its slot number with the
+    boundaries: plane ``k`` governs where ``boundaries[k-1] <= slot <
+    boundaries[k]``, which is ``searchsorted(..., side="right")`` as a
+    one-hot (of equal boundaries the last wins; a padding plane starts
+    at the largest int32 and governs nothing)."""
+    k, g, n = masks.shape
+    counts = (masks.reshape(k * g, n).astype(jnp.int32)
+              @ cols.astype(jnp.int32))                        # [K*G, B]
+    satisfied = (counts >= thresholds.reshape(k * g, 1)).reshape(k, g, -1)
+    plane_hit = jnp.where(combine_any[:, None], satisfied.any(1),
+                          satisfied.all(1))                    # [K, B]
+    begun = slot_ids[None, :] >= boundaries[:, None]           # [K-1, B]
+    edge = jnp.ones((1, slot_ids.shape[0]), dtype=jnp.bool_)
+    governs = (jnp.concatenate([edge, begun])
+               & ~jnp.concatenate([begun, ~edge]))             # [K, B]
+    return (plane_hit & governs).any(0)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+@_named("fpx_quorum_record_block_epochs")
+def _record_block_epochs(
+    board: VoteBoard,
+    staged: jax.Array,       # uint8, from _stage_block
+    boundaries: jax.Array,   # [K-1] int32: start slots of epochs 1..K-1
+    masks: jax.Array,        # [K, G, N] padded per-epoch masks
+    thresholds: jax.Array,   # [K, G]
+    combine_any: jax.Array,  # [K] bool
+) -> tuple[VoteBoard, jax.Array]:
+    """The epoch-segmented dense kernel: :func:`_record_block`'s board
+    update, with each column's quorum predicate that of its SLOT's
+    epoch, so a block spans any number of hand-over boundaries. The
+    planes live on the device (:func:`_place_planes`): a launch
+    transfers the one staged buffer."""
+    return _apply_block_votes(
+        board, staged,
+        lambda cols, owner: _epoch_block_hit(
+            cols, owner, boundaries, masks, thresholds, combine_any))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -462,6 +530,19 @@ def _place_planes(planes: tuple, mesh) -> tuple:
     return tuple(jax.device_put(plane, placement) for plane in planes)
 
 
+def _pin_board(board: VoteBoard, mesh) -> VoteBoard:
+    """A board that rides beside placed planes, placed like them: with
+    no mesh COMMITTED to the planes' device (a sharded board already
+    is). A jitted call's outputs are committed as soon as one input is,
+    so a board that starts out uncommitted (fresh from
+    :func:`make_vote_board`, or adopted from a single-spec checker)
+    would meet each program twice: as it is, and as the first call
+    returns it -- and the second meeting compiles on the event loop."""
+    if mesh is not None:
+        return board
+    return jax.device_put(board, jax.devices()[0])
+
+
 def _spec_statics(spec: QuorumSpec) -> tuple[tuple, tuple]:
     """Hashable statics for the jitted kernels: ``(masks_t, meta)``
     where ``meta = (thresholds_t, combine_any, grid_or_None)``. Grid
@@ -507,21 +588,16 @@ def reshape_block(block: np.ndarray, old_universe,
     return np.asarray(_reshape_columns(np.asarray(block), cmap))
 
 
-class TpuQuorumChecker:
-    """Stateful batched quorum checking for one quorum predicate.
+class _BoardChecker:
+    """The host side of a stateful vote board, shared by the single-spec
+    and the epoch-segmented checker: bucket padding, the one staged
+    buffer of a dense call, ring surveillance, release. A subclass sets
+    ``num_nodes`` (the rows a dense block must have), calls
+    :meth:`_init_board`, and supplies the two jitted kernels with its
+    predicate's arguments (:meth:`_block_kernel`,
+    :meth:`_votes_kernel`)."""
 
-    Typical use (ProxyLeader Phase2b path)::
-
-        checker = TpuQuorumChecker(qs.write_spec(), window=1 << 20)
-        # hot path: contiguous slot block, dense [n, B] arrival mask
-        newly = checker.record_block(start_slot, arrivals, round=3)
-        # thin tail: out-of-order votes
-        newly = checker.record_and_check(slots, acceptor_cols, rounds)
-
-    One call per event-loop drain, thousands of votes per call.
-    """
-
-    def __init__(self, spec: QuorumSpec, window: int, mesh=None):
+    def _init_board(self, window: int, rows: int, mesh) -> None:
         """``mesh``: an optional ``jax.sharding.Mesh``. When given, the
         vote board's SLOT axis shards over every mesh axis (the
         slot-partitioning scaling axis, SURVEY.md section 2.3 /
@@ -532,9 +608,7 @@ class TpuQuorumChecker:
         tests/test_multichip_checker.py)."""
         if window <= 0:
             raise ValueError("window must be positive")
-        self.spec = spec
         self.window = window
-        self.num_nodes = spec.num_nodes
         # Ring-invariant surveillance (the "window > max slots in
         # flight" contract, see VoteBoard): a vote whose slot trails the
         # newest recorded slot by >= window may land on a reclaimed
@@ -544,10 +618,18 @@ class TpuQuorumChecker:
         # violations and log the first occurrence loudly.
         self._max_slot_seen = -1
         self.window_violations = 0
-        self._masks_t, self._meta = _spec_statics(spec)
-        self.board = make_vote_board(window, spec.num_nodes)
+        self.board = make_vote_board(window, rows)
         if mesh is not None:
             self.board = _shard_board(self.board, mesh, window)
+
+    def _block_kernel(self, staged: np.ndarray) -> jax.Array:
+        """Run the dense kernel on ``self.board``; the device mask."""
+        raise NotImplementedError
+
+    def _votes_kernel(self, slots, true_slots, nodes, rounds,
+                      valid) -> jax.Array:
+        """Run the scatter kernel on ``self.board``; the device mask."""
+        raise NotImplementedError
 
     def record_block_async(self, start_slot: int, block: np.ndarray,
                            vote_round: int = 0) -> jax.Array:
@@ -566,8 +648,8 @@ class TpuQuorumChecker:
         of its own."""
         n, b = block.shape
         if n != self.num_nodes:
-            raise ValueError(f"block has {n} acceptor rows, spec has "
-                             f"{self.num_nodes}")
+            raise ValueError(f"block has {n} acceptor rows, the board "
+                             f"has {self.num_nodes}")
         start = start_slot % self.window
         if start + b > self.window:
             raise ValueError(
@@ -579,11 +661,8 @@ class TpuQuorumChecker:
             padded *= 2
         if start + padded > self.window:
             padded = b
-        self.board, newly = _record_block(
-            self.board,
-            _stage_block(block, padded, start, start_slot, vote_round),
-            self._masks_t, self._meta)
-        return newly
+        return self._block_kernel(
+            _stage_block(block, padded, start, start_slot, vote_round))
 
     def record_block(self, start_slot: int, block: np.ndarray,
                      vote_round: int = 0) -> np.ndarray:
@@ -634,10 +713,8 @@ class TpuQuorumChecker:
         nodes_p[:b] = np.asarray(node_cols, dtype=np.int32)
         rounds_p[:b] = np.asarray(rounds, dtype=np.int32)
         valid[:b] = True
-        self.board, newly = _record_and_check(
-            self.board, slots_p, true_p, nodes_p, rounds_p, valid,
-            self._masks_t, self._meta)
-        return newly
+        return self._votes_kernel(slots_p, true_p, nodes_p, rounds_p,
+                                  valid)
 
     def record_and_check(
         self,
@@ -670,8 +747,8 @@ class TpuQuorumChecker:
                 import warnings
 
                 warnings.warn(
-                    f"TpuQuorumChecker: vote for slot {lowest} trails the "
-                    f"frontier ({self._max_slot_seen}) by >= window "
+                    f"{type(self).__name__}: vote for slot {lowest} trails "
+                    f"the frontier ({self._max_slot_seen}) by >= window "
                     f"({self.window}); straggler votes may be silently "
                     f"dropped -- raise `window` above the max slots in "
                     f"flight (further violations counted in "
@@ -679,6 +756,45 @@ class TpuQuorumChecker:
                     RuntimeWarning, stacklevel=3)
         if highest > self._max_slot_seen:
             self._max_slot_seen = highest
+
+    def release(self, slots: Sequence[int] | np.ndarray) -> None:
+        """GC slot columns below the chosen watermark so the ring can wrap."""
+        slots = np.asarray(slots, dtype=np.int32) % self.window
+        valid = np.ones(slots.shape[0], dtype=bool)
+        self.board = _release(self.board, slots, valid)
+
+
+class TpuQuorumChecker(_BoardChecker):
+    """Stateful batched quorum checking for one quorum predicate.
+
+    Typical use (ProxyLeader Phase2b path)::
+
+        checker = TpuQuorumChecker(qs.write_spec(), window=1 << 20)
+        # hot path: contiguous slot block, dense [n, B] arrival mask
+        newly = checker.record_block(start_slot, arrivals, round=3)
+        # thin tail: out-of-order votes
+        newly = checker.record_and_check(slots, acceptor_cols, rounds)
+
+    One call per event-loop drain, thousands of votes per call.
+    """
+
+    def __init__(self, spec: QuorumSpec, window: int, mesh=None):
+        """``mesh``: see :meth:`_BoardChecker._init_board`."""
+        self.spec = spec
+        self.num_nodes = spec.num_nodes
+        self._masks_t, self._meta = _spec_statics(spec)
+        self._init_board(window, spec.num_nodes, mesh)
+
+    def _block_kernel(self, staged):
+        self.board, newly = _record_block(self.board, staged,
+                                          self._masks_t, self._meta)
+        return newly
+
+    def _votes_kernel(self, slots, true_slots, nodes, rounds, valid):
+        self.board, newly = _record_and_check(
+            self.board, slots, true_slots, nodes, rounds, valid,
+            self._masks_t, self._meta)
+        return newly
 
     def reshape(self, new_spec: QuorumSpec) -> None:
         """Epoch reshape: remap the live board's ACCEPTOR axis onto
@@ -706,12 +822,6 @@ class TpuQuorumChecker:
         self.num_nodes = new_spec.num_nodes
         self._masks_t, self._meta = _spec_statics(new_spec)
 
-    def release(self, slots: Sequence[int] | np.ndarray) -> None:
-        """GC slot columns below the chosen watermark so the ring can wrap."""
-        slots = np.asarray(slots, dtype=np.int32) % self.window
-        valid = np.ones(slots.shape[0], dtype=bool)
-        self.board = _release(self.board, slots, valid)
-
     def check_batch(self, present: np.ndarray) -> np.ndarray:
         """Stateless: evaluate the predicate for ``[B, N]`` responder rows."""
         return np.asarray(_check_batch(np.asarray(present), self._masks_t,
@@ -724,7 +834,7 @@ _MIN_PLANES = 128
 _ROW_TILE = 8
 
 
-class EpochSegmentedChecker:
+class EpochSegmentedChecker(_BoardChecker):
     """Quorum checking where each SLOT selects its epoch's predicate.
 
     The reconfiguration (paxepoch) shape: epochs partition slot space
@@ -732,9 +842,11 @@ class EpochSegmentedChecker:
     start_{k+1})``), each with its own acceptor set and QuorumSpec.
     Specs are padded into one ``[K, G, N]`` plane stack over the UNION
     universe (``quorums.spec.pad_specs``), and every kernel selects a
-    slot's plane by ``searchsorted`` over the activation boundaries --
-    so ONE fused call (stateless ``check_batch`` or the stateful
-    scatter ``record_and_check``) spans the handover boundary instead
+    slot's plane by its slot number against the activation boundaries
+    -- so ONE fused call (stateless ``check_batch``, the stateful
+    scatter ``record_and_check`` or the stateful dense
+    ``record_block``, which take and pad what
+    :class:`TpuQuorumChecker`'s do) spans the handover boundary instead
     of splitting the drain at it.
 
     ``add_epoch`` grows the stack in place: specs reindex onto the
@@ -762,7 +874,6 @@ class EpochSegmentedChecker:
         if list(boundaries) != sorted(boundaries):
             raise ValueError(
                 f"epoch boundaries must be nondecreasing: {boundaries}")
-        self.window = window
         self.mesh = mesh
         # Per-epoch specs in their OWN universes; the union universe is
         # first-seen order so adding an epoch only APPENDS columns
@@ -772,9 +883,27 @@ class EpochSegmentedChecker:
         self._starts = [int(b) for b in boundaries]
         self.universe: tuple = ()
         self._rebuild_universe()
-        self.board = make_vote_board(window, self._rows)
-        if mesh is not None:
-            self.board = _shard_board(self.board, mesh, window)
+        self._init_board(window, self._rows, mesh)
+        self.board = _pin_board(self.board, mesh)
+
+    @property
+    def num_nodes(self) -> int:
+        """The board's rows, which a dense block must have: the union
+        universe padded to whole tiles."""
+        return self._rows
+
+    def _block_kernel(self, staged):
+        self.board, newly = _record_block_epochs(
+            self.board, staged, self._boundaries, self._masks,
+            self._thresholds, self._combine_any)
+        return newly
+
+    def _votes_kernel(self, slots, true_slots, nodes, rounds, valid):
+        self.board, newly = _record_and_check_epochs(
+            self.board, slots, true_slots, nodes, rounds, valid,
+            self._boundaries, self._masks, self._thresholds,
+            self._combine_any)
+        return newly
 
     def _rebuild_universe(self) -> None:
         seen: dict = {}
@@ -843,9 +972,10 @@ class EpochSegmentedChecker:
         cmap = np.full(self._rows, -1, dtype=np.int32)
         cmap[:len(self.universe)] = epoch_column_map(universe,
                                                     self.universe)
-        self.board = VoteBoard(
+        self.board = _pin_board(VoteBoard(
             votes=_reshape_columns(board.votes, cmap),
-            rounds=board.rounds, chosen=board.chosen, owner=board.owner)
+            rounds=board.rounds, chosen=board.chosen, owner=board.owner),
+            self.mesh)
 
     def adopt(self, checker: "TpuQuorumChecker") -> None:
         """Continue on ``checker``'s live board: its acceptor axis is
@@ -887,45 +1017,6 @@ class EpochSegmentedChecker:
         slots = start_slot + np.arange(b, dtype=np.int64)
         return self.check_batch(np.asarray(block, dtype=np.uint8).T,
                                 slots)
-
-    def record_and_check(
-        self,
-        slots: Sequence[int] | np.ndarray,
-        node_cols: Sequence[int] | np.ndarray,
-        rounds: Sequence[int] | np.ndarray | None = None,
-    ) -> np.ndarray:
-        """Stateful sparse path (the TpuQuorumChecker scatter shape):
-        record votes on the union-universe board and return the
-        per-vote "slot newly has quorum" mask, each slot judged under
-        its epoch's spec."""
-        slots = np.asarray(slots, dtype=np.int64)
-        b = slots.shape[0]
-        if rounds is None:
-            rounds = np.zeros(b, dtype=np.int32)
-        pad = 64
-        while pad < b:
-            pad *= 2
-        slots_p = np.zeros(pad, dtype=np.int32)
-        true_p = np.zeros(pad, dtype=np.int32)
-        nodes_p = np.zeros(pad, dtype=np.int32)
-        rounds_p = np.zeros(pad, dtype=np.int32)
-        valid = np.zeros(pad, dtype=bool)
-        slots_p[:b] = slots % self.window
-        true_p[:b] = slots
-        nodes_p[:b] = np.asarray(node_cols, dtype=np.int32)
-        rounds_p[:b] = np.asarray(rounds, dtype=np.int32)
-        valid[:b] = True
-        self.board, newly = _record_and_check_epochs(
-            self.board, slots_p, true_p, nodes_p, rounds_p, valid,
-            self._boundaries, self._masks, self._thresholds,
-            self._combine_any)
-        return np.asarray(newly)[:b]
-
-    def release(self, slots: Sequence[int] | np.ndarray) -> None:
-        """GC chosen columns below the watermark (ring wrap)."""
-        slots = np.asarray(slots, dtype=np.int32) % self.window
-        valid = np.ones(slots.shape[0], dtype=bool)
-        self.board = _release(self.board, slots, valid)
 
 
 class MultiConfigQuorumChecker:

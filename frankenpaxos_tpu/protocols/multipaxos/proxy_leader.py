@@ -115,7 +115,11 @@ class ProxyLeader(Actor):
             collectors.counter(
                 "multipaxos_proxy_leader_epoch_votes_total"),
             collectors.counter(
-                "multipaxos_proxy_leader_epoch_launches_total"))
+                "multipaxos_proxy_leader_epoch_launches_total"),
+            # Of the votes, those that reached the device in a dense
+            # block: how often a drain is one launch.
+            collectors.counter(
+                "multipaxos_proxy_leader_epoch_dense_votes_total"))
         self._epoch_published = (0,) * len(self.metrics_epoch_work)
         self.metrics_epoch_planes = collectors.gauge(
             "multipaxos_proxy_leader_epoch_planes")
@@ -571,8 +575,9 @@ class ProxyLeader(Actor):
             self._hand_over_dispatches()
         if self._epoch_tracker is not None \
                 and self._epoch_tracker.has_votes():
-            # Stage ``epoch-drain``: the epoch tracker's whole check,
-            # its device round trips included (it fetches on the loop).
+            # Stage ``epoch-drain``: the epoch tracker's whole check:
+            # the drain's plan, its launches (one dense block as a
+            # rule) and the fetch of their answers, here on the loop.
             with self.trace_stage("epoch-drain"):
                 chosen = self._epoch_tracker.drain()
             self._publish_epoch_counts()
@@ -624,7 +629,7 @@ class ProxyLeader(Actor):
         """The epoch tracker's work counts into /metrics, as
         increments (as :meth:`_publish_tpu_counts`)."""
         t = self._epoch_tracker
-        counts = (t.votes, t.launches)
+        counts = (t.votes, t.launches, t.dense_votes)
         for series, now, then in zip(self.metrics_epoch_work, counts,
                                      self._epoch_published):
             series.inc(now - then)

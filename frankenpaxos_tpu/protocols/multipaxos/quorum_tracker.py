@@ -8,7 +8,9 @@ implementations:
     keyed (slot, round) accumulating (group, acceptor) votes. The oracle.
   * ``TpuQuorumTracker`` -- votes buffered per event-loop drain, then
     dispatched to ``TpuQuorumChecker``'s vote board on the device (one
-    ``record_block`` a drain as a rule) and collected off the loop.
+    ``record_block`` a drain as a rule: ``BoardDrainPlanner``, which
+    ``reconfig.EpochQuorumTracker`` plans its drains with too) and
+    collected off the loop.
     Acceptor coordinates flatten to columns ``group * group_size + index``.
     In non-flexible mode only a slot's own group is ever messaged, so a
     universe-wide count >= f+1 threshold is exactly the per-group f+1
@@ -98,71 +100,62 @@ class DictQuorumTracker(QuorumTracker):
         return newly
 
 
-class TpuQuorumTracker(QuorumTracker):
-    """Every vote goes to the stateful vote board on the device.
+def expand_votes(slots: list, cols: list, rounds: list, ranges: list,
+                 array_votes: list) -> tuple:
+    """A drain's three vote buffers (single votes as three lists, ranges
+    ``(start, end, col, round)``, packed arrays ``(slots, col, rounds)``)
+    as three arrays, one entry a vote, in that order. Vectorized: the
+    whole point of Phase2bRange / Phase2bVotes is no per-slot Python
+    before the device call."""
+    slots = np.asarray(slots, dtype=np.int64)
+    cols = np.asarray(cols, dtype=np.int32)
+    rounds = np.asarray(rounds, dtype=np.int32)
+    if not (ranges or array_votes):
+        return slots, cols, rounds
+    parts_s = [slots] if slots.size else []
+    parts_c = [cols] if slots.size else []
+    parts_r = [rounds] if slots.size else []
+    for start, end, col, rnd in ranges:
+        width = end - start
+        parts_s.append(np.arange(start, end, dtype=np.int64))
+        parts_c.append(np.full(width, col, dtype=np.int32))
+        parts_r.append(np.full(width, rnd, dtype=np.int32))
+    for s_arr, col, r_arr in array_votes:
+        parts_s.append(s_arr)
+        parts_c.append(np.full(s_arr.size, col, dtype=np.int32))
+        parts_r.append(r_arr)
+    return (np.concatenate(parts_s), np.concatenate(parts_c),
+            np.concatenate(parts_r))
 
-    A drain DISPATCHES its votes asynchronously (``record_block`` for
-    dense runs, the scatter for stragglers), returns [] and enqueues an
-    in-flight record; the caller collects completed dispatches via
-    :meth:`take_dispatch` + :meth:`collect` -- from a worker thread
-    (ProxyLeader posts results back onto the event loop) or a flush
-    timer. This overlaps the device->host fetch of one drain's result
-    with the decode of the next drain's messages, at the cost of one
-    dispatch of added choose latency; the board must see every vote
-    because results are not available within the drain.
 
-    The tracker counts its work: ``device_drains`` and the
-    ``device_votes`` they carried, and ``device_launches``, the jitted
-    calls those drains made, dense or sparse: one a drain is the common
-    case, and more says what splits drains (a ring straddle, several
-    rounds, a sparse tail)."""
+class BoardDrainPlanner:
+    """How a drain's votes reach a vote board, and what its device calls
+    chose: the ONE plan of ``TpuQuorumTracker`` and of
+    ``reconfig.EpochQuorumTracker``'s ``tpu`` backend, over whichever
+    board checker it is handed (``ops.quorum.TpuQuorumChecker`` judges
+    every column by one predicate, ``EpochSegmentedChecker`` each by its
+    slot's epoch; the plan does not know which).
 
-    # Read by benchmark/harness/role_entry.py::TRACKER_COUNTERS, which
-    # still names the host tally that is gone: they can only read 0
-    # (ROADMAP.md M6 drops them there, then here).
-    host_drains = 0
-    host_votes = 0
-    spilled_votes = 0
+    :meth:`dispatch` turns a drain's buffered votes into asynchronous
+    device calls, one dense ``record_block`` as a rule:
+    votes in rounds OLDER than the dominant round go first (scattered),
+    so that an old-round quorum completing in this drain is reported
+    before the newer round's preemption clears it; the dominant round's
+    slots are clustered into contiguous runs and chunked at the
+    prewarmed bucket widths; what is too thin for a block, and newer
+    rounds, take the scatter in chunks of ``max_chunk``. It returns the
+    calls' ``parts``; :meth:`fetch` waits for them and reports each
+    (slot, round) exactly once.
 
-    def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
-                 mesh=None):
-        import collections
+    ``votes`` counts the votes dispatched, ``launches`` the jitted
+    calls they made, dense or sparse, and ``dense_votes`` the votes
+    that reached the device in a dense block (a block that straddles
+    the ring end counts its cells)."""
 
-        self.config = config
-        self.device_drains = 0
-        self.device_launches = 0
-        self.device_votes = 0
-        # In-flight dispatches: (slots, rounds, device per-vote masks).
-        # append/popleft are GIL-atomic, so a collector thread may pop
-        # while the event loop appends.
-        self._inflight = collections.deque()
-        self._row_size = len(config.acceptor_addresses[0])
-        num_cols = config.num_acceptor_groups * self._row_size
-        universe = tuple(range(num_cols))
-        if config.flexible:
-            spec = config.quorum_grid().write_spec().reindexed(universe)
-        else:
-            spec = QuorumSpec(
-                masks=np.ones((1, num_cols), dtype=np.uint8),
-                thresholds=np.array([config.f + 1], dtype=np.int32),
-                combine=ANY,
-                universe=universe,
-            )
-        # Lazy: keeps jax out of dict-backend role processes entirely
-        # (it costs seconds of startup per process).
-        from frankenpaxos_tpu.ops.quorum import TpuQuorumChecker
-
-        self.checker = TpuQuorumChecker(spec, window=window, mesh=mesh)
-        self._slots: list[int] = []
-        self._cols: list[int] = []
-        self._rounds: list[int] = []
-        # Ranged votes (Phase2bRange): [(start, end, col, round)] --
-        # O(1) Python per message, expanded vectorized at drain time.
-        self._ranges: list[tuple[int, int, int, int]] = []
-        # Packed array votes (Phase2bVotes): [(slots, col, rounds)] --
-        # O(1) Python per message, arrays straight off the native
-        # codec's unpack.
-        self._array_votes: list = []
+    def __init__(self, window: int):
+        self.votes = 0
+        self.launches = 0
+        self.dense_votes = 0
         # Exactly-once reporting, vectorized. Each device call answers
         # with what IT newly chose, and one drain can be several calls
         # (an older round's scatter before the dense block, a ring
@@ -196,85 +189,33 @@ class TpuQuorumTracker(QuorumTracker):
         # A dominant-round cluster goes dense when it's at least this
         # filled; emptier clusters cost fewer device calls via scatter.
         self.min_fill = 0.25
-        # Pre-compile every bucket at construction -- before client
-        # traffic -- so the first real drains don't stall on XLA
-        # compiles. Prewarm votes land at round -1 (below any real
-        # round), and release() clears the touched columns (including
-        # the ring owners the prewarm claimed).
+
+    def prewarm(self, checker) -> None:
+        """Compile every width a plan can launch on ``checker``'s board
+        (the dense buckets and the scatter's two) -- at construction,
+        before client traffic -- so that no drain stalls on an XLA
+        compile. Prewarm votes land at round -1 (below any real round),
+        and release() clears the touched columns (including the ring
+        owners the prewarm claimed)."""
         for width in self.dense_buckets:
-            warm = np.zeros((self.checker.num_nodes, width),
-                            dtype=np.uint8)
+            warm = np.zeros((checker.num_nodes, width), dtype=np.uint8)
             warm[0, 0] = 1
-            self.checker.record_block(0, warm, vote_round=-1)
+            checker.record_block(0, warm, vote_round=-1)
         for width in (1, self.max_chunk):
-            self.checker.record_and_check([0] * width, [0] * width,
-                                          [-1] * width)
-        self.checker.release(np.arange(self.max_dense))
+            checker.record_and_check([0] * width, [0] * width,
+                                     [-1] * width)
+        checker.release(np.arange(self.max_dense))
 
-    def record(self, slot, round, group_index, acceptor_index) -> None:
-        self._slots.append(slot)
-        self._cols.append(group_index * self._row_size + acceptor_index)
-        self._rounds.append(round)
-
-    def record_range(self, slot_start, slot_end, round, group_index,
-                     acceptor_index) -> None:
-        if slot_end <= slot_start:
-            # Drop empties like record_votes does.
-            return
-        self._ranges.append((slot_start, slot_end,
-                             group_index * self._row_size
-                             + acceptor_index, round))
-
-    def record_votes(self, slots, rounds, group_index,
-                     acceptor_index) -> None:
-        slots = np.asarray(slots, dtype=np.int64)
-        if not slots.size:
-            # Drop empties at the door: every drain path assumes
-            # non-empty entries (round scans, frontier max, rounds[0]).
-            return
-        self._array_votes.append(
-            (slots, group_index * self._row_size + acceptor_index,
-             np.asarray(rounds, dtype=np.int32)))
-
-    def has_votes(self) -> bool:
-        return bool(self._slots or self._ranges or self._array_votes)
-
-    def drain(self) -> list[tuple[int, int]]:
-        """Dispatch this drain's votes onto the stateful vote board
-        asynchronously (usually one device call); results are collected
-        later (take_dispatch + collect), so this returns []. Sparse
-        stragglers and off-round votes go through the scatter path;
-        votes in rounds OLDER than the dominant round dispatch BEFORE
-        the dense block so an old-round quorum completing in this drain
-        is reported before the newer round's preemption clears it."""
-        if not self.has_votes():
-            return []
+    def dispatch(self, checker, slots: list, cols: list, rounds: list,
+                 ranges: list, array_votes: list) -> list:
+        """Launch a drain's votes (a tracker's three buffers, as
+        :func:`expand_votes` takes them, at least one vote in all) onto
+        ``checker``'s board without waiting; the calls' parts, for
+        :meth:`fetch`."""
+        slots, cols, rounds = expand_votes(slots, cols, rounds, ranges,
+                                           array_votes)
+        self.votes += slots.shape[0]
         parts: list[tuple] = []
-        slots = np.asarray(self._slots, dtype=np.int64)
-        cols = np.asarray(self._cols, dtype=np.int32)
-        rounds = np.asarray(self._rounds, dtype=np.int32)
-        if self._ranges or self._array_votes:
-            # Expand ranged/packed votes vectorized (the whole point of
-            # Phase2bRange/Phase2bVotes: no per-slot Python before this
-            # point).
-            parts_s = [slots] if slots.size else []
-            parts_c = [cols] if slots.size else []
-            parts_r = [rounds] if slots.size else []
-            for start, end, col, rnd in self._ranges:
-                width = end - start
-                parts_s.append(np.arange(start, end, dtype=np.int64))
-                parts_c.append(np.full(width, col, dtype=np.int32))
-                parts_r.append(np.full(width, rnd, dtype=np.int32))
-            for s_arr, col, r_arr in self._array_votes:
-                parts_s.append(s_arr)
-                parts_c.append(np.full(s_arr.size, col, dtype=np.int32))
-                parts_r.append(r_arr)
-            slots = np.concatenate(parts_s)
-            cols = np.concatenate(parts_c)
-            rounds = np.concatenate(parts_r)
-        self.device_drains += 1
-        self.device_votes += slots.shape[0]
-
         # The drain's dominant round (fast path: single-round drain).
         if rounds[0] == rounds[-1] and (rounds == rounds[0]).all():
             dom = int(rounds[0])
@@ -286,15 +227,12 @@ class TpuQuorumTracker(QuorumTracker):
                           None) if width <= self.max_dense else None
             if (bucket is not None
                     and slots.shape[0] >= width * self.min_fill):
-                block = np.zeros((self.checker.num_nodes, bucket),
+                block = np.zeros((checker.num_nodes, bucket),
                                  dtype=np.uint8)
                 block[cols, slots - lo] = 1
-                self._record_board(parts, lo, block, bucket, dom)
-                self._slots, self._cols, self._rounds = [], [], []
-                self._ranges = []
-                self._array_votes = []
-                self._inflight.append(parts)
-                return []
+                self._record_board(checker, parts, lo, block, bucket,
+                                   dom, slots.shape[0])
+                return parts
             dense_idx = np.arange(slots.shape[0])
             pre = post = None
         else:
@@ -305,7 +243,7 @@ class TpuQuorumTracker(QuorumTracker):
             pre = np.flatnonzero(rounds < dom)
             post = np.flatnonzero(rounds > dom)
         if pre is not None and pre.size:
-            self._dispatch_sparse(parts, slots, cols, rounds, pre)
+            self._dispatch_sparse(checker, parts, slots, cols, rounds, pre)
 
         # Cluster the dominant round's slots into contiguous runs.
         ds = slots[dense_idx]
@@ -338,37 +276,37 @@ class TpuQuorumTracker(QuorumTracker):
                 j = int(np.searchsorted(cs, start + bucket))
                 members = cl[i:j]
                 block = np.zeros(
-                    (self.checker.num_nodes, bucket), dtype=np.uint8)
+                    (checker.num_nodes, bucket), dtype=np.uint8)
                 block[cols[members], slots[members] - start] = 1
-                self._record_board(parts, start, block, bucket, dom)
+                self._record_board(checker, parts, start, block, bucket,
+                                   dom, members.size)
                 i = j
 
         for cl in sparse_leftover:
-            self._dispatch_sparse(parts, slots, cols, rounds, cl)
+            self._dispatch_sparse(checker, parts, slots, cols, rounds, cl)
         if post is not None and post.size:
-            self._dispatch_sparse(parts, slots, cols, rounds, post)
+            self._dispatch_sparse(checker, parts, slots, cols, rounds,
+                                  post)
+        return parts
 
-        self._slots, self._cols, self._rounds = [], [], []
-        self._ranges = []
-        self._array_votes = []
-        self._inflight.append(parts)
-        return []
-
-    def _record_board(self, parts: list, start: int, block: np.ndarray,
-                      bucket: int, rnd: int) -> None:
-        """Record a dense run on the vote board, splitting at the ring
-        end (record_block's no-straddle contract)."""
-        window = self.checker.window
-        room = window - start % window
+    def _record_board(self, checker, parts: list, start: int,
+                      block: np.ndarray, bucket: int, rnd: int,
+                      votes: int) -> None:
+        """Record a dense run of ``votes`` votes on the vote board,
+        splitting at the ring end (record_block's no-straddle
+        contract)."""
+        room = checker.window - start % checker.window
         if bucket <= room:
-            newly = self.checker.record_block_async(start, block,
-                                                    vote_round=rnd)
-            self.device_launches += 1
+            newly = checker.record_block_async(start, block,
+                                               vote_round=rnd)
+            self.launches += 1
+            self.dense_votes += votes
             parts.append(("block", start, bucket, rnd, newly))
         else:
-            self._record_board_split(parts, start, block, room, rnd)
+            self._record_board_split(checker, parts, start, block, room,
+                                     rnd)
 
-    def _record_board_split(self, parts: list, start: int,
+    def _record_board_split(self, checker, parts: list, start: int,
                             block: np.ndarray, room: int,
                             rnd: int) -> None:
         """Record a block that straddles the ring end WITHOUT compiling
@@ -376,13 +314,14 @@ class TpuQuorumTracker(QuorumTracker):
         bucket widths, and sub-bucket remainders take the (prewarmed)
         scatter path. A mid-run XLA compile would stall the event loop
         for seconds."""
-        self._record_board_bucketed(parts, start, block[:, :room], rnd)
+        self._record_board_bucketed(checker, parts, start,
+                                    block[:, :room], rnd)
         rest = block[:, room:]
         if rest.any():
-            self._record_board_bucketed(parts, start + room,
+            self._record_board_bucketed(checker, parts, start + room,
                                         np.ascontiguousarray(rest), rnd)
 
-    def _record_board_bucketed(self, parts: list, start: int,
+    def _record_board_bucketed(self, checker, parts: list, start: int,
                                block: np.ndarray, rnd: int) -> None:
         width = block.shape[1]
         i = 0
@@ -394,43 +333,35 @@ class TpuQuorumTracker(QuorumTracker):
                 rows, pos = np.nonzero(block[:, i:])
                 if rows.size:
                     self._dispatch_sparse(
-                        parts, (start + i + pos).astype(np.int64),
+                        checker, parts,
+                        (start + i + pos).astype(np.int64),
                         rows.astype(np.int32),
                         np.full(rows.size, rnd, dtype=np.int32),
                         np.arange(rows.size))
                 return
             sub = block[:, i:i + bucket]
             if sub.any():
-                newly = self.checker.record_block_async(
+                newly = checker.record_block_async(
                     start + i, np.ascontiguousarray(sub), vote_round=rnd)
-                self.device_launches += 1
+                self.launches += 1
+                self.dense_votes += int(sub.sum())
                 parts.append(("block", start + i, bucket, rnd, newly))
             i += bucket
 
-    def _dispatch_sparse(self, parts, slots, cols, rounds, idx) -> None:
+    def _dispatch_sparse(self, checker, parts, slots, cols, rounds,
+                         idx) -> None:
         """Scatter-path dispatch, chunked so only prewarmed widths run."""
         for at in range(0, idx.size, self.max_chunk):
             chunk = idx[at:at + self.max_chunk]
-            self.device_launches += 1
+            self.launches += 1
             parts.append(("votes", slots[chunk], rounds[chunk],
-                          self.checker.record_and_check_async(
+                          checker.record_and_check_async(
                               slots[chunk], cols[chunk], rounds[chunk],
                               pad_to=(64 if chunk.size <= 64
                                       else self.max_chunk)),
                           chunk.size))
 
-    def has_pending(self) -> bool:
-        return bool(self._inflight)
-
-    def take_dispatch(self):
-        """Pop the oldest in-flight dispatch (None if empty); pass it to
-        :meth:`collect`. Safe to call from a collector thread."""
-        try:
-            return self._inflight.popleft()
-        except IndexError:
-            return None
-
-    def collect(self, dispatch) -> list[tuple[int, int]]:
+    def fetch(self, parts) -> list[tuple[int, int]]:
         """Fetch a dispatch's results (blocking on the device for any
         part not done yet) and dedup per slot, keeping each slot's
         first reporting round in part order (as the dict oracle's
@@ -441,7 +372,7 @@ class TpuQuorumTracker(QuorumTracker):
         ``("votes", slots, rounds, device_mask, n)`` -- a per-vote mask
         from the scatter path."""
         out: list[tuple[int, int]] = []
-        for part in dispatch:
+        for part in parts:
             kind = part[0]
             if kind == "block":
                 _, start, width, rnd, mask = part
@@ -482,3 +413,137 @@ class TpuQuorumTracker(QuorumTracker):
         self._dedup_round[fi] = np.asarray(rounds)[fresh] \
             if isinstance(rounds, np.ndarray) else rounds
         return fresh
+
+
+class TpuQuorumTracker(QuorumTracker):
+    """Every vote goes to the stateful vote board on the device.
+
+    A drain DISPATCHES its votes asynchronously (:class:`BoardDrainPlanner`:
+    ``record_block`` for dense runs, the scatter for stragglers),
+    returns [] and enqueues an in-flight record; the caller collects
+    completed dispatches via :meth:`take_dispatch` + :meth:`collect` --
+    from a worker thread (ProxyLeader posts results back onto the event
+    loop) or a flush timer. This overlaps the device->host fetch of one
+    drain's result with the decode of the next drain's messages, at the
+    cost of one dispatch of added choose latency; the board must see
+    every vote because results are not available within the drain.
+
+    The tracker counts its work: ``device_drains`` and the
+    ``device_votes`` they carried, and ``device_launches``, the jitted
+    calls those drains made, dense or sparse: one a drain is the common
+    case, and more says what splits drains (a ring straddle, several
+    rounds, a sparse tail)."""
+
+    # Read by benchmark/harness/role_entry.py::TRACKER_COUNTERS, which
+    # still names the host tally that is gone: they can only read 0
+    # (ROADMAP.md M6 drops them there, then here).
+    host_drains = 0
+    host_votes = 0
+    spilled_votes = 0
+
+    def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
+                 mesh=None):
+        import collections
+
+        self.config = config
+        self.device_drains = 0
+        # In-flight dispatches: each the parts of one drain's plan.
+        # append/popleft are GIL-atomic, so a collector thread may pop
+        # while the event loop appends.
+        self._inflight = collections.deque()
+        self._row_size = len(config.acceptor_addresses[0])
+        num_cols = config.num_acceptor_groups * self._row_size
+        universe = tuple(range(num_cols))
+        if config.flexible:
+            spec = config.quorum_grid().write_spec().reindexed(universe)
+        else:
+            spec = QuorumSpec(
+                masks=np.ones((1, num_cols), dtype=np.uint8),
+                thresholds=np.array([config.f + 1], dtype=np.int32),
+                combine=ANY,
+                universe=universe,
+            )
+        self._planner = BoardDrainPlanner(window)
+        # Lazy: keeps jax out of dict-backend role processes entirely
+        # (it costs seconds of startup per process).
+        from frankenpaxos_tpu.ops.quorum import TpuQuorumChecker
+
+        self.checker = TpuQuorumChecker(spec, window=window, mesh=mesh)
+        self._slots: list[int] = []
+        self._cols: list[int] = []
+        self._rounds: list[int] = []
+        # Ranged votes (Phase2bRange): [(start, end, col, round)] --
+        # O(1) Python per message, expanded vectorized at drain time.
+        self._ranges: list[tuple[int, int, int, int]] = []
+        # Packed array votes (Phase2bVotes): [(slots, col, rounds)] --
+        # O(1) Python per message, arrays straight off the native
+        # codec's unpack.
+        self._array_votes: list = []
+        self._planner.prewarm(self.checker)
+
+    @property
+    def device_votes(self) -> int:
+        return self._planner.votes
+
+    @property
+    def device_launches(self) -> int:
+        return self._planner.launches
+
+    def record(self, slot, round, group_index, acceptor_index) -> None:
+        self._slots.append(slot)
+        self._cols.append(group_index * self._row_size + acceptor_index)
+        self._rounds.append(round)
+
+    def record_range(self, slot_start, slot_end, round, group_index,
+                     acceptor_index) -> None:
+        if slot_end <= slot_start:
+            # Drop empties like record_votes does.
+            return
+        self._ranges.append((slot_start, slot_end,
+                             group_index * self._row_size
+                             + acceptor_index, round))
+
+    def record_votes(self, slots, rounds, group_index,
+                     acceptor_index) -> None:
+        slots = np.asarray(slots, dtype=np.int64)
+        if not slots.size:
+            # Drop empties at the door: every drain path assumes
+            # non-empty entries (round scans, frontier max, rounds[0]).
+            return
+        self._array_votes.append(
+            (slots, group_index * self._row_size + acceptor_index,
+             np.asarray(rounds, dtype=np.int32)))
+
+    def has_votes(self) -> bool:
+        return bool(self._slots or self._ranges or self._array_votes)
+
+    def drain(self) -> list[tuple[int, int]]:
+        """Dispatch this drain's votes onto the stateful vote board
+        asynchronously (usually one device call); results are collected
+        later (take_dispatch + collect), so this returns []."""
+        if not self.has_votes():
+            return []
+        self.device_drains += 1
+        self._inflight.append(self._planner.dispatch(
+            self.checker, self._slots, self._cols, self._rounds,
+            self._ranges, self._array_votes))
+        self._slots, self._cols, self._rounds = [], [], []
+        self._ranges = []
+        self._array_votes = []
+        return []
+
+    def has_pending(self) -> bool:
+        return bool(self._inflight)
+
+    def take_dispatch(self):
+        """Pop the oldest in-flight dispatch (None if empty); pass it to
+        :meth:`collect`. Safe to call from a collector thread."""
+        try:
+            return self._inflight.popleft()
+        except IndexError:
+            return None
+
+    def collect(self, dispatch) -> list[tuple[int, int]]:
+        """Fetch a dispatch's results and report each (slot, round)
+        once (:meth:`BoardDrainPlanner.fetch`)."""
+        return self._planner.fetch(dispatch)

@@ -9,12 +9,17 @@ EPOCH's spec, resolved through the ``EpochStore``. Two backends:
   * ``dict`` -- the oracle: per-(slot, round) voter sets checked with
     ``EpochConfig.has_write_quorum`` (set intersection, the reference
     semantics). Counts only the slot's epoch's members.
-  * ``tpu`` -- one ``ops.quorum.EpochSegmentedChecker`` scatter per
-    event-loop drain over the store's union universe; the epoch plane
-    is selected per slot INSIDE the fused kernel, so a drain spanning
-    the handover boundary stays one dispatch. Non-member votes land in
-    columns the epoch's mask zeroes -- they can never complete a
-    quorum they do not belong to.
+  * ``tpu`` -- an ``ops.quorum.EpochSegmentedChecker`` board over the
+    store's union universe, fed as ``TpuQuorumTracker`` feeds its own:
+    the same three buffers (O(1) Python a message) and the same plan of
+    a drain (``quorum_tracker.BoardDrainPlanner``: one dense block a
+    drain as a rule). The epoch plane is selected per slot INSIDE the
+    kernel, so a drain spanning any number of handover boundaries
+    stays one dispatch. Non-member votes land in rows the epoch's mask
+    zeroes -- they can never complete a quorum they do not belong to.
+    What is this backend's own: voter address to row through the
+    store, the plane stack following the store, the adopted board. A
+    drain's answer is fetched inside :meth:`drain` and returned by it.
 
 Both report each (slot, round)'s quorum exactly once (the dict's Done
 sentinel; the board's chosen bitmap).
@@ -39,28 +44,43 @@ class EpochQuorumTracker:
         # once reported (Done).
         self._states: dict = {}
         self._newly: list = []
-        # tpu backend: per-drain vote buffer + the segmented checker.
+        # tpu backend: TpuQuorumTracker's three per-drain buffers (single
+        # votes, ranges (start, end, row, round), packed arrays (slots,
+        # row, rounds)), the drain planner and the segmented checker.
         self._checker = None
+        self._planner = None
         self._slots: list = []
         self._cols: list = []
         self._rounds: list = []
-        self._chunk = 256
-        # Work counts (ProxyLeader publishes them): votes handed to
-        # drain() and the jitted calls they made, one a chunk.
-        self.votes = 0
-        self.launches = 0
+        self._ranges: list = []
+        self._array_votes: list = []
         if backend == "tpu":
             from frankenpaxos_tpu.ops.quorum import EpochSegmentedChecker
+            from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker \
+                import BoardDrainPlanner
 
             specs, starts = store.specs_and_boundaries()
+            self._planner = BoardDrainPlanner(window)
             self._checker = EpochSegmentedChecker(specs, starts,
                                                   window=window)
-            # Prewarm every scatter bucket a drain's chunks can pad to,
-            # before this tracker's first votes.
-            for width in (64, 128, self._chunk):
-                self._checker.record_and_check(
-                    [0] * width, [0] * width, [-1] * width)
-            self._checker.release([0])
+            # Every width a drain can launch, before this tracker's
+            # first votes: no drain and no reconfiguration compiles.
+            self._planner.prewarm(self._checker)
+
+    # The tpu backend's work counts (ProxyLeader publishes them): votes
+    # handed to drain(), the jitted calls they made, dense or sparse,
+    # and the votes that reached the device in a dense block.
+    @property
+    def votes(self) -> int:
+        return self._planner.votes if self._planner else 0
+
+    @property
+    def launches(self) -> int:
+        return self._planner.launches if self._planner else 0
+
+    @property
+    def dense_votes(self) -> int:
+        return self._planner.dense_votes if self._planner else 0
 
     def note_epochs(self) -> None:
         """Refresh after the store committed new epochs. Pure appends
@@ -91,7 +111,7 @@ class EpochQuorumTracker:
                 # new board (a quorum one real vote short). Drop them
                 # -- they voted for the superseded definition's
                 # proposals, which protocol-level resends re-drive.
-                self._slots, self._cols, self._rounds = [], [], []
+                self._drop_buffered()
         self._known = known
 
     @property
@@ -107,6 +127,10 @@ class EpochQuorumTracker:
         dispatched every vote it meant for ``checker`` and never uses
         it again (its buffers are donated to this tracker's calls)."""
         self._checker.adopt(checker)
+
+    def _drop_buffered(self) -> None:
+        self._slots, self._cols, self._rounds = [], [], []
+        self._ranges, self._array_votes = [], []
 
     # --- recording (per message, O(1) Python) ------------------------------
     def record(self, slot: int, round: int, voter) -> None:
@@ -129,10 +153,7 @@ class EpochQuorumTracker:
         col = self.store.column_of(voter)
         if col is None or slot_end <= slot_start:
             return
-        width = slot_end - slot_start
-        self._slots.extend(range(slot_start, slot_end))
-        self._cols.extend([col] * width)
-        self._rounds.extend([round] * width)
+        self._ranges.append((slot_start, slot_end, col, round))
 
     def record_votes(self, slots, rounds, voter) -> None:
         """One voter's votes for an arbitrary slot array (a packed
@@ -143,12 +164,11 @@ class EpochQuorumTracker:
                 self._record_dict(int(slot), int(round), voter)
             return
         col = self.store.column_of(voter)
-        if col is None:
+        slots = np.asarray(slots, dtype=np.int64)
+        if col is None or not slots.size:
             return
-        slots = np.asarray(slots)
-        self._slots.extend(slots.tolist())
-        self._cols.extend([col] * slots.size)
-        self._rounds.extend(np.asarray(rounds).tolist())
+        self._array_votes.append(
+            (slots, col, np.asarray(rounds, dtype=np.int32)))
 
     def _record_dict(self, slot: int, round: int, voter) -> None:
         key = (slot, round)
@@ -169,37 +189,24 @@ class EpochQuorumTracker:
     # --- drain -------------------------------------------------------------
     def has_votes(self) -> bool:
         """Would :meth:`drain` have work (QuorumTracker.has_votes)?"""
-        return bool(self._newly if self.backend == "dict"
-                    else self._slots)
+        if self.backend == "dict":
+            return bool(self._newly)
+        return bool(self._slots or self._ranges or self._array_votes)
 
     def drain(self) -> list:
+        """What this drain's votes chose, each (slot, round) once. The
+        ``tpu`` backend dispatches every part of the drain's plan, then
+        fetches them in order."""
         if self.backend == "dict":
             newly, self._newly = self._newly, []
             return newly
-        if not self._slots:
+        if not self.has_votes():
             return []
-        slots = np.asarray(self._slots, dtype=np.int64)
-        cols = np.asarray(self._cols, dtype=np.int32)
-        rounds = np.asarray(self._rounds, dtype=np.int32)
-        self._slots, self._cols, self._rounds = [], [], []
-        self.votes += slots.size
-        out: list = []
-        seen: set = set()
-        for at in range(0, slots.size, self._chunk):
-            self.launches += 1
-            sl = slots[at:at + self._chunk]
-            newly = self._checker.record_and_check(
-                sl, cols[at:at + self._chunk],
-                rounds[at:at + self._chunk])
-            for i in np.flatnonzero(newly).tolist():
-                key = (int(sl[i]), int(rounds[at + i]))
-                # The board reports every same-batch duplicate of a
-                # newly-chosen slot; exactly-once within the drain is
-                # host-side (cross-drain is the chosen bitmap's job).
-                if key[0] not in seen:
-                    seen.add(key[0])
-                    out.append(key)
-        return out
+        parts = self._planner.dispatch(
+            self._checker, self._slots, self._cols, self._rounds,
+            self._ranges, self._array_votes)
+        self._drop_buffered()
+        return self._planner.fetch(parts)
 
     def release(self, slots) -> None:
         """Watermark GC passthrough (ring wrap for the tpu board)."""

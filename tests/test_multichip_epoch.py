@@ -135,6 +135,43 @@ def test_sharded_reconfig_mid_window_matches_oracle(seed, direction,
             assert not oracle.chosen(slot, voters), (slot, voters)
 
 
+def test_sharded_dense_blocks_match_the_unsharded_board_and_the_oracle(
+        mesh_factory):
+    """``record_block`` of the epoch-segmented checker (ISSUE 38) with
+    the board's slot axis sharded: blocks that cross shard edges and
+    three epoch boundaries, two rounds, report what the unsharded
+    checker reports and leave the same board, and every report agrees
+    with the ``quorums/systems.py`` oracle (tests/test_reconfig.py)."""
+    from tests.test_reconfig import BoardModel, EpochsOracle
+
+    rng = random.Random(38)
+    systems = [SimpleMajority(members) for members in
+               ((0, 1, 2), (3, 4, 5), (1, 3, 5), (0, 2, 4))]
+    starts = [0, 20, 45, 70]
+    checkers = _checkers(mesh_factory,
+                         [s.write_spec() for s in systems], starts)
+    model = BoardModel(EpochsOracle(systems, starts), WINDOW)
+    chosen = 0
+    for start, round in ((0, 0), (30, 0), (0, 0), (30, 1), (30, 1),
+                         (WINDOW + 8, 1), (WINDOW + 8, 1)):
+        votes = [(rng.randrange(64), rng.randrange(6)) for _ in range(100)]
+        block = np.zeros((checkers[0].num_nodes, 64), dtype=np.uint8)
+        for offset, node in votes:
+            block[checkers[0].column_of(node), offset] = 1
+        newlies = [checker.record_block(start, block, vote_round=round)
+                   for checker in checkers]
+        for sharded in newlies[1:]:
+            np.testing.assert_array_equal(sharded, newlies[0])
+        assert {start + int(o) for o in np.flatnonzero(newlies[0])} \
+            == model.block(start, votes, round)
+        chosen += int(newlies[0].sum())
+        for checker in checkers[1:]:
+            for got, want in zip(checker.board, checkers[0].board):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+    assert chosen >= 40
+
+
 def test_window_must_divide_mesh_size(mesh_factory):
     spec = SimpleMajority(range(3)).write_spec()
     with pytest.raises(ValueError, match="multiple of the mesh size"):

@@ -514,3 +514,129 @@ def test_record_block_scalars_survive_the_one_host_buffer(width):
         start_slot + np.arange(width))
     assert (np.asarray(checker.board.rounds)[columns] == vote_round).all()
     assert checker.window_violations == 0
+
+
+# What TpuQuorumTracker did with the drains of ``_planned_drains`` at the
+# commit before the plan moved into ``BoardDrainPlanner`` (824ed9c): per
+# drain its launches in order, how many (slot, round)s it reported, the
+# CRC-32 of that list as int64 pairs, its first three and its last.
+# A block is (start slot, width, round, votes, CRC-32 of the block); a
+# scatter (votes, pad_to, first slot, last slot, CRC-32 of slots, columns
+# and rounds).
+_PLANNED = [
+    ("dense",
+     [("block", 0, 1024, 0, 600, 2157296486)],
+     300, 3794490453, [(0, 0), (1, 0), (2, 0)], [(299, 0)]),
+    ("clustered",
+     [("block", 20000, 256, 0, 200, 1375499983),
+      ("block", 26000, 64, 0, 101, 3804446436)],
+     150, 1745212135, [(20000, 0), (20001, 0), (20002, 0)], [(26049, 0)]),
+    ("multi-round",
+     [("votes", 1, 64, 26055, 26055, 1348293691),
+      ("block", 26050, 1024, 1, 900, 3284542904),
+      ("votes", 2, 64, 26060, 26060, 372353089)],
+     450, 1215162172, [(26055, 0), (26050, 1), (26051, 1)], [(26499, 1)]),
+    ("straddling",
+     [("block", 32068, 256, 1, 512, 3304015762),
+      ("block", 32324, 256, 1, 512, 3304015762),
+      ("block", 32580, 64, 1, 128, 2086209108),
+      ("block", 32644, 64, 1, 128, 2086209108),
+      ("votes", 120, 256, 32708, 32767, 3757369263),
+      ("block", 32768, 1024, 1, 660, 3303458141)],
+     1030, 1803197219, [(32068, 1), (32069, 1), (32070, 1)], [(33097, 1)]),
+    ("sparse tail",
+     [("block", 40600, 256, 1, 200, 1375499983),
+      ("votes", 256, 256, 34000, 36040, 4234635308),
+      ("votes", 44, 64, 36048, 36392, 2027371706)],
+     100, 4230709098, [(40600, 1), (40601, 1), (40602, 1)], [(40699, 1)]),
+    ("re-acks",
+     [("block", 40600, 256, 1, 100, 2717574356),
+      ("votes", 256, 256, 34000, 36040, 1954974375),
+      ("votes", 44, 64, 36048, 36392, 3423951963)],
+     300, 3775700345, [(34000, 1), (34008, 1), (34016, 1)], [(36392, 1)]),
+]
+
+
+def _planned_drains(t, window: int):
+    """Record one drain's votes on ``t``, then yield its name."""
+    # Two acceptors' ranges, one round.
+    t.record_range(0, 300, 0, 0, 0)
+    t.record_range(0, 300, 0, 0, 1)
+    yield "dense"
+    # Two runs 6000 slots apart, packed arrays and single votes; and one
+    # of the two round-0 votes of slot 26055.
+    for acceptor in (0, 2):
+        t.record_votes(np.arange(20000, 20100), np.zeros(100, np.int32),
+                       0, acceptor)
+    for slot in range(26000, 26050):
+        t.record(slot, 0, 0, 1)
+        t.record(slot, 0, 0, 2)
+    t.record(26055, 0, 0, 0)
+    yield "clustered"
+    # Three rounds: the older round's quorum completes, the dominant
+    # round's block covers its slot, a newer round's votes come last.
+    t.record_range(26050, 26500, 1, 0, 0)
+    t.record_range(26050, 26500, 1, 0, 1)
+    t.record(26055, 0, 0, 2)
+    t.record(26060, 2, 0, 0)
+    t.record(26060, 2, 0, 1)
+    yield "multi-round"
+    # A run across the ring's end.
+    for acceptor in (1, 2):
+        t.record_range(2 * window - 700, 2 * window + 330, 1, 0, acceptor)
+    yield "straddling"
+    # A thin cluster, every eighth slot, beside a dense run.
+    t.record_votes(np.arange(34000, 36400, 8), np.ones(300, np.int32), 0, 0)
+    t.record_range(40600, 40700, 1, 0, 0)
+    t.record_range(40600, 40700, 1, 0, 2)
+    yield "sparse tail"
+    # The thin cluster's second votes, and a re-ack of what is chosen.
+    t.record_votes(np.arange(34000, 36400, 8), np.ones(300, np.int32), 0, 1)
+    t.record_range(40600, 40700, 1, 0, 1)
+    yield "re-acks"
+
+
+def test_the_shared_planner_launches_and_reports_as_the_tracker_did():
+    """``TpuQuorumTracker`` over ``BoardDrainPlanner`` (ISSUE 38) makes,
+    for dense, clustered, multi-round, straddling and sparse drains, the
+    launches the tracker made when the plan was its own, argument for
+    argument, and reports the same (slot, round)s in the same order."""
+    import zlib
+
+    from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+        TpuQuorumTracker,
+    )
+
+    window = 1 << 14
+    t = TpuQuorumTracker(_tracker_config(False), window=window)
+    calls: list = []
+    block = t.checker.record_block_async
+    votes = t.checker.record_and_check_async
+
+    def noting_block(start_slot, b, vote_round=0):
+        calls.append(("block", int(start_slot), b.shape[1],
+                      int(vote_round), int(np.count_nonzero(b)),
+                      zlib.crc32(np.ascontiguousarray(b).tobytes())))
+        return block(start_slot, b, vote_round=vote_round)
+
+    def noting_votes(slots, cols, rounds=None, pad_to=None):
+        calls.append(("votes", len(slots), pad_to, int(slots[0]),
+                      int(slots[-1]),
+                      zlib.crc32(np.asarray(slots, np.int64).tobytes()
+                                 + np.asarray(cols, np.int32).tobytes()
+                                 + np.asarray(rounds, np.int32).tobytes())))
+        return votes(slots, cols, rounds, pad_to=pad_to)
+
+    t.checker.record_block_async = noting_block
+    t.checker.record_and_check_async = noting_votes
+    got = []
+    for name in _planned_drains(t, window):
+        del calls[:]
+        reported = drain_and_collect(t)
+        pairs = np.asarray(reported, np.int64).reshape(-1, 2)
+        got.append((name, list(calls), len(reported),
+                    zlib.crc32(pairs.tobytes()), reported[:3],
+                    reported[-1:]))
+    assert got == _PLANNED
+    assert (t.device_launches, t.device_votes, t.device_drains,
+            t.checker.window_violations) == (18, 4764, 6, 0)

@@ -155,17 +155,29 @@ def _random_system(rng, universe_pool):
     return Grid([cells[r * cols:(r + 1) * cols] for r in range(rows)])
 
 
-class TwoConfigOracle:
-    """slot < boundary: old system's write quorums; else the new's
-    (quorums/systems.py is the authority)."""
+class EpochsOracle:
+    """Any number of epochs of ``quorums/systems.py`` systems (the
+    authority): a slot's system is the last whose start is at or below
+    it."""
 
-    def __init__(self, old, new, boundary):
-        self.old, self.new, self.boundary = old, new, boundary
+    def __init__(self, systems, starts):
+        self.systems, self.starts = systems, starts
+
+    def system_of(self, slot):
+        at = max(k for k, start in enumerate(self.starts) if start <= slot)
+        return self.systems[at]
 
     def chosen(self, slot, voters) -> bool:
-        system = self.old if slot < self.boundary else self.new
+        system = self.system_of(slot)
         return system.is_superset_of_write_quorum(
             set(voters) & set(system.nodes()))
+
+
+class TwoConfigOracle(EpochsOracle):
+    """slot < boundary: old system's write quorums; else the new's."""
+
+    def __init__(self, old, new, boundary):
+        super().__init__([old, new], [float("-inf"), boundary])
 
 
 @pytest.mark.parametrize("seed", range(8))
@@ -344,3 +356,192 @@ def test_epoch_tracker_backends_agree(seed):
     for b, got in reported.items():
         assert len(got) == len(set(got)), (b, got)
     assert set(reported["dict"]) == set(reported["tpu"])
+
+
+# --- the dense epoch kernel (ISSUE 38) ---------------------------------------
+
+
+class BoardModel:
+    """What a vote board holds, on the host: per column its owner slot,
+    round, voters and whether the owner was reported chosen."""
+
+    def __init__(self, oracle, window):
+        self.oracle, self.window = oracle, window
+        self.columns: dict = {}
+
+    def block(self, start, votes, round) -> set:
+        """Apply one block's ``(offset, node)`` votes; the slots it
+        newly chose."""
+        touched = set()
+        for offset, node in votes:
+            slot = start + offset
+            state = self.columns.setdefault(
+                slot % self.window, [-1, -1, set(), False])
+            if slot < state[0]:
+                continue                       # the ring moved past it
+            if slot > state[0]:
+                state[:] = [slot, -1, set(), False]
+            if round > state[1]:
+                state[1], state[2] = round, set()
+            if round == state[1]:
+                state[2].add(node)
+            touched.add(slot % self.window)
+        newly = set()
+        for column in touched:
+            slot, _, voters, chosen = self.columns[column]
+            if not chosen and self.oracle.chosen(slot, voters):
+                self.columns[column][3] = True
+                newly.add(slot)
+        return newly
+
+
+def _majorities(rng, pool, planes, spacing):
+    """``planes`` epochs of 3 of ``pool``, ``spacing`` slots apart."""
+    systems = [SimpleMajority(rng.sample(pool, 3)) for _ in range(planes)]
+    return systems, [k * spacing for k in range(planes)]
+
+
+def _checker_pair(systems, starts, window):
+    return [EpochSegmentedChecker([s.write_spec() for s in systems],
+                                  starts, window=window) for _ in range(2)]
+
+
+def _same_boards(dense, sparse) -> None:
+    for field, a, b in zip(dense.board._fields, dense.board, sparse.board):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), field)
+
+
+def _record_both(dense, sparse, model, start, width, votes, round=0):
+    """One block into the dense kernel, its votes into the scatter, and
+    both against the model: ``newly`` and the boards, bit for bit."""
+    block = np.zeros((dense.num_nodes, width), dtype=np.uint8)
+    for offset, node in votes:
+        block[dense.column_of(node), offset] = 1
+    newly = dense.record_block(start, block, vote_round=round)
+    rows, offsets = np.nonzero(block)
+    per_vote = sparse.record_and_check(
+        start + offsets, rows, np.full(rows.size, round, dtype=np.int32))
+    from_scatter = np.zeros(width, dtype=bool)
+    from_scatter[offsets[per_vote]] = True
+    np.testing.assert_array_equal(newly, from_scatter)
+    assert {start + int(o) for o in np.flatnonzero(newly)} \
+        == model.block(start, votes, round)
+    _same_boards(dense, sparse)
+    return newly
+
+
+def _spanning(boundaries_inside):
+    """A 64-column block at slot 100 over epochs that start at the given
+    offsets into it; a last epoch far above it, so that the universe
+    holds acceptors that are members of none of the block's epochs."""
+    def case(rng):
+        pool = list(range(6))
+        starts = [0] + [100 + b for b in boundaries_inside] + [5000]
+        systems = [SimpleMajority(rng.sample(pool, 3)) for _ in starts]
+        systems[-1] = SimpleMajority([3, 4, 5])
+        systems[0] = SimpleMajority([0, 1, 2])
+        return systems, starts, pool, [(100, 64, 0)] * 4
+    return case
+
+
+def _preempting(rng):
+    """Round 0 half-votes, then a round-1 block over the middle of them,
+    then round 0 again (stale there, live beside it)."""
+    pool = list(range(6))
+    systems, starts = _majorities(rng, pool, 4, 40)
+    systems[0], systems[1] = (SimpleMajority([0, 1, 2]),
+                              SimpleMajority([3, 4, 5]))
+    return systems, starts, pool, [(64, 64, 0), (80, 32, 1), (64, 64, 0),
+                                   (64, 64, 1), (64, 64, 1)]
+
+
+def _reclaiming(rng):
+    """The ring wraps onto columns that hold half-voted and chosen slots
+    of earlier epochs; a straggler block for the old slots comes last."""
+    pool = list(range(6))
+    systems, starts = _majorities(rng, pool, 9, 60)
+    systems[0], systems[1] = (SimpleMajority([0, 1, 2]),
+                              SimpleMajority([3, 4, 5]))
+    return systems, starts, pool, [(0, 64, 0), (0, 64, 0), (256 + 16, 64, 0),
+                                   (256 + 16, 64, 0), (0, 64, 0),
+                                   (512, 64, 0), (512, 64, 0)]
+
+
+DENSE_CASES = {
+    "inside-one-epoch": _spanning([]),
+    "one-boundary": _spanning([31]),
+    "three-boundaries": _spanning([5, 6, 40]),
+    "boundary-at-first-and-last-column": _spanning([0, 63]),
+    "epochs-that-govern-no-slot": _spanning([20, 20, 20, 41]),
+    "newer-round-preempts-mid-block": _preempting,
+    "column-reclaimed-by-the-ring": _reclaiming,
+}
+
+
+# The reclaiming case ends with a straggler, which the checker flags.
+@pytest.mark.filterwarnings("ignore:EpochSegmentedChecker. vote for slot")
+@pytest.mark.parametrize("case", DENSE_CASES)
+@pytest.mark.parametrize("seed", [0, 1])
+def test_dense_epoch_kernel_equals_the_oracle_and_the_scatter(case, seed):
+    """``record_block`` of the epoch-segmented checker: every column is
+    judged under its own slot's epoch, non-members' votes (any of the
+    pool votes anywhere) complete nothing, and the board comes out as
+    the scatter leaves it."""
+    rng = random.Random(f"{case}-{seed}")
+    systems, starts, pool, blocks = DENSE_CASES[case](rng)
+    window = 256
+    dense, sparse = _checker_pair(systems, starts, window)
+    model = BoardModel(EpochsOracle(systems, starts), window)
+    chosen = 0
+    for start, width, round in blocks:
+        votes = [(rng.randrange(width), rng.choice(pool))
+                 for _ in range(2 * width)]
+        chosen += int(_record_both(dense, sparse, model, start, width,
+                                   votes, round).sum())
+    assert chosen >= 16       # the case decides something
+
+
+def test_dense_epoch_kernel_leaves_padding_columns_untouched():
+    """A 40-column block is padded to the 64 bucket: the 24 columns
+    beyond it, which hold other slots' votes, rounds and owners, come
+    out as they went in."""
+    rng = random.Random(38)
+    pool = list(range(6))
+    systems, starts = _majorities(rng, pool, 3, 50)
+    systems[0], systems[1] = (SimpleMajority([0, 1, 2]),
+                              SimpleMajority([3, 4, 5]))
+    dense, sparse = _checker_pair(systems, starts, 256)
+    model = BoardModel(EpochsOracle(systems, starts), 256)
+    beyond = [(offset, rng.choice(pool)) for offset in range(24)]
+    _record_both(dense, sparse, model, 80, 24, beyond, round=3)
+    before = [np.asarray(plane)[..., 80:104] for plane in dense.board]
+    votes = [(rng.randrange(40), rng.choice(pool)) for _ in range(80)]
+    _record_both(dense, sparse, model, 40, 40, votes, round=0)
+    for plane, was in zip(dense.board, before):
+        np.testing.assert_array_equal(np.asarray(plane)[..., 80:104], was)
+
+
+@pytest.mark.parametrize("planes", [1, 29, 128])
+def test_dense_epoch_kernel_at_any_number_of_planes(planes):
+    """K real planes of the 128 a stack is allocated by: one of them,
+    the cell's 29, and all of them (no padding plane left)."""
+    rng = random.Random(planes)
+    pool = list(range(6))
+    systems, starts = _majorities(rng, pool, planes, 24)
+    systems[0] = SimpleMajority([0, 1, 2])
+    if planes > 1:
+        systems[1] = SimpleMajority([3, 4, 5])
+    else:
+        pool = [0, 1, 2]
+    window = 4096
+    dense, sparse = _checker_pair(systems, starts, window)
+    assert dense._masks.shape[0] == 128
+    model = BoardModel(EpochsOracle(systems, starts), window)
+    chosen = 0
+    for start in range(0, min(24 * planes, window - 256), 200):
+        for _ in range(2):
+            votes = [(rng.randrange(256), rng.choice(pool))
+                     for _ in range(400)]
+            chosen += int(_record_both(dense, sparse, model, start, 256,
+                                       votes).sum())
+    assert chosen >= 100
