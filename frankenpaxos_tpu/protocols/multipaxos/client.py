@@ -31,6 +31,13 @@ batch's slot is at or above that write's, and the replica answers only
 once that slot has executed. Still f+1 acceptors a read, still one
 replica that has executed the slot. A batch is closed when its request
 is sent: a max-slot answer is never used for a read issued later.
+
+A pass's coalesced writes (``coalesce_writes``) leave as one
+ClientRequestArray and share its resend timer: one timer a pass where
+there was one a write, armed as the array is sent, stopped once every
+write of it has been answered. When it fires, the writes still pending
+are each charged their own retry and re-sent as one array. A write
+sent alone keeps its pseudonym's own timer.
 """
 
 from __future__ import annotations
@@ -88,8 +95,9 @@ class ClientOptions:
     # Coalesce this event-loop pass's writes into ONE ClientRequestArray
     # to the leader (each command still gets its own slot -- see
     # messages.ClientRequestArray). Flushed by on_drain / flush_writes;
-    # resends still go per-request. Bypasses batchers: the array is
-    # transport-level coalescing, not slot sharing.
+    # the array's writes share one resend timer (_WriteBatch). Bypasses
+    # batchers: the array is transport-level coalescing, not slot
+    # sharing.
     coalesce_writes: bool = False
     # paxload retry discipline (serve/backoff.py, docs/SERVING.md).
     # retry_budget = 0 keeps the pre-paxload behavior: unlimited
@@ -109,12 +117,17 @@ class _PendingWrite:
     resend: object
     attempts: int = 0
     backoff_pending: bool = False
+    # The batch a coalesced write travels in: set when its array
+    # leaves, None again once a Rejected write is re-staged
+    # (``_reissue``), so that a later batch carries it.
+    batch: object = None
 
 
 class _BatchTimed:
-    """The ``resend`` of a read that travels in a batch: it has no
-    timer of its own, its batch's timer re-sends it while it is
-    pending (``Client._live_reads``)."""
+    """The ``resend`` of a read or a coalesced write that travels in a
+    batch: it has no timer of its own, its batch's timer re-sends it
+    while it is pending (``Client._live_reads``,
+    ``Client._resend_write_batch``)."""
 
     def start(self) -> None:
         pass
@@ -163,6 +176,18 @@ class _ReadBatch:
     unanswered: int = 0
 
 
+@dataclasses.dataclass
+class _WriteBatch:
+    """The coalesced writes one ClientRequestArray carried, and their
+    one resend timer. ``writes`` holds (pseudonym, state, Command) of
+    the writes it covers; ``unanswered`` counts those of them not yet
+    answered nor re-staged."""
+    id: int
+    writes: list
+    unanswered: int = 0
+    timer: object = None
+
+
 class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
     #: The commit path (obs/trace.py): one write in PATH_EVERY of a
     #: client is sampled, unless a sampled write of it is outstanding.
@@ -207,10 +232,17 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         self._fan = make_fan_router(
             config,
             revive_after_s=options.resend_client_request_period_s)
-        # One reusable resend timer per pseudonym (vs a fresh Timer per
-        # write): timer construction was a measurable per-command cost
-        # at drain widths in the thousands.
+        # One reusable resend timer per pseudonym for writes sent alone
+        # (vs a fresh Timer per write): timer construction was a
+        # measurable per-command cost at drain widths in the thousands.
         self._write_timers: dict[int, object] = {}
+        # Coalesced writes share their array's timer instead. How often
+        # that engages: batches armed, the writes they covered, and
+        # batch resends fired.
+        self._next_write_batch_id = 0
+        self.write_batches_armed = 0
+        self.write_batch_writes = 0
+        self.write_batch_resends = 0
         # Read batches waiting for their max-slot quorum, by the batch
         # id this client counts up (it rides BatchMaxSlotRequest's
         # read_batcher_id); a batch leaves as its ReadRequestBatch does.
@@ -226,21 +258,22 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         self._path_gap -= 1
         path = self._sample_path(pseudonym, id) if not self._path_gap \
             else None
-        request = ClientRequest(Command(
-            CommandId(self.address, pseudonym, id), command))
+        entry = Command(CommandId(self.address, pseudonym, id), command)
         if self.options.coalesce_writes:
             # Stage for the end-of-pass array flush (runs/client.py:
             # a burst of call_soon'd closed loops, or reissues inside
-            # a delivery drain, coalesce into one array).
-            self._stage_write(request.command)
+            # a delivery drain, coalesce into one array), whose timer
+            # re-sends it.
+            state = _PendingWrite(id, command, callback, _BATCH_TIMED)
+            self._stage_write((pseudonym, state, entry))
             if path is not None:
                 self._path_staged = path
         else:
-            self._send_client_request(request, path)
-        timer = self._write_resend_timer(pseudonym)
-        timer.start()
-        state = self.states[pseudonym] = _PendingWrite(id, command,
-                                                       callback, timer)
+            self._send_client_request(ClientRequest(entry), path)
+            timer = self._write_resend_timer(pseudonym)
+            timer.start()
+            state = _PendingWrite(id, command, callback, timer)
+        self.states[pseudonym] = state
         if path is not None:
             self._path_write = (pseudonym, state)
         self.ids[pseudonym] = id + 1
@@ -402,14 +435,94 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         an ingest disseminator when the config deploys them, else
         straight to the round's leader). The array spans many of this
         client's pseudonyms, so it rides the client-scoped ring key
-        (pseudonym -1)."""
+        (pseudonym -1). Its writes' resend timer starts as it leaves."""
         dst = pick_array_destination(self.config, self.rng,
                                      self._round_leader, fan=self._fan,
                                      key=(self.address, -1))
         if self._path_staged is not None:
             self.transport.path_out, self._path_staged = \
                 self._path_staged, None
-        self.send(dst, ClientRequestArray(commands=tuple(staged)))
+        self.send(dst, ClientRequestArray(
+            commands=tuple(entry for _, _, entry in staged)))
+        self._open_write_batch(staged)
+
+    def _open_write_batch(self, staged: list) -> None:
+        """One resend timer for the writes of the array just sent that
+        are still pending (one answered or given up while it was staged
+        is left out)."""
+        batch = _WriteBatch(self._next_write_batch_id, [])
+        states = self.states
+        writes = batch.writes
+        for write in staged:
+            state = write[1]
+            if states.get(write[0]) is state:
+                state.batch = batch
+                writes.append(write)
+        if not writes:
+            return
+        self._next_write_batch_id += 1
+        batch.unanswered = len(writes)
+        self.write_batches_armed += 1
+        self.write_batch_writes += len(writes)
+        batch.timer = self.timer(
+            f"resendWriteBatch{batch.id}",
+            self.options.resend_client_request_period_s,
+            lambda: self._resend_write_batch(batch))
+        batch.timer.start()
+
+    def _close_write_batch(self, batch: _WriteBatch) -> None:
+        """Nothing of ``batch`` is left to re-send: stop its timer and
+        let go of its writes (each points back at it)."""
+        if batch.timer is not None:
+            batch.timer.stop()
+            batch.timer = None
+        batch.writes = ()
+
+    def _write_left_batch(self, state: _PendingWrite) -> None:
+        """``state`` was answered or re-staged: its batch covers one
+        write fewer, and is closed when it covers none."""
+        batch = state.batch
+        batch.unanswered -= 1
+        if batch.unanswered <= 0:
+            self._close_write_batch(batch)
+
+    def _resend_write_batch(self, batch: _WriteBatch) -> None:
+        """A write batch's resend timer. A timeout is the FAILOVER
+        signal (the leader may be gone): each write still pending and
+        not backing off is charged its own retry, one that exhausts its
+        budget completes with RETRY_EXHAUSTED and leaves the batch, and
+        the rest are re-sent as one array on the normal discovery
+        path. A write backing off after a Rejected stays in the batch
+        unsent until its reissue re-stages it."""
+        states = self.states
+        kept: list = []
+        entries: list = []
+        for write in batch.writes:
+            pseudonym, state, entry = write
+            if states.get(pseudonym) is not state \
+                    or state.batch is not batch:
+                continue
+            if state.backoff_pending:
+                kept.append(write)
+            elif self._consume_retry(pseudonym, state, "failover"):
+                kept.append(write)
+                entries.append(entry)
+        batch.writes = kept
+        batch.unanswered = len(kept)
+        if not kept:
+            self._close_write_batch(batch)
+            return
+        if entries:
+            self.write_batch_resends += 1
+            if self._fan is not None:
+                # paxfan: the timeout suspects the shard the array's
+                # key routes to, so the resend routes past it.
+                self._fan.suspect_key(self.address, -1)
+            dst = pick_array_destination(self.config, self.rng,
+                                         self._round_leader, fan=self._fan,
+                                         key=(self.address, -1))
+            self.send(dst, ClientRequestArray(commands=tuple(entries)))
+        batch.timer.start()
 
     def _flush_staged_reads(self, staged: list) -> None:
         """Open the quorum round of the reads this pass issued: ONE
@@ -546,17 +659,20 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
     # RetryAdmissionMixin; only the operation re-send is ours.
     def _reissue(self, pseudonym: int, state) -> None:
         if isinstance(state, _PendingWrite):
-            request = ClientRequest(Command(
-                CommandId(self.address, pseudonym, state.id),
-                state.command))
+            entry = Command(CommandId(self.address, pseudonym, state.id),
+                            state.command)
             if self.options.coalesce_writes:
                 # Re-enter through the STAGED path: a burst of backoff
                 # expiries coalesces back into one ClientRequestArray
                 # instead of a retry storm of singles (the storm would
-                # re-congest the very leader that just shed us).
-                self._stage_write(request.command)
+                # re-congest the very leader that just shed us). The
+                # write leaves its old batch for the next one.
+                if state.batch is not None:
+                    self._write_left_batch(state)
+                    state.batch = None
+                self._stage_write((pseudonym, state, entry))
             else:
-                self._send_client_request(request)
+                self._send_client_request(ClientRequest(entry))
         elif isinstance(state, _PendingRead):
             batch = state.batch
             if batch is not None and batch.replica is not None:
@@ -581,7 +697,10 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
                 or reply.command_id.client_id != state.id:
             self.logger.debug(f"stale ClientReply {reply}")
             return
-        state.resend.stop()
+        if state.batch is None:
+            state.resend.stop()
+        else:
+            self._write_left_batch(state)
         self.largest_seen_slots[pseudonym] = max(
             self.largest_seen_slots.get(pseudonym, -1), reply.slot)
         del self.states[pseudonym]
@@ -598,7 +717,10 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
                 self.logger.debug(
                     f"stale reply-array entry for pseudonym {pseudonym}")
                 continue
-            state.resend.stop()
+            if state.batch is None:
+                state.resend.stop()
+            else:
+                self._write_left_batch(state)
             self.largest_seen_slots[pseudonym] = max(
                 self.largest_seen_slots.get(pseudonym, -1), slot)
             del self.states[pseudonym]

@@ -768,9 +768,9 @@ class ProxyLeader(Actor):
     def _emit_chosen_grouped(self, keys) -> None:
         """Group a drain's chosen (slot, round)s into contiguous
         same-round segments (preserving the tracker's arrival-order
-        reporting -- no sort) and emit each run-covered segment as ONE
-        ChosenRun per replica; anything outside a run falls back to the
-        per-slot path."""
+        reporting -- no sort) and emit each segment as ONE ChosenRun per
+        replica for every pending run it crosses; a slot outside any run
+        falls back to the per-slot path."""
         slots = np.fromiter((k[0] for k in keys), dtype=np.int64,
                             count=len(keys))
         rounds = np.fromiter((k[1] for k in keys), dtype=np.int64,
@@ -781,16 +781,25 @@ class ProxyLeader(Actor):
         for b in list(breaks.tolist()) + [len(keys)]:
             if b == at:
                 continue
-            lo = int(slots[at])
-            hi = int(slots[b - 1]) + 1
-            rnd = int(rounds[at])
-            run = self._run_for(lo, rnd)
-            if run is not None and hi <= run[0]:
-                self._emit_run_segment(run, lo, hi)
-            else:
-                for i in range(at, b):
-                    self._emit_one((int(slots[i]), rnd))
+            self._emit_segment(int(slots[at]), int(slots[b - 1]) + 1,
+                               int(rounds[at]))
             at = b
+
+    def _emit_segment(self, lo: int, hi: int, rnd: int) -> None:
+        """Chosen slots [lo, hi) of round ``rnd``, cut at run
+        boundaries: one ChosenRun a pending run the segment crosses
+        (many small runs, one a client's array, often share a
+        drain's segment), a slot outside any run alone."""
+        while lo < hi:
+            run = (None if (lo, rnd) in self.pending
+                   else self._run_for(lo, rnd))
+            if run is None:
+                self._emit_one((lo, rnd))
+                lo += 1
+            else:
+                end = min(hi, run[0])
+                self._emit_run_segment(run, lo, end)
+                lo = end
 
     def _emit_run_segment(self, run: list, lo: int, hi: int) -> None:
         """Emit chosen slots [lo, hi) of one pending run: slice the

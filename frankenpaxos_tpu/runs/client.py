@@ -170,8 +170,10 @@ class StagedWriteMixin:
             loop.call_soon_threadsafe(self._deferred_flush)
         return True
 
-    def _stage_write(self, command) -> None:
-        self._staged_writes.append(command)
+    def _stage_write(self, write) -> None:
+        """``write`` is whatever the subclass's ``_flush_staged`` takes
+        (a command, or a command with its pending state)."""
+        self._staged_writes.append(write)
         self._schedule_flush()
 
     def _stage_read(self, read) -> None:

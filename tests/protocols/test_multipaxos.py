@@ -546,6 +546,54 @@ class TestCoalescedRunPipeline:
         assert [m for m in sim.transport.messages
                 if m.dst.startswith("replica")] == []
 
+    def test_proxy_leader_segment_across_runs_is_one_chosen_run_a_run(self):
+        """A drain whose chosen segment spans three pending runs (one a
+        client's array) emits one ChosenRun per run and replica, not one
+        per slot; a single-slot proposal inside the segment still goes
+        alone."""
+        from frankenpaxos_tpu.protocols.multipaxos.messages import (
+            Chosen,
+            Command,
+            CommandBatch,
+            CommandId,
+            Phase2a,
+            Phase2aRun,
+            Phase2b,
+            Phase2bRange,
+        )
+
+        sim = make_multipaxos(f=1)
+        proxy = sim.proxy_leaders[0]
+        v = lambda i: CommandBatch((Command(  # noqa: E731
+            CommandId("client-0", i, 0), b"v%d" % i),))
+        proxy.receive("leader-0", Phase2aRun(
+            start_slot=0, round=0, values=tuple(v(i) for i in range(3))))
+        proxy.receive("leader-0", Phase2aRun(
+            start_slot=3, round=0, values=tuple(v(i) for i in range(3, 7))))
+        proxy.receive("leader-0", Phase2a(slot=7, round=0, value=v(7)))
+        proxy.receive("leader-0", Phase2aRun(
+            start_slot=8, round=0, values=tuple(v(i) for i in range(8, 10))))
+        sim.transport.messages.clear()  # drop the quorum forwards
+        for acc in (0, 1):
+            proxy.receive(f"acceptor-0-{acc}", Phase2bRange(
+                group_index=0, acceptor_index=acc, slot_start_inclusive=0,
+                slot_end_exclusive=7, round=0))
+            proxy.receive(f"acceptor-0-{acc}", Phase2b(
+                group_index=0, acceptor_index=acc, slot=7, round=0))
+            proxy.receive(f"acceptor-0-{acc}", Phase2bRange(
+                group_index=0, acceptor_index=acc, slot_start_inclusive=8,
+                slot_end_exclusive=10, round=0))
+        proxy.on_drain()
+        chosen = [proxy.serializer.from_bytes(m.data)
+                  for m in sim.transport.messages if m.dst == "replica-0"]
+        assert [(c.slot, 1) if isinstance(c, Chosen)
+                else (c.start_slot, len(c.values)) for c in chosen] == \
+            [(0, 3), (3, 4), (7, 1), (8, 2)]
+        assert [value for c in chosen for value in (
+            (c.value,) if isinstance(c, Chosen) else c.values)] == \
+            [v(i) for i in range(10)]
+        assert proxy._runs == {} and proxy.chosen_count == 10
+
     def test_proxy_leader_duplicate_run_ignored(self):
         """A resent Phase2aRun for a start slot already pending must
         not re-forward or double-register."""
