@@ -37,7 +37,10 @@ ClientRequestArray and share its resend timer: one timer a pass where
 there was one a write, armed as the array is sent, stopped once every
 write of it has been answered. When it fires, the writes still pending
 are each charged their own retry and re-sent as one array. A write
-sent alone keeps its pseudonym's own timer.
+sent alone keeps its pseudonym's own timer. Where the config deploys
+batchers the array goes to one of them, the same for all of this
+client's arrays (the ring key ``(client, -1)``), which re-buckets its
+commands into batches of a slot each (batcher.py).
 """
 
 from __future__ import annotations
@@ -93,11 +96,11 @@ class ClientOptions:
     flush_writes_every_n: int = 1
     flush_reads_every_n: int = 1
     # Coalesce this event-loop pass's writes into ONE ClientRequestArray
-    # to the leader (each command still gets its own slot -- see
-    # messages.ClientRequestArray). Flushed by on_drain / flush_writes;
-    # the array's writes share one resend timer (_WriteBatch). Bypasses
-    # batchers: the array is transport-level coalescing, not slot
-    # sharing.
+    # (each command gets its own slot at the leader -- see
+    # messages.ClientRequestArray -- or, where the config deploys
+    # batchers, a place in the batcher's batches). Flushed by on_drain /
+    # flush_writes; the array's writes share one resend timer
+    # (_WriteBatch).
     coalesce_writes: bool = False
     # paxload retry discipline (serve/backoff.py, docs/SERVING.md).
     # retry_budget = 0 keeps the pre-paxload behavior: unlimited
@@ -224,14 +227,16 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
         self._retry_budget = options.retry_budget
         self._retry_backoff = options.backoff
         self._init_staging()
-        # paxfan: consistent ring over the ingest-batcher tier -- a
+        # paxfan: consistent ring over the ingest-batcher tier, or
+        # the plain batchers that take this client's arrays -- a
         # session key (this client, pseudonym) pins to one shard; a
         # resend timeout suspects THAT shard (its keys fail over to
         # the clockwise survivors, everyone else stays pinned); a
         # Rejected floors backoff against the shedding shard only.
         self._fan = make_fan_router(
             config,
-            revive_after_s=options.resend_client_request_period_s)
+            revive_after_s=options.resend_client_request_period_s,
+            batchers=True)
         # One reusable resend timer per pseudonym for writes sent alone
         # (vs a fresh Timer per write): timer construction was a
         # measurable per-command cost at drain widths in the thousands.
@@ -432,10 +437,11 @@ class Client(RetryAdmissionMixin, StagedWriteMixin, Actor):
 
     def _flush_staged(self, staged: list) -> None:
         """Ship writes staged by ``coalesce_writes`` as one array (to
-        an ingest disseminator when the config deploys them, else
-        straight to the round's leader). The array spans many of this
-        client's pseudonyms, so it rides the client-scoped ring key
-        (pseudonym -1). Its writes' resend timer starts as it leaves."""
+        an ingest disseminator or a batcher when the config deploys
+        them, else straight to the round's leader). The array spans
+        many of this client's pseudonyms, so it rides the client-scoped
+        ring key (pseudonym -1). Its writes' resend timer starts as it
+        leaves."""
         dst = pick_array_destination(self.config, self.rng,
                                      self._round_leader, fan=self._fan,
                                      key=(self.address, -1))

@@ -54,6 +54,7 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     ClientRequest,
     ClientRequestArray,
     ClientRequestBatch,
+    ClientRequestBatchRun,
     CommandBatch,
     LeaderInfoReplyBatcher,
     LeaderInfoReplyClient,
@@ -410,10 +411,10 @@ class Leader(Actor):
 
     def _note_path(self, path, place: int) -> None:
         """The frame being handled carries a sampled command's commit
-        path from its client, and the command is the ``place``-th of
-        the values about to be proposed from ``next_slot`` (-1: not
-        among them, and the path ends here)."""
-        if path.kind == "client" and place >= 0:
+        path from its client or its batcher, and the command is in the
+        ``place``-th of the values about to be proposed from
+        ``next_slot`` (-1: not among them, and the path ends here)."""
+        if path.kind in ("client", "batcher") and place >= 0:
             self._path = path.onward("leader", self.next_slot + place)
 
     def _path_onto(self, start: int, end: int) -> None:
@@ -626,6 +627,8 @@ class Leader(Actor):
              self._handle_client_request_array),
             (ClientRequestBatch, "ClientRequestBatch",
              self._handle_client_request_batch),
+            (ClientRequestBatchRun, "ClientRequestBatchRun",
+             self._handle_client_request_batch_run),
             (IngestRun, "IngestRun", self._handle_ingest_run),
             (LeaderInfoRequestClient, "LeaderInfoRequestClient",
              self._handle_leader_info_request_client),
@@ -1004,6 +1007,40 @@ class Leader(Actor):
             self.state.pending_batches.append(batch)
         else:
             self._process_client_request_batch(batch)
+
+    def _handle_client_request_batch_run(
+            self, src: Address, run: ClientRequestBatchRun) -> None:
+        """A batcher's pass of batches: one slot a batch, proposed as
+        one run (the ``_propose_value_run`` the arrays take). Off
+        Phase 2 each batch goes the way a ClientRequestBatch goes: back
+        to the batcher, or into Phase 1's pending batches."""
+        values = run.values
+        if not len(values):
+            return
+        if isinstance(self.state, _Inactive):
+            for value in values:
+                self.send(src, NotLeaderBatcher(
+                    client_request_batch=ClientRequestBatch(value)))
+            return
+        if self.admission is not None and not self._admit(
+                ClientRequestBatch(CommandBatch(tuple(
+                    command for value in values
+                    for command in value.commands))),
+                sum(len(value.commands) for value in values)):
+            return
+        if isinstance(self.state, _Phase1):
+            for value in values:
+                self._admitted_backlog += len(value.commands)
+                self.state.pending_batches.append(ClientRequestBatch(value))
+            return
+        path = self.transport.path_in
+        if path is not None:
+            # A batcher's path holds its command's place among the
+            # batches in ``slot`` (batcher.py).
+            self._note_path(path, path.slot if path.kind == "batcher"
+                            else -1)
+        self._propose_value_run(values)
+        self._path = None
 
     def _handle_leader_info_request_client(self, src: Address, _) -> None:
         if not isinstance(self.state, _Inactive):

@@ -189,6 +189,18 @@ class ClientRequestArray:
 
 
 @dataclasses.dataclass(frozen=True)
+class ClientRequestBatchRun:
+    """The batches one batcher's loop pass made, in one message to the
+    leader: each a CommandBatch that takes ONE slot (as a
+    ClientRequestBatch does), the leader proposing them as one run of
+    slots. ``values`` is a tuple, or on the deployed path a
+    LazyValueArray the batcher built from its clients' wire bytes and
+    the leader forwards into its Phase2aRun without a parse."""
+
+    values: tuple  # tuple[CommandBatch, ...] | LazyValueArray
+
+
+@dataclasses.dataclass(frozen=True)
 class Phase2aRun:
     """Phase2as for a CONTIGUOUS slot run in one round, one message.
 

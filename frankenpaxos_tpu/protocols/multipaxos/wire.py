@@ -33,6 +33,7 @@ from frankenpaxos_tpu.protocols.multipaxos.messages import (
     ClientRequest,
     ClientRequestArray,
     ClientRequestBatch,
+    ClientRequestBatchRun,
     Command,
     CommandBatch,
     CommandId,
@@ -618,6 +619,21 @@ class ClientReplyArrayCodec(MessageCodec):
         return ClientReplyArray(entries=tuple(entries)), at
 
 
+class ClientRequestBatchRunCodec(MessageCodec):
+    """A batcher's pass of batches as one value array: the leader
+    forwards the segment into its Phase2aRun as a raw copy."""
+
+    message_type = ClientRequestBatchRun
+    tag = 119
+
+    def encode(self, out, message):
+        _put_value_array(out, message.values)
+
+    def decode(self, buf, at):
+        values, at = _take_value_array(buf, at)
+        return ClientRequestBatchRun(values=values), at
+
+
 # --- read-path codecs -------------------------------------------------------
 # The read hot path (the Evelyn read-scale mechanism): a max-slot
 # quorum round, then a read request to one replica, answered with a
@@ -625,7 +641,7 @@ class ClientReplyArrayCodec(MessageCodec):
 # they get fixed layouts like the write path. A client's (and a read
 # batcher's) round is BatchMaxSlotRequest / BatchMaxSlotReply and its
 # request a ReadRequestBatch: those sit with the other batch shapes on
-# the extended tag page below. Tags 119 and 120 are free.
+# the extended tag page below. Tag 120 is free.
 
 
 class _SlotCommandCodec(MessageCodec):
@@ -1119,7 +1135,8 @@ for _codec in (Phase2bCodec(), Phase2aCodec(), ChosenCodec(),
                Phase2bRangeCodec(), Phase2bVotesCodec(),
                ClientRequestArrayCodec(), Phase2aRunCodec(),
                ChosenRunCodec(), ClientReplyArrayCodec(),
-               ReadRequestCodec(), SequentialReadRequestCodec(),
+               ClientRequestBatchRunCodec(), ReadRequestCodec(),
+               SequentialReadRequestCodec(),
                EventualReadRequestCodec(), ReadReplyBatchCodec(),
                ClientReplyBatchCodec(), ReadRequestBatchCodec(),
                SequentialReadRequestBatchCodec(),

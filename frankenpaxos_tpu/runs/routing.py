@@ -22,15 +22,20 @@ import random
 from typing import Callable, Optional
 
 
-def make_fan_router(config, *, revive_after_s: float = 1.0):
-    """A ShardRouter over the config's ingest tier, or None when the
-    config deploys no ingest batchers (the ladder falls through)."""
-    if getattr(config, "num_ingest_batchers", 0) <= 0:
+def make_fan_router(config, *, revive_after_s: float = 1.0,
+                    batchers: bool = False):
+    """A ShardRouter over the config's ingest tier; with ``batchers``
+    (a protocol whose plain batchers take a client's array) over its
+    plain batchers when it deploys no ingest tier; None when there is
+    no such tier (the ladder falls through)."""
+    count = getattr(config, "num_ingest_batchers", 0)
+    if count <= 0 and batchers:
+        count = getattr(config, "num_batchers", 0)
+    if count <= 0:
         return None
     from frankenpaxos_tpu.ingest.fan import ShardRouter
 
-    return ShardRouter(config.num_ingest_batchers,
-                       revive_after_s=revive_after_s)
+    return ShardRouter(count, revive_after_s=revive_after_s)
 
 
 def pick_request_destination(config, rng: random.Random,
@@ -55,15 +60,18 @@ def pick_array_destination(config, rng: random.Random,
                            leader_fallback: Callable,
                            fan=None, key: Optional[tuple] = None):
     """Destination for a staged ClientRequestArray: ingest batchers >
-    ``leader_fallback()``. Arrays bypass plain batchers -- they are
-    already transport-level coalesced, and the batcher tier only
-    re-buckets singles. A staged array spans many pseudonyms of one
+    plain batchers, when ``fan`` routes over them (a protocol whose
+    batchers re-bucket an array's commands into batches) >
+    ``leader_fallback()``. A staged array spans many pseudonyms of one
     client, so its ring key is the client-scoped sentinel the caller
-    passes (conventionally ``(client_token, -1)``)."""
+    passes (conventionally ``(client_token, -1)``): a client's arrays
+    stay with one batcher."""
     if getattr(config, "num_ingest_batchers", 0) > 0:
-        if fan is not None and key is not None:
-            return config.ingest_batcher_addresses[
-                fan.route(key[0], key[1])]
-        return config.ingest_batcher_addresses[
-            rng.randrange(config.num_ingest_batchers)]
-    return leader_fallback()
+        addresses = config.ingest_batcher_addresses
+    elif fan is not None and getattr(config, "num_batchers", 0) > 0:
+        addresses = config.batcher_addresses
+    else:
+        return leader_fallback()
+    if fan is not None and key is not None:
+        return addresses[fan.route(key[0], key[1])]
+    return addresses[rng.randrange(len(addresses))]

@@ -32,6 +32,7 @@ CLIENT_LANE_TYPE_NAMES = frozenset({
     "ClientRequest",
     "ClientRequestArray",
     "ClientRequestBatch",
+    "ClientRequestBatchRun",
     "BatchMaxSlotRequest",
     "ReadRequest",
     "ReadRequestBatch",

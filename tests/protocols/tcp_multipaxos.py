@@ -32,16 +32,19 @@ def free_port() -> int:
 
 class TcpMultiPaxos:
     """f=1: 2 leaders, 2 proxy leaders on one transport, 3 acceptors, 2
-    replicas, one client. ``collectors[label]`` holds a process's
-    metrics, ``metrics[label]`` its RuntimeMetrics."""
+    replicas, ``batchers`` batchers (none by default), one client.
+    ``collectors[label]`` holds a process's metrics, ``metrics[label]``
+    its RuntimeMetrics."""
 
     OWNER = "proxy_leader_0_1"
 
-    def __init__(self, overrides: dict, tracer_for=None):
+    def __init__(self, overrides: dict, tracer_for=None, batchers: int = 0):
         """``tracer_for(label, metrics)`` may give a process's transport
         an ``obs.Tracer``."""
         self.protocol = get_protocol("multipaxos")
         raw = self.protocol.cluster(1, lambda: ["127.0.0.1", free_port()])
+        raw["batchers"] = [["127.0.0.1", free_port()]
+                           for _ in range(batchers)]
         self.config = self.protocol.load_config(raw)
         self.logger = FakeLogger(LogLevel.FATAL)
         self.overrides = overrides
@@ -52,7 +55,7 @@ class TcpMultiPaxos:
         self.actors: dict = {}
         self._serializer = PickleSerializer()
         try:
-            for name in ("acceptor", "replica", "leader"):
+            for name in ("acceptor", "replica", "leader", "batcher"):
                 role = self.protocol.roles[name]
                 for index, address in enumerate(role.addresses(self.config)):
                     self._host(f"{name}_{index}", address,

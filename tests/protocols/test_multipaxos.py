@@ -86,15 +86,8 @@ class TestMultiPaxosIntegration:
         for client in sim.clients:
             client.write(0, b"w", results.append)
         sim.transport.deliver_all()
-        # Partial batches can strand below batch_size until client resends
-        # top them up (batchers only flush on size, Batcher.scala:148-163).
-        for _ in range(5):
-            if len(results) == 4:
-                break
-            for timer in sim.transport.running_timers():
-                if timer.name.startswith("resendWrite"):
-                    sim.transport.trigger_timer(timer.id)
-            sim.transport.deliver_all()
+        # A partial batch leaves at the end of the batcher's pass (here
+        # every delivery is a pass), so no write waits for a resend.
         assert len(results) == 4
         assert len(sim.replicas[0].state_machine.get()) == 4
 

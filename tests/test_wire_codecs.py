@@ -668,6 +668,8 @@ def all_codec_samples() -> dict:
         mp.Phase2aRun(start_slot=5, round=2, values=(batch, mp.NOOP)),
         mp.ChosenRun(start_slot=9, values=(mp.NOOP, batch)),
         mp.ClientReplyArray(entries=((0, 1, 5, b"r0"),)),
+        mp.ClientRequestBatchRun(
+            values=(batch, mp.CommandBatch((command, command)))),
         mp.ReadRequest(slot=5, command=command),
         mp.SequentialReadRequest(slot=-1, command=command),
         mp.EventualReadRequest(command=command),
