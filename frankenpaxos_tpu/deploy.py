@@ -538,12 +538,15 @@ def _mencius() -> Protocol:
                 flat_leaders,
                 lambda ctx, a, i: m.MenciusLeader(
                     a, ctx.transport, ctx.logger, ctx.config,
-                    seed=ctx.seed, **ctx.kw(m.MenciusLeader))),
+                    seed=ctx.seed, collectors=ctx.collectors,
+                    **ctx.kw(m.MenciusLeader))),
             "proxy_leader": Role(
                 lambda c: list(c.proxy_leader_addresses),
                 lambda ctx, a, i: m.MenciusProxyLeader(
                     a, ctx.transport, ctx.logger, ctx.config,
-                    seed=ctx.seed)),
+                    seed=ctx.seed, collectors=ctx.collectors,
+                    **ctx.kw(m.MenciusProxyLeader)),
+                device_options=("quorum_backend",)),
             "acceptor": Role(
                 flat_acceptors,
                 lambda ctx, a, i: m.MenciusAcceptor(
@@ -554,6 +557,7 @@ def _mencius() -> Protocol:
                 lambda ctx, a, i: m.MenciusReplica(
                     a, ctx.transport, ctx.logger, ctx.sm(), ctx.config,
                     seed=ctx.seed, wal=ctx.wal(f"replica_{i}"),
+                    collectors=ctx.collectors,
                     **ctx.kw(m.MenciusReplica))),
             "proxy_replica": Role(
                 lambda c: list(c.proxy_replica_addresses),

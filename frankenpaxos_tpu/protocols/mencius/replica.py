@@ -49,7 +49,7 @@ from frankenpaxos_tpu.runs.routing import (
     pick_array_destination,
     pick_request_destination,
 )
-from frankenpaxos_tpu.runtime import Actor, Logger
+from frankenpaxos_tpu.runtime import Actor, FakeCollectors, Logger
 from frankenpaxos_tpu.runtime.transport import Address, Transport
 from frankenpaxos_tpu.serve.messages import Rejected
 from frankenpaxos_tpu.statemachine import StateMachine
@@ -70,10 +70,17 @@ class MenciusReplica(Actor, DurableRole):
                  recover_min_period_s: float = 5.0,
                  recover_max_period_s: float = 10.0,
                  unsafe_dont_recover: bool = False, seed: int = 0,
-                 wal=None):
+                 wal=None, collectors=None):
         super().__init__(address, transport, logger)
         config.check_valid()
         self.config = config
+        # Slots newly chosen here, by what they hold: a command batch
+        # (of a ChosenRun or a Chosen) or a noop (a ChosenNoopRange, or
+        # a recovered Noop).
+        chosen = (collectors or FakeCollectors()).counter(
+            "mencius_replica_chosen_slots_total", labels=("kind",))
+        self.metrics_chosen_commands = chosen.labels("command")
+        self.metrics_chosen_noops = chosen.labels("noop")
         self.state_machine = state_machine
         self.rng = random.Random(seed)
         self.send_chosen_watermark_every_n = send_chosen_watermark_every_n
@@ -313,6 +320,8 @@ class MenciusReplica(Actor, DurableRole):
         if isinstance(message, Chosen):
             if self._log_chosen(message.slot, 1, (message.value,)) == 0:
                 return
+            (self.metrics_chosen_noops if isinstance(message.value, Noop)
+             else self.metrics_chosen_commands).inc()
             if self.wal is not None:
                 self.wal.append(WalChosenRun(
                     start_slot=message.slot, stride=1,
@@ -323,6 +332,8 @@ class MenciusReplica(Actor, DurableRole):
         elif isinstance(message, ChosenNoopRange):
             new = self._log_noop_range(message.slot_start_inclusive,
                                        message.slot_end_exclusive)
+            if new:
+                self.metrics_chosen_noops.inc(new)
             if new and self.wal is not None:
                 self.wal.append(WalNoopRange(
                     slot_start_inclusive=message.slot_start_inclusive,
@@ -338,6 +349,7 @@ class MenciusReplica(Actor, DurableRole):
         new = self._log_chosen(run.start_slot, run.stride, run.values)
         if new == 0:
             return
+        self.metrics_chosen_commands.inc(new)
         if self.wal is not None:
             # Common case: every slot new -> one raw-copy segment
             # record; partial overlap falls back to per-new-slot
@@ -495,6 +507,15 @@ class MenciusClient(RetryAdmissionMixin, StagedWriteMixin, Actor):
         self.states[pseudonym] = _PendingWrite(
             id, command, callback or (lambda _: None), timer)
         self.ids[pseudonym] = id + 1
+
+    def read(self, pseudonym: int, command: bytes,
+             callback: Optional[Callable[[bytes], None]] = None) -> None:
+        """A linearizable read ordered through the log: ``command`` (a
+        state machine read, such as a ``GetRequest``) is proposed in a
+        slot like a write, and ``callback`` gets what the state machine
+        answered when a replica executed that slot. Mencius has no read
+        path beside the log (docs/MENCIUS.md)."""
+        self.write(pseudonym, command, callback)
 
     # Rejected handling + backoff/reissue scheduling live in
     # RetryAdmissionMixin (runs/client.py); only the re-send is ours.

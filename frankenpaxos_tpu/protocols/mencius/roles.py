@@ -6,9 +6,11 @@ ProxyLeader.scala:31-420, Acceptor.scala:103-300.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import random
 
+import numpy as np
 from sortedcontainers import SortedDict  # type: ignore[import-untyped]
 
 from frankenpaxos_tpu.election.basic import (
@@ -46,6 +48,9 @@ from frankenpaxos_tpu.protocols.mencius.common import (
     Phase2bRun,
     Recover,
 )
+from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+    TpuQuorumTracker,
+)
 from frankenpaxos_tpu.protocols.multipaxos.wire import (
     decode_value,
     decode_value_array,
@@ -62,7 +67,7 @@ from frankenpaxos_tpu.reconfig import (
     Reconfigure,
 )
 from frankenpaxos_tpu.roundsystem import ClassicRoundRobin
-from frankenpaxos_tpu.runtime import Actor, Logger
+from frankenpaxos_tpu.runtime import Actor, FakeCollectors, Logger
 from frankenpaxos_tpu.runtime.transport import Address, Transport
 from frankenpaxos_tpu.wal import (
     DurableRole,
@@ -192,10 +197,18 @@ class MenciusLeader(Actor):
                  admission_inbox_policy: str = "reject",
                  admission_codel_target_s: float = 0.0,
                  admission_codel_interval_s: float = 0.1,
-                 admission_retry_after_ms: int = 0):
+                 admission_retry_after_ms: int = 0,
+                 collectors=None):
         super().__init__(address, transport, logger)
         config.check_valid()
         self.config = config
+        collectors = collectors or FakeCollectors()
+        # How often this leader gossips its next slot, and how often a
+        # lagging group skips its slots with a noop range.
+        self.metrics_high_watermarks = collectors.counter(
+            "mencius_leader_high_watermarks_sent_total")
+        self.metrics_noop_ranges = collectors.counter(
+            "mencius_leader_noop_ranges_sent_total")
         # paxload admission (serve/): built only when armed; the
         # in-flight measure is this group's owned-slot span
         # (next_slot - chosen_watermark) / stride, refreshed on
@@ -393,6 +406,7 @@ class MenciusLeader(Actor):
                 >= self.send_high_watermark_every_n):
             self.send(self._proxy_leader(),
                       HighWatermark(next_slot=self.next_slot))
+            self.metrics_high_watermarks.inc()
             self._commands_since_watermark_send = 0
 
     # --- paxingest (ingest/, docs/TRANSPORT.md) ---------------------------
@@ -665,6 +679,7 @@ class MenciusLeader(Actor):
                                      need_old_quorum=not proven)
         for batch in phase1.pending_batches:
             self._process_batch(batch)
+        self._skip_if_lagging()
         # The backlog just moved into the span; resync so it isn't
         # double-counted.
         self._admitted_backlog = 0
@@ -759,6 +774,14 @@ class MenciusLeader(Actor):
         if message.next_slot <= self.high_watermark:
             return
         self.high_watermark = message.next_slot
+        self._skip_if_lagging()
+
+    def _skip_if_lagging(self) -> None:
+        """Propose noops for this group's slots below the high watermark
+        when it lags by the threshold. Also run on entering phase 2: a
+        watermark heard during phase 1 would otherwise wait for the next
+        one, which a load held up behind this group's holes never
+        sends."""
         if self.state != ("phase2",):
             return
         if self.high_watermark - self.next_slot \
@@ -785,6 +808,7 @@ class MenciusLeader(Actor):
                       Phase2aNoopRange(slot_start_inclusive=at,
                                        slot_end_exclusive=seg_end,
                                        round=self.round))
+            self.metrics_noop_ranges.inc()
             at = seg_end
         self.next_slot = end
 
@@ -954,10 +978,23 @@ class MenciusLeader(Actor):
 
 
 class MenciusProxyLeader(Actor):
-    """(mencius/ProxyLeader.scala:31-420)."""
+    """(mencius/ProxyLeader.scala:31-420).
+
+    ``quorum_backend="dict"`` counts votes in host dicts, as upstream
+    does. ``"tpu"`` counts every vote on a device vote board over global
+    slots (docs/MENCIUS.md): a row per acceptor of every leader group, a
+    slot chosen once ANY group has f+1 votes in its own rows, exact
+    because a slot takes votes only from its own group's acceptors. A
+    strided run or a noop range reaches the board as one strided range
+    a voting acceptor; the drain is dispatched by the tracker and
+    collected by a ``BoardDriver`` (runs/board.py), and what comes back
+    goes out as one ``ChosenRun`` a proposed run and one
+    ``ChosenNoopRange`` a noop range."""
 
     def __init__(self, address: Address, transport: Transport,
-                 logger: Logger, config: MenciusConfig, seed: int = 0):
+                 logger: Logger, config: MenciusConfig, seed: int = 0,
+                 quorum_backend: str = "dict", tpu_window: int = 1 << 20,
+                 collectors=None):
         super().__init__(address, transport, logger)
         config.check_valid()
         self.config = config
@@ -981,6 +1018,171 @@ class MenciusProxyLeader(Actor):
             if len(groups) == 1:
                 self.epochs[lg] = EpochStore.from_members(
                     tuple(groups[0]), config.f)
+        self.tracker = None
+        self.driver = None
+        if quorum_backend == "tpu":
+            self._init_board(tpu_window, collectors)
+        elif quorum_backend != "dict":
+            raise ValueError(f"quorum_backend {quorum_backend!r}: "
+                             f"'dict' or 'tpu'")
+
+    # --- the device vote board (quorum_backend="tpu") ---------------------
+    def _init_board(self, window: int, collectors) -> None:
+        from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+            group_quorum_spec,
+        )
+        from frankenpaxos_tpu.runs.board import BoardDriver
+
+        # Acceptor group ag of leader group lg is board group
+        # lg * A + ag, its acceptor i column (lg * A + ag) * (2f+1) + i.
+        # (Multi-acceptor-group striping is epoch-frozen.)
+        # paxlint: disable=PAX110
+        self._acceptor_groups = len(self.config.acceptor_addresses[0])
+        row_size = 2 * self.config.f + 1
+        groups = self.config.num_leader_groups * self._acceptor_groups
+        self.tracker = TpuQuorumTracker(
+            None, window=window, row_size=row_size,
+            spec=group_quorum_spec(groups, row_size, self.config.f + 1))
+        self.driver = BoardDriver(self, self.tracker, self._emit_chosen,
+                                  collectors or FakeCollectors(),
+                                  "mencius_proxy_leader")
+        # What was proposed and not yet chosen: start -> [round, stride,
+        # count, values (None: a noop range), remaining (bool per
+        # position), left]; the starts of each leader group's records,
+        # sorted, for the slot -> record bisect.
+        self._records: dict[int, list] = {}
+        self._starts: list[list] = [
+            [] for _ in range(self.config.num_leader_groups)]
+        # Single-slot Phase2as: (slot, round) -> value.
+        self._slot_values: dict[tuple[int, int], object] = {}
+
+    def _board_group(self, slot: int, acceptor_group: int) -> int:
+        return (self.slot_system.leader(slot) * self._acceptor_groups
+                + acceptor_group)
+
+    def _note_proposal(self, start: int, stride: int, round: int,
+                       count: int, values) -> bool:
+        """A proposed strided run (``values``) or noop range (None) of
+        one leader group, awaiting its board decisions. A same-start
+        record of a lower round is evicted (round-monotone, as the
+        acceptors' vote stores are); against one of the same or a
+        higher round this is a duplicate: False."""
+        old = self._records.get(start)
+        if old is not None:
+            if round <= old[0]:
+                return False
+            self._drop_record(start)
+        self._records[start] = [round, stride, count, values,
+                                np.ones(count, dtype=bool), count]
+        bisect.insort(self._starts[self.slot_system.leader(start)], start)
+        return True
+
+    def _drop_record(self, start: int) -> None:
+        del self._records[start]
+        starts = self._starts[self.slot_system.leader(start)]
+        starts.pop(bisect.bisect_left(starts, start))
+
+    def _record_for(self, slot: int, round: int):
+        """The start of the pending record holding (slot, round), or
+        None."""
+        starts = self._starts[self.slot_system.leader(slot)]
+        i = bisect.bisect_right(starts, slot) - 1
+        if i < 0:
+            return None
+        start = starts[i]
+        rnd, stride, count = self._records[start][:3]
+        if (rnd == round and (slot - start) % stride == 0
+                and (slot - start) // stride < count):
+            return start
+        return None
+
+    def on_drain(self) -> None:
+        # Stage ``drain``: the tracker's plan and its device dispatch,
+        # and the hand-over of what it dispatched; the decisions come
+        # back through the BoardDriver's collector under ``fan-out``.
+        if self.driver is None:
+            return
+        if self.tracker.has_votes():
+            with self.trace_stage("drain"):
+                self.tracker.drain()
+                self.driver.hand_over()
+        else:
+            self.driver.hand_over()
+
+    def _emit_chosen(self, keys) -> None:
+        """A batch of decided (slot, round)s: cut into runs of
+        consecutive slots of one leader group and one round, each cut
+        again at record boundaries, so a proposed run goes out as one
+        ``ChosenRun`` and a noop range as one ``ChosenNoopRange`` a
+        batch, never a slot at a time."""
+        if not keys:
+            return
+        with self.trace_stage("fan-out"):
+            stride = self.config.num_leader_groups
+            slots = np.fromiter((k[0] for k in keys), dtype=np.int64,
+                                count=len(keys))
+            rounds = np.fromiter((k[1] for k in keys), dtype=np.int64,
+                                 count=len(keys))
+            order = np.lexsort((slots, rounds, slots % stride))
+            slots, rounds = slots[order], rounds[order]
+            breaks = np.flatnonzero((np.diff(slots) != stride)
+                                    | (np.diff(rounds) != 0)) + 1
+            at = 0
+            for b in breaks.tolist() + [len(slots)]:
+                self._emit_segment(int(slots[at]), int(slots[b - 1]),
+                                   int(rounds[at]))
+                at = b
+
+    def _emit_segment(self, lo: int, hi: int, rnd: int) -> None:
+        """Chosen slots ``lo, lo + G, ..., hi`` (inclusive) of one leader
+        group in round ``rnd``."""
+        stride = self.config.num_leader_groups
+        while lo <= hi:
+            value = self._slot_values.pop((lo, rnd), None)
+            if value is not None:
+                self.broadcast(self.config.replica_addresses,
+                               Chosen(slot=lo, value=value))
+                lo += stride
+                continue
+            start = self._record_for(lo, rnd)
+            if start is None:
+                lo += stride  # not proposed here, or already emitted
+                continue
+            record = self._records[start]
+            run_stride, count = record[1], record[2]
+            first = (lo - start) // run_stride
+            last = min(count - 1, (hi - start) // run_stride)
+            self._emit_positions(start, record, first, last)
+            lo = start + (last + 1) * run_stride
+
+    def _emit_positions(self, start: int, record: list, first: int,
+                        last: int) -> None:
+        """Positions ``first..last`` of one record are chosen: one
+        message to every replica for the fresh ones."""
+        _, stride, count, values, remaining, left = record
+        fresh = remaining[first:last + 1]
+        if not fresh.all():
+            # A re-report (the tracker reports once; a record replaced
+            # by a higher round's can see its slots again): emit only
+            # the fresh positions.
+            for p in (first + np.flatnonzero(fresh)).tolist():
+                self._emit_positions(start, record, p, p)
+            return
+        fresh[:] = False
+        record[5] = left - (last - first + 1)
+        lo = start + first * stride
+        if values is None:
+            message = ChosenNoopRange(slot_start_inclusive=lo,
+                                      slot_end_exclusive=start
+                                      + last * stride + 1)
+        else:
+            message = ChosenRun(
+                start_slot=lo, stride=stride,
+                values=(values if first == 0 and last == count - 1
+                        else values[first:last + 1]))
+        self.broadcast(self.config.replica_addresses, message)
+        if record[5] == 0:
+            self._drop_record(start)
 
     # A GROUP-COUNT read for the striping arithmetic, not a membership
     # read; group counts are structural (reconfig swaps members within
@@ -996,6 +1198,14 @@ class MenciusProxyLeader(Actor):
 
     def _handle_epoch_commit(self, src: Address,
                              commit: EpochCommit) -> None:
+        if self.tracker is not None:
+            # The board counts a vote by the (group, index) it carries,
+            # which a replacement acceptor can share with the member it
+            # replaced; so this proxy never acks, and the epoch never
+            # activates (docs/MENCIUS.md).
+            self.logger.warn("EpochCommit ignored: reconfiguration needs "
+                             "quorum_backend=dict")
+            return
         store = self.epochs.get(self.slot_system.leader(commit.start_slot))
         if store is None:
             return
@@ -1054,9 +1264,20 @@ class MenciusProxyLeader(Actor):
                                      self.config.quorum_size)
         for acceptor in quorum:
             self.send(acceptor, phase2a)
+        if self.tracker is not None:
+            self.states[key] = None  # proposed; the board counts
+            self._slot_values[(phase2a.slot, phase2a.round)] = \
+                phase2a.value
+            return
         self.states[key] = {"phase2a": phase2a, "phase2bs": {}}
 
     def _handle_phase2b(self, src: Address, phase2b: Phase2b) -> None:
+        if self.tracker is not None:
+            self.tracker.record(
+                phase2b.slot, phase2b.round,
+                self._board_group(phase2b.slot, phase2b.group_index),
+                phase2b.acceptor_index)
+            return
         key = (phase2b.slot, phase2b.slot + 1, phase2b.round)
         state = self.states.get(key)
         if key not in self.states:
@@ -1098,15 +1319,20 @@ class MenciusProxyLeader(Actor):
                     slot=run.start_slot + i * run.stride,
                     round=run.round, value=value))
             return
-        pending = self._runs.get(run.start_slot)
-        if pending is not None:
-            if run.round <= pending[0]:
+        if self.tracker is not None:
+            if not self._note_proposal(run.start_slot, run.stride,
+                                       run.round, k, run.values):
                 return  # duplicate (same or stale round)
-            # Round-monotone eviction, mirroring the acceptor: the
-            # higher-round re-proposal wins; remember the evicted round
-            # so its straggler acks are recognized.
-            self._done_runs.setdefault(run.start_slot,
-                                       set()).add(pending[0])
+        else:
+            pending = self._runs.get(run.start_slot)
+            if pending is not None:
+                if run.round <= pending[0]:
+                    return  # duplicate (same or stale round)
+                # Round-monotone eviction, mirroring the acceptor: the
+                # higher-round re-proposal wins; remember the evicted
+                # round so its straggler acks are recognized.
+                self._done_runs.setdefault(run.start_slot,
+                                           set()).add(pending[0])
         config = self._epoch_for_slot(run.start_slot)
         if config is not None:
             # A run never spans epochs (the leader buffers through the
@@ -1120,14 +1346,25 @@ class MenciusProxyLeader(Actor):
                                      self.config.quorum_size)
         for acceptor in quorum:
             self.send(acceptor, run)  # encode the values ONCE
-        self._runs[run.start_slot] = [run.round, run.stride,
-                                      run.values, set()]
+        if self.tracker is None:
+            self._runs[run.start_slot] = [run.round, run.stride,
+                                          run.values, set()]
 
     def _handle_phase2b_run(self, src: Address,
                             phase2b: Phase2bRun) -> None:
         """Acceptors vote runs atomically, so quorum tracking is
         run-granular: count distinct acceptors, emit ONE ChosenRun per
-        replica when f+1 acked."""
+        replica when f+1 acked. On the board, the run's votes are one
+        strided range."""
+        if self.tracker is not None:
+            self.tracker.record_range(
+                phase2b.start_slot,
+                phase2b.start_slot + phase2b.count * phase2b.stride,
+                phase2b.round,
+                self._board_group(phase2b.start_slot,
+                                  phase2b.acceptor_group_index),
+                phase2b.acceptor_index, phase2b.stride)
+            return
         run = self._runs.get(phase2b.start_slot)
         if run is None or run[0] != phase2b.round:
             if phase2b.round in self._done_runs.get(phase2b.start_slot,
@@ -1167,22 +1404,51 @@ class MenciusProxyLeader(Actor):
             for acceptor in self.rng.sample(list(config.members),
                                             config.quorum_size):
                 self.send(acceptor, phase2a)
-            self.states[key] = {"noop_range": phase2a,
-                                "phase2bs_per_group": [{}]}
+            self._note_noop_range(key, phase2a, 1)
             return
         # paxlint: disable=PAX110 -- multi-group striping is frozen
         for group in self.config.acceptor_addresses[leader_group]:
             for acceptor in self.rng.sample(list(group),
                                             self.config.quorum_size):
                 self.send(acceptor, phase2a)
-        self.states[key] = {
-            "noop_range": phase2a,
-            "phase2bs_per_group": [
-                {} for _ in self.config.acceptor_addresses[leader_group]],
-        }
+        self._note_noop_range(
+            key, phase2a, len(self.config.acceptor_addresses[leader_group]))
+
+    def _note_noop_range(self, key: tuple, phase2a: Phase2aNoopRange,
+                         acceptor_groups: int) -> None:
+        if self.tracker is not None:
+            # The range's slots of its leader group (its start is one),
+            # one record; the board counts each acceptor group's own.
+            self.states[key] = None
+            stride = self.config.num_leader_groups
+            count = len(range(phase2a.slot_start_inclusive,
+                              phase2a.slot_end_exclusive, stride))
+            if count:
+                self._note_proposal(phase2a.slot_start_inclusive, stride,
+                                    phase2a.round, count, None)
+            return
+        self.states[key] = {"noop_range": phase2a,
+                            "phase2bs_per_group": [
+                                {} for _ in range(acceptor_groups)]}
 
     def _handle_phase2b_noop_range(self, src: Address,
                                    phase2b: Phase2bNoopRange) -> None:
+        if self.tracker is not None:
+            # The acceptor voted its own acceptor group's slots of the
+            # range (MenciusAcceptor._store_noop_range).
+            groups = self.config.num_leader_groups
+            stride = groups * self._acceptor_groups
+            start = phase2b.slot_start_inclusive
+            while (start < phase2b.slot_end_exclusive
+                   and (start // groups) % self._acceptor_groups
+                   != phase2b.acceptor_group_index):
+                start += groups
+            self.tracker.record_range(
+                start, phase2b.slot_end_exclusive, phase2b.round,
+                self._board_group(phase2b.slot_start_inclusive,
+                                  phase2b.acceptor_group_index),
+                phase2b.acceptor_index, stride)
+            return
         key = (phase2b.slot_start_inclusive, phase2b.slot_end_exclusive,
                phase2b.round)
         state = self.states.get(key)

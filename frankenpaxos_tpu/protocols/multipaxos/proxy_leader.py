@@ -17,7 +17,6 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import random
-import time
 
 import numpy as np
 
@@ -44,13 +43,9 @@ from frankenpaxos_tpu.reconfig import (
     EpochQuorumTracker,
     EpochStore,
 )
+from frankenpaxos_tpu.runs.board import BoardDriver
 from frankenpaxos_tpu.runtime import Actor, Collectors, FakeCollectors, Logger
 from frankenpaxos_tpu.runtime.transport import Address, Transport
-
-
-# SimTransport only: how long after a drain the flush timer collects
-# what the tracker dispatched, if no further message came first.
-TPU_FLUSH_PERIOD_S = 0.005
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,31 +79,6 @@ class ProxyLeader(Actor):
             "multipaxos_proxy_leader_requests_latency_seconds", labels=("type",))
         self.metrics_requests = collectors.counter(
             "multipaxos_proxy_leader_requests_total", labels=("type",))
-        # Overlap instrumentation of the tpu tracker: how many dispatches
-        # are in flight when a new one is queued (depth 0 = no overlap,
-        # every fetch is serialized behind its drain) and how long each
-        # device collect blocks the worker thread.
-        self.metrics_tpu_dispatches = collectors.counter(
-            "multipaxos_proxy_leader_tpu_dispatches_total")
-        self.metrics_tpu_inflight = collectors.summary(
-            "multipaxos_proxy_leader_tpu_inflight_at_dispatch")
-        self.metrics_tpu_collect = collectors.summary(
-            "multipaxos_proxy_leader_tpu_collect_seconds")
-        # The tpu tracker's own counts, published after every drain.
-        # The one child of ``path`` is what benchmark/harness/
-        # readings.py asks for by name.
-        tpu_drains = collectors.counter(
-            "multipaxos_proxy_leader_tpu_drains_total", labels=("path",))
-        tpu_votes = collectors.counter(
-            "multipaxos_proxy_leader_tpu_votes_total", labels=("path",))
-        # In the order _publish_tpu_counts reads the tracker's counts.
-        self.metrics_tpu_work = (
-            tpu_drains.labels("device"), tpu_votes.labels("device"),
-            collectors.counter(
-                "multipaxos_proxy_leader_tpu_launches_total"),
-            collectors.counter(
-                "multipaxos_proxy_leader_tpu_window_violations_total"))
-        self._tpu_published = (0,) * len(self.metrics_tpu_work)
         # The epoch tracker's counts (reconfig/), published after each
         # of its drains: in the order _publish_epoch_counts reads them.
         self.metrics_epoch_work = (
@@ -182,60 +152,12 @@ class ProxyLeader(Actor):
         # EpochPhase2aRuns for epochs this proxy has not seen the
         # commit for yet: epoch -> [run]; replayed when it arrives.
         self._stashed_epoch_runs: dict[int, list] = {}
-        self._flush_timer = None
-        self._collector = None
+        # The device tracker's driving (runs/board.py): its counts, the
+        # collector thread or the sim's flush timer, the hand-back.
+        self.driver: "BoardDriver | None" = None
         if options.quorum_backend == "tpu":
-            # Branch on the transport's CAPABILITY (threaded event loop),
-            # not on whether its loop happens to exist yet: a TcpTransport
-            # actor constructed before start() must still get the
-            # collector thread, and a SimTransport must never (its actors
-            # run inline on the caller's thread).
-            if transport.threaded:
-                # Real transport: fetch device results on ONE daemon
-                # worker thread (preserving dispatch order) and post
-                # each completion back onto the event loop, so the loop
-                # never blocks on a device fetch. A daemon thread (vs a
-                # ThreadPoolExecutor, whose threads are joined at
-                # interpreter exit) cannot hold up process shutdown.
-                import queue
-                import threading
-
-                self._collector = queue.Queue()
-                # 1 while the collector thread is inside a device
-                # collect (that dispatch has left the queue but is
-                # still in flight); single writer, read for metrics.
-                self._collecting = 0
-
-                def collect_loop():
-                    # This thread's own stage accumulators (the loop's
-                    # belong to the loop), where /metrics is on.
-                    metrics = self.transport.runtime_metrics
-                    stages = (None if metrics is None
-                              else metrics.thread_stages())
-                    while True:
-                        dispatch, queued_at = self._collector.get()
-                        if stages is not None:
-                            stages.stage("dispatch-wait").add(
-                                time.perf_counter() - queued_at)
-                        self._collecting = 1
-                        try:
-                            self._collect_and_post(dispatch, stages)
-                        finally:
-                            self._collecting = 0
-
-                threading.Thread(target=collect_loop, daemon=True,
-                                 name="tpu-collect").start()
-            else:
-                # SimTransport: a flush timer collects synchronously
-                # (tests fire it explicitly).
-                def flush_pending():
-                    self._collect_all()
-                    if self.tracker.has_pending():
-                        self._flush_timer.start()
-
-                self._flush_timer = self.timer(
-                    "tpuDrainFlush", TPU_FLUSH_PERIOD_S,
-                    flush_pending)
+            self.driver = BoardDriver(self, self.tracker, self._emit_chosen,
+                                      collectors, "multipaxos_proxy_leader")
         if options.epoch_quorums and self.epochs is not None:
             self._ensure_epoch_tracker()
 
@@ -636,46 +558,10 @@ class ProxyLeader(Actor):
             self._emit_chosen(chosen)
 
     def _hand_over_dispatches(self) -> None:
-        """After a drain: publish the tracker's counts, and pass what
-        it dispatched to the collector thread (or arm the sim's flush
-        timer)."""
-        if self.options.quorum_backend == "tpu":
-            self._publish_tpu_counts()
-        if self._collector is not None:
-            while True:
-                dispatch = self.tracker.take_dispatch()
-                if dispatch is None:
-                    break
-                self.metrics_tpu_dispatches.inc()
-                # Depth includes the dispatch the collector thread is
-                # currently blocked on (it left the queue but is in
-                # flight): a healthy one-deep pipeline must read 1,
-                # not 0 -- 0 means every fetch is serialized.
-                self.metrics_tpu_inflight.observe(
-                    self._collector.qsize()
-                    + getattr(self, "_collecting", 0))
-                # With the time it was queued: the collector thread
-                # accounts the wait as ``dispatch-wait``.
-                self._collector.put((dispatch, time.perf_counter()))
-        elif self._flush_timer is not None:
-            # (Re)arm the quiescence flush while a dispatch is in
-            # flight; the timer collects it if no further messages come.
-            self._flush_timer.stop()
-            if self.tracker.has_pending():
-                self._flush_timer.start()
-
-    def _publish_tpu_counts(self) -> None:
-        """The tracker's work counts into /metrics, as increments:
-        colocated proxy leaders share one series."""
-        t = self.tracker
-        counts = (t.device_drains, t.device_votes, t.device_launches,
-                  t.checker.window_violations)
-        if counts == self._tpu_published:
-            return
-        for series, now, then in zip(self.metrics_tpu_work, counts,
-                                     self._tpu_published):
-            series.inc(now - then)
-        self._tpu_published = counts
+        """After a drain: what the device tracker dispatched goes to its
+        driver (runs/board.py)."""
+        if self.driver is not None:
+            self.driver.hand_over()
 
     def _publish_epoch_counts(self) -> None:
         """The epoch tracker's work counts into /metrics, as
@@ -686,58 +572,6 @@ class ProxyLeader(Actor):
                                      self._epoch_published):
             series.inc(now - then)
         self._epoch_published = counts
-
-    def _collect_and_post(self, dispatch, stages) -> None:
-        """Runs on the collector thread: block on the device fetch, then
-        hand the results back to the single-threaded event loop.
-        ``stages`` are this thread's stage accumulators, or None."""
-        try:
-            # Stage ``collect`` and the collect summary share ONE clock
-            # pair, taken by hand: between collect() returning and the
-            # hand-back nothing is done but the second reading (and,
-            # while a device trace runs, closing the annotation); the
-            # adds come after the results are on their way.
-            stage = span = None
-            if stages is not None:
-                stage = stages.stage("collect")
-                stages.refresh()
-                if stages.annotation is not None:
-                    span = stage.open_span()
-            t0 = time.perf_counter()
-            results = self.tracker.collect(dispatch)
-            t1 = time.perf_counter()
-            if span is not None:
-                span.__exit__(None, None, None)
-            if results:
-                self.transport.loop.call_soon_threadsafe(
-                    self._emit_handed_back, results, t1)
-            if stage is not None:
-                stage.add(t1 - t0)
-            self.metrics_tpu_collect.observe(t1 - t0)
-        except RuntimeError as e:
-            # Loop closed during teardown: dropping in-flight results is
-            # expected, but say so.
-            self.logger.debug(f"tpu collect post skipped: {e!r}")
-        except Exception as e:  # noqa: BLE001 - surface, don't swallow
-            # A swallowed collector error would silently drop this
-            # dispatch's Chosen broadcasts and wedge its clients.
-            self.logger.error(f"tpu collect failed: {e!r}")
-
-    def _emit_handed_back(self, keys, collected_at: float) -> None:
-        """On the event loop: what a collector thread fetched. Stage
-        ``handback-wait`` is the time it waited for the loop."""
-        metrics = self.transport.runtime_metrics
-        if metrics is not None:
-            metrics.observe_stage("handback-wait",
-                                  time.perf_counter() - collected_at)
-        self._emit_chosen(keys)
-
-    def _collect_all(self) -> None:
-        while True:
-            dispatch = self.tracker.take_dispatch()
-            if dispatch is None:
-                return
-            self._emit_chosen(self.tracker.collect(dispatch))
 
     def _emit_chosen(self, keys) -> None:
         if not keys:

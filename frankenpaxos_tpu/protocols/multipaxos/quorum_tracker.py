@@ -14,7 +14,9 @@ implementations:
     Acceptor coordinates flatten to columns ``group * group_size + index``.
     In non-flexible mode only a slot's own group is ever messaged, so a
     universe-wide count >= f+1 threshold is exactly the per-group f+1
-    quorum; in flexible mode the grid write-spec applies.
+    quorum; in flexible mode the grid write-spec applies. A tracker
+    built from a ``spec`` instead (Mencius: ANY of one row threshold a
+    leader group, :func:`group_quorum_spec`) judges by that.
 
 Both report each (slot, round)'s quorum exactly once.
 """
@@ -39,10 +41,13 @@ class QuorumTracker(abc.ABC):
         ...
 
     def record_range(self, slot_start: int, slot_end: int, round: int,
-                     group_index: int, acceptor_index: int) -> None:
-        """One acceptor's votes for slots [slot_start, slot_end) in one
-        round (a Phase2bRange). Default: per-slot expansion."""
-        for slot in range(slot_start, slot_end):
+                     group_index: int, acceptor_index: int,
+                     stride: int = 1) -> None:
+        """One acceptor's votes for slots ``range(slot_start, slot_end,
+        stride)`` in one round: a Phase2bRange (stride 1), or a Mencius
+        leader group's strided run or noop range. Default: per-slot
+        expansion."""
+        for slot in range(slot_start, slot_end, stride):
             self.record(slot, round, group_index, acceptor_index)
 
     def record_votes(self, slots, rounds, group_index: int,
@@ -100,12 +105,28 @@ class DictQuorumTracker(QuorumTracker):
         return newly
 
 
+def group_quorum_spec(groups: int, row_size: int,
+                      threshold: int) -> QuorumSpec:
+    """ANY of ``groups`` rows, each satisfied by ``threshold`` of its own
+    ``row_size`` columns (group ``g`` owns columns ``g * row_size`` up
+    to ``(g + 1) * row_size``). Exact for a board whose slots each take
+    votes from one group only, as a Mencius leader group's slots do."""
+    num_cols = groups * row_size
+    masks = np.zeros((groups, num_cols), dtype=np.uint8)
+    for g in range(groups):
+        masks[g, g * row_size:(g + 1) * row_size] = 1
+    return QuorumSpec(masks=masks,
+                      thresholds=np.full(groups, threshold, dtype=np.int32),
+                      combine=ANY, universe=tuple(range(num_cols)))
+
+
 def expand_votes(slots: list, cols: list, rounds: list, ranges: list,
                  array_votes: list) -> tuple:
     """A drain's three vote buffers (single votes as three lists, ranges
-    ``(start, end, col, round)``, packed arrays ``(slots, col, rounds)``)
-    as three arrays, one entry a vote, in that order. Vectorized: the
-    whole point of Phase2bRange / Phase2bVotes is no per-slot Python
+    ``(start, end, col, round, stride)`` for the slots ``range(start,
+    end, stride)``, packed arrays ``(slots, col, rounds)``) as three
+    arrays, one entry a vote, in that order. Vectorized: the whole point
+    of Phase2bRange / Phase2bVotes / a strided run is no per-slot Python
     before the device call."""
     slots = np.asarray(slots, dtype=np.int64)
     cols = np.asarray(cols, dtype=np.int32)
@@ -115,9 +136,10 @@ def expand_votes(slots: list, cols: list, rounds: list, ranges: list,
     parts_s = [slots] if slots.size else []
     parts_c = [cols] if slots.size else []
     parts_r = [rounds] if slots.size else []
-    for start, end, col, rnd in ranges:
-        width = end - start
-        parts_s.append(np.arange(start, end, dtype=np.int64))
+    for start, end, col, rnd, stride in ranges:
+        part = np.arange(start, end, stride, dtype=np.int64)
+        width = part.size
+        parts_s.append(part)
         parts_c.append(np.full(width, col, dtype=np.int32))
         parts_r.append(np.full(width, rnd, dtype=np.int32))
     for s_arr, col, r_arr in array_votes:
@@ -143,7 +165,11 @@ class BoardDrainPlanner:
     before the newer round's preemption clears it; the dominant round's
     slots are clustered into contiguous runs and chunked at the
     prewarmed bucket widths; what is too thin for a block, and newer
-    rounds, take the scatter in chunks of ``max_chunk``. It returns the
+    rounds, take the scatter in chunks of ``max_chunk``. "Too thin" is
+    under ``min_fill`` votes a column of the span, divided by the widest
+    stride among the drain's ranges: a leader group's strided run can
+    fill at most one column in ``stride``, and its block is no emptier
+    than that allows. It returns the
     calls' ``parts``; :meth:`fetch` waits for them and reports each
     (slot, round) exactly once.
 
@@ -212,6 +238,8 @@ class BoardDrainPlanner:
         :func:`expand_votes` takes them, at least one vote in all) onto
         ``checker``'s board without waiting; the calls' parts, for
         :meth:`fetch`."""
+        min_fill = self.min_fill / max(
+            (r[4] for r in ranges), default=1)
         slots, cols, rounds = expand_votes(slots, cols, rounds, ranges,
                                            array_votes)
         self.votes += slots.shape[0]
@@ -226,7 +254,7 @@ class BoardDrainPlanner:
             bucket = next((b for b in self.dense_buckets if b >= width),
                           None) if width <= self.max_dense else None
             if (bucket is not None
-                    and slots.shape[0] >= width * self.min_fill):
+                    and slots.shape[0] >= width * min_fill):
                 block = np.zeros((checker.num_nodes, bucket),
                                  dtype=np.uint8)
                 block[cols, slots - lo] = 1
@@ -261,7 +289,7 @@ class BoardDrainPlanner:
             cs = ss[cluster]
             hi = int(cs[-1])
             width = hi - int(cs[0]) + 1
-            if cl.size < width * self.min_fill:
+            if cl.size < width * min_fill:
                 sparse_leftover.append(cl)
                 continue
             # Chunk the run at prewarmed bucket widths. Each chunk
@@ -441,8 +469,13 @@ class TpuQuorumTracker(QuorumTracker):
     host_votes = 0
     spilled_votes = 0
 
-    def __init__(self, config: MultiPaxosConfig, window: int = 1 << 20,
-                 mesh=None):
+    def __init__(self, config: "MultiPaxosConfig | None",
+                 window: int = 1 << 20, mesh=None, *,
+                 spec: "QuorumSpec | None" = None, row_size: int = 0):
+        """The board's predicate is ``config``'s write quorum, or
+        ``spec`` with acceptor ``(group, index)`` at column ``group *
+        row_size + index`` where a ``spec`` is given (``config`` is then
+        not read)."""
         import collections
 
         self.config = config
@@ -451,18 +484,22 @@ class TpuQuorumTracker(QuorumTracker):
         # append/popleft are GIL-atomic, so a collector thread may pop
         # while the event loop appends.
         self._inflight = collections.deque()
-        self._row_size = len(config.acceptor_addresses[0])
-        num_cols = config.num_acceptor_groups * self._row_size
-        universe = tuple(range(num_cols))
-        if config.flexible:
-            spec = config.quorum_grid().write_spec().reindexed(universe)
+        if spec is None:
+            self._row_size = len(config.acceptor_addresses[0])
+            num_cols = config.num_acceptor_groups * self._row_size
+            universe = tuple(range(num_cols))
+            if config.flexible:
+                spec = config.quorum_grid().write_spec().reindexed(
+                    universe)
+            else:
+                spec = QuorumSpec(
+                    masks=np.ones((1, num_cols), dtype=np.uint8),
+                    thresholds=np.array([config.f + 1], dtype=np.int32),
+                    combine=ANY,
+                    universe=universe,
+                )
         else:
-            spec = QuorumSpec(
-                masks=np.ones((1, num_cols), dtype=np.uint8),
-                thresholds=np.array([config.f + 1], dtype=np.int32),
-                combine=ANY,
-                universe=universe,
-            )
+            self._row_size = row_size
         self._planner = BoardDrainPlanner(window)
         # Lazy: keeps jax out of dict-backend role processes entirely
         # (it costs seconds of startup per process).
@@ -472,9 +509,10 @@ class TpuQuorumTracker(QuorumTracker):
         self._slots: list[int] = []
         self._cols: list[int] = []
         self._rounds: list[int] = []
-        # Ranged votes (Phase2bRange): [(start, end, col, round)] --
-        # O(1) Python per message, expanded vectorized at drain time.
-        self._ranges: list[tuple[int, int, int, int]] = []
+        # Ranged votes (Phase2bRange, strided runs): [(start, end, col,
+        # round, stride)] -- O(1) Python per message, expanded
+        # vectorized at drain time.
+        self._ranges: list[tuple[int, int, int, int, int]] = []
         # Packed array votes (Phase2bVotes): [(slots, col, rounds)] --
         # O(1) Python per message, arrays straight off the native
         # codec's unpack.
@@ -489,19 +527,23 @@ class TpuQuorumTracker(QuorumTracker):
     def device_launches(self) -> int:
         return self._planner.launches
 
+    @property
+    def dense_votes(self) -> int:
+        return self._planner.dense_votes
+
     def record(self, slot, round, group_index, acceptor_index) -> None:
         self._slots.append(slot)
         self._cols.append(group_index * self._row_size + acceptor_index)
         self._rounds.append(round)
 
     def record_range(self, slot_start, slot_end, round, group_index,
-                     acceptor_index) -> None:
+                     acceptor_index, stride=1) -> None:
         if slot_end <= slot_start:
             # Drop empties like record_votes does.
             return
         self._ranges.append((slot_start, slot_end,
                              group_index * self._row_size
-                             + acceptor_index, round))
+                             + acceptor_index, round, stride))
 
     def record_votes(self, slots, rounds, group_index,
                      acceptor_index) -> None:

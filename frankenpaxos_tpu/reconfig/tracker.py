@@ -153,7 +153,7 @@ class EpochQuorumTracker:
         col = self.store.column_of(voter)
         if col is None or slot_end <= slot_start:
             return
-        self._ranges.append((slot_start, slot_end, col, round))
+        self._ranges.append((slot_start, slot_end, col, round, 1))
 
     def record_votes(self, slots, rounds, voter) -> None:
         """One voter's votes for an arbitrary slot array (a packed

@@ -83,7 +83,8 @@ def make_mencius(f=1, num_leader_groups=2, num_acceptor_groups=1,
                  state_machine_factory=AppendLog, seed=0,
                  wal=False, leader_admission: dict | None = None,
                  client_retry_budget: int = 0,
-                 client_backoff=None) -> MenciusSim:
+                 client_backoff=None, quorum_backend: str = "dict",
+                 tpu_window: int = 1 << 12) -> MenciusSim:
     logger = FakeLogger(LogLevel.FATAL)
     transport = SimTransport(logger)
     wal_storages: dict = {}
@@ -128,7 +129,9 @@ def make_mencius(f=1, num_leader_groups=2, num_acceptor_groups=1,
                for g, group in enumerate(config.leader_addresses)
                for i, a in enumerate(group)]
     proxy_leaders = [MenciusProxyLeader(a, transport, logger, config,
-                                        seed=seed + 50 + i)
+                                        seed=seed + 50 + i,
+                                        quorum_backend=quorum_backend,
+                                        tpu_window=tpu_window)
                      for i, a in enumerate(config.proxy_leader_addresses)]
     acceptors = [MenciusAcceptor(a, transport, logger, config,
                                  wal=wal_for(a))

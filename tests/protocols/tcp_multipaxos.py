@@ -197,7 +197,8 @@ class TcpMultiPaxos:
         collect = owner["multipaxos_proxy_leader_tpu_collect_seconds"]
 
         def collected() -> bool:
-            return (all(proxy._collector.empty() and not proxy._collecting
+            return (all(proxy.driver.collector.empty()
+                        and not proxy.driver.collecting
                         for proxy in self.actors[self.OWNER])
                     and collect.get_count() == dispatched.get())
 

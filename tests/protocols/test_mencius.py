@@ -381,3 +381,83 @@ def test_simulation_no_divergence(kwargs):
     failure = Simulator(MenciusSimulated(**kwargs), run_length=150,
                         num_runs=15).run(seed=0)
     assert failure is None, str(failure)
+
+
+def test_a_read_goes_through_the_log():
+    """``MenciusClient.read`` proposes the read in a slot like a write:
+    its answer is the state machine's at that place in the log, after
+    the write before it."""
+    from frankenpaxos_tpu.runtime.serializer import PickleSerializer
+    from frankenpaxos_tpu.statemachine import (
+        GetRequest,
+        KeyValueStore,
+        SetRequest,
+    )
+    from tests.protocols.mencius_harness import make_mencius as make
+
+    sim = make(num_leader_groups=3, coalesced=True, lag_threshold=1,
+               state_machine_factory=KeyValueStore)
+    serializer = PickleSerializer()
+    client = sim.clients[0]
+    got = []
+
+    def settle():
+        sim.transport.deliver_all_coalesced()
+        for _ in range(30):
+            if len(got) == 2:
+                return
+            for timer in sim.transport.running_timers():
+                if timer.name == "recover":
+                    sim.transport.trigger_timer(timer.id)
+            sim.transport.deliver_all_coalesced()
+
+    client.write(0, serializer.to_bytes(SetRequest((("k", "v1"),))),
+                 got.append)
+    client.flush_writes()
+    settle()
+    client.read(0, serializer.to_bytes(GetRequest(("k",))), got.append)
+    client.flush_writes()
+    settle()
+    assert len(got) == 2
+    assert dict(serializer.from_bytes(got[1]).key_values) == {"k": "v1"}
+    # The read took a slot of its own in the log.
+    from frankenpaxos_tpu.protocols.mencius.common import Noop
+
+    assert sum(len(v.commands) for v in executed_prefix(sim.replicas[0])
+               if not isinstance(v, Noop)) == 2
+
+
+def test_a_group_that_enters_phase_2_late_skips_what_it_heard():
+    """A leader still in phase 1 when the others' high watermarks reach
+    it skips its slots below them as it enters phase 2: the writes held
+    up behind its holes send no further watermark, so waiting for one
+    would leave them to a replica's recover timer."""
+    from frankenpaxos_tpu.protocols.mencius.common import Noop, Phase1b
+    from frankenpaxos_tpu.runtime.serializer import DEFAULT_SERIALIZER
+    from tests.protocols.mencius_harness import make_mencius as make
+
+    sim = make(num_leader_groups=3, coalesced=True, lag_threshold=1)
+    transport = sim.transport
+    late = sim.config.leader_addresses[1][0]
+    client = sim.clients[0]
+    client._random_group_leader = lambda: client._leader_of_group(0)
+    got = []
+    for p in range(4):
+        client.write(p, b"v%d" % p, got.append)
+    client.flush_writes()
+
+    def held(message) -> bool:
+        return message.dst == late and isinstance(
+            DEFAULT_SERIALIZER.from_bytes(message.data), Phase1b)
+
+    while True:
+        transport._compact_messages()
+        ready = [m for m in transport.messages if not held(m)]
+        if not ready:
+            break
+        transport.deliver_message(ready[0])
+    assert len(got) < 4  # group 1's slots are holes
+    transport.deliver_all()  # its phase 1 completes; no timer fires
+    assert len(got) == 4
+    log = executed_prefix(sim.replicas[0])
+    assert any(isinstance(v, Noop) for v in log[1::3])

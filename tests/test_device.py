@@ -212,4 +212,41 @@ def test_overrides_of_the_tracker_mode_that_went_are_ignored_and_listed():
     assert type(proxy_leader.tracker) is TpuQuorumTracker
     assert proxy_leader.tracker.checker.window == 256
     # Built for a SimTransport: the flush timer, which collects.
-    assert proxy_leader._flush_timer is not None
+    assert proxy_leader.driver.flush_timer is not None
+
+
+def test_the_mencius_proxy_leader_takes_its_options_and_owns_the_chip():
+    """``--options.quorum_backend tpu`` reaches the Mencius proxy
+    leader (and ``tpu_window`` with it), its role declares the option
+    that makes it own the chip, and a launch colocates both proxy
+    leaders in the one chip-owning process."""
+    from frankenpaxos_tpu.deploy import DeployCtx
+    from frankenpaxos_tpu.protocols.multipaxos.quorum_tracker import (
+        TpuQuorumTracker,
+    )
+    from frankenpaxos_tpu.runtime import FakeLogger, SimTransport
+
+    protocol = get_protocol("mencius")
+    ports = iter(range(21000, 22000))
+    config = protocol.load_config(
+        protocol.cluster(1, lambda: ["127.0.0.1", next(ports)]))
+    logger = FakeLogger()
+    ctx = DeployCtx(
+        config=config, transport=SimTransport(logger), logger=logger,
+        overrides={"quorum_backend": "tpu", "tpu_window": "256"})
+    role = protocol.roles["proxy_leader"]
+    assert role.device_options == ("quorum_backend",)
+    proxy_leader = role.make(ctx, role.addresses(config)[0], 0)
+    assert {"quorum_backend", "tpu_window"} <= ctx.consumed
+    assert type(proxy_leader.tracker) is TpuQuorumTracker
+    assert proxy_leader.tracker.checker.window == 256
+    # A row per acceptor of every leader group.
+    assert proxy_leader.tracker.checker.num_nodes == 2 * 3
+    assert proxy_leader.driver.flush_timer is not None
+    plan = launch_plan(protocol, config, {"quorum_backend": "tpu"},
+                       supernode=False)
+    assert [entry for entry in plan if entry[2]] == [
+        ("proxy_leader", "0,1", True)]
+    unset = DeployCtx(config=config, transport=SimTransport(logger),
+                      logger=logger, overrides={})
+    assert role.make(unset, role.addresses(config)[0], 0).tracker is None

@@ -1188,9 +1188,9 @@ class TestServedPathStages:
 
     def test_the_tracker_publishes_what_it_counts_and_one_path(
             self, served):
-        """``_publish_tpu_counts``: the colocated trackers' drains, votes
-        and launches, summed, are what ``/metrics`` serves, every drain
-        launched, and ``device`` is the only child of ``path`` (no host
+        """``BoardDriver.publish_counts``: the colocated trackers'
+        drains, votes and launches, summed, are what ``/metrics``
+        serves, every drain launched, and ``device`` is the only child of ``path`` (no host
         tally is left to publish one)."""
         served.closed_loops(8, 5)
         served.settle()

@@ -321,7 +321,7 @@ def half_voted(proxy, sim) -> tuple:
         proxy.receive(acceptors[index], Phase2b(
             slot=1, round=0, group_index=0, acceptor_index=index))
     proxy.on_drain()
-    proxy._collect_all()
+    proxy.driver.collect_all()
     assert proxy.chosen_count == 1
     return acceptors
 
@@ -378,7 +378,7 @@ def test_votes_buffered_for_the_old_board_reach_it_before_it_is_adopted():
     proxy.receive(acceptors[2], Phase2b(slot=0, round=0, group_index=0,
                                         acceptor_index=2))
     proxy.on_drain()
-    proxy._collect_all()
+    proxy.driver.collect_all()
     assert proxy.chosen_count == 1
 
 
